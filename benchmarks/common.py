@@ -1,6 +1,7 @@
 """Shared benchmark plumbing: dataset loading (cached), the four-SpMM GCN
 cycle model (paper §III.D: PEs allocated ∝ kernel ops, kernels pipelined),
-CSV row helpers, and the ``--smoke`` size preset (``BENCH_SMOKE=1``)."""
+CSV row helpers, the ``--smoke`` size preset (``BENCH_SMOKE=1``), and the
+guard that keeps subprocess suites off a TPU host."""
 from __future__ import annotations
 
 import functools
@@ -27,6 +28,22 @@ else:
                    "reddit": 4}
 X2_DENSITY = {"cora": 0.78, "citeseer": 0.891, "pubmed": 0.776,
               "nell": 0.864, "reddit": 0.60}
+
+
+def refuse_jax_children_on_tpu(suite: str) -> None:
+    """Raise before a suite starts JAX child processes from a TPU host.
+
+    Those children run a forced host-device CPU mesh, and on a TPU host
+    they would reach for the chip this process holds — and fail or hang.
+    Four-chip runs belong to ``python chip_smoke.py --chips 4``."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{suite}: refusing to start JAX child processes — this process "
+            "holds the TPU, which a child would need; this suite measures a "
+            "forced host-device CPU mesh. Run it on a CPU host, and run "
+            "four-chip paths with `python chip_smoke.py --chips 4`")
 
 
 @functools.lru_cache(maxsize=None)

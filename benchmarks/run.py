@@ -6,7 +6,8 @@
 Prints human tables per benchmark, then the machine-readable
 ``name,us_per_call,derived`` CSV block. ``--json PATH`` additionally writes
 the same rows as JSON (with a timestamp and the jax backend), so the perf
-trajectory is tracked across PRs.
+trajectory is tracked across PRs. A failed suite leaves a ``<suite>/FAILED``
+row, the other suites still run, and the harness exits non-zero.
 """
 from __future__ import annotations
 
@@ -34,6 +35,10 @@ def main() -> None:
         os.environ["BENCH_SMOKE"] = "1"
         print("[smoke] tiny synthetic preset active")
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (convergence, latency, moe_imbalance, openloop,
                             order_ops, reorder, roofline_table, scaling,
                             schedule_tuning, schedule_util, serving,
@@ -57,14 +62,16 @@ def main() -> None:
     }
     only = [s for s in args.only.split(",") if s]
     rows = []
+    failed = []
     for name, fn in suites.items():
         if only and name not in only:
             continue
         try:
             rows.extend(fn())
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # run the other suites, then exit non-zero
             print(f"[{name}] FAILED: {type(e).__name__}: {e}")
             rows.append((f"{name}/FAILED", 0.0, str(e)[:80]))
+            failed.append(name)
 
     print("\n== CSV ==")
     print("name,us_per_call,derived")
@@ -95,6 +102,8 @@ def main() -> None:
             json.dump(payload, f, indent=2)
             f.write("\n")
         print(f"wrote {len(rows)} rows to {args.json}")
+    if failed:
+        raise SystemExit(f"suite(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -249,6 +249,7 @@ print("ROW hot_replicated %%f req_per_s=%%.2f;replicas=%%d;"
 def _run_saturation(root) -> list:
     """Hot-graph replica scaling on the forced 8-way mesh: one graph,
     ``max_replicas=1`` vs grown replicas, bit-identity asserted."""
+    common.refuse_jax_children_on_tpu("serving/saturation")
     rows = []
     script = _SAT_SCRIPT % dict(n_dev=N_MESH_DEVICES, src=_SRC,
                                 store=str(root), sat=SAT)
@@ -273,6 +274,7 @@ def _run_mesh(root) -> list:
     subprocess reuses the store the single-device section populated only
     for its own graphs' *single-device* keys — on an 8-dev mesh the small
     graphs still take the single route, so admissions warm-start."""
+    common.refuse_jax_children_on_tpu("serving/mesh")
     rows = []
     script = _MESH_SCRIPT % dict(
         n_dev=N_MESH_DEVICES, src=_SRC,

@@ -17,6 +17,8 @@ import subprocess
 import sys
 from pathlib import Path
 
+from benchmarks import common
+
 N_FORCED_DEVICES = 8
 DEVICE_COUNTS = (1, 2, 4, 8)
 GRAPH = dict(n=3000, density=0.004, alpha=0.9, seed=0)
@@ -48,6 +50,7 @@ for d in %(counts)r:
 
 
 def run() -> list:
+    common.refuse_jax_children_on_tpu("sharded_spmm")
     rows = []
     name = f"powerlaw{GRAPH['n']}"
     print(f"\n== sharded SpMM ({name}, {N_FORCED_DEVICES} host devices, "

@@ -7,9 +7,12 @@ layer replays the converged plan. This module is purely the **execution
 machinery** for that plan:
 
 * ``ScheduleExecutor`` uploads a ``Schedule``'s arrays to the device exactly
-  once at construction and exposes jitted closures: ``spmm(b) = A @ b``
-  (fused-gather VPU routing or step-scanned one-hot MXU routing, chosen by
-  ``select_routing``'s cost model) and a jitted whole-GCN ``forward``.
+  once at construction and serves ``spmm(b) = A @ b`` (fused-gather VPU
+  routing or step-scanned one-hot MXU routing, chosen by
+  ``select_routing``'s cost model), a whole-GCN ``forward`` and its
+  request-batched ``forward_batch``. The compiled programs take the schedule
+  arrays as arguments, keyed on a static ``Geometry``, so they never hold a
+  copy of the schedule and executors of equal geometry share them.
 * ``ShardedScheduleExecutor`` runs the same plan across a 1-D device mesh
   (per-device step shards under ``shard_map``, psum merge — DESIGN.md §4).
 
@@ -41,13 +44,14 @@ attaches an f32-vs-bf16 max-error report to the winning ``TunedConfig``).
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 from collections import OrderedDict
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -322,10 +326,152 @@ def _spliced_host_slots(old_host, new_sched: Schedule, repair):
     return gcol, tgt, val, moved
 
 
+@dataclasses.dataclass(frozen=True)
+class Geometry:
+    """The static facts a routing body specializes on — everything but the
+    schedule arrays themselves, which every jitted entry point takes as
+    arguments. Two executors with equal geometry (a shape-preserving repair
+    of one graph, or two graphs of one shape) share compiled programs, and
+    the compiled program's size does not grow with nnz."""
+
+    routing: str
+    m: int
+    #: one-hot path only (0 on the gather path): A's column count, window
+    #: rows, column-block width and window count
+    n: int
+    r: int
+    cb: int
+    n_windows: int
+    #: chunk count of the gather slot stream (0 on the one-hot path)
+    n_chunks: int
+    bf16: bool
+    #: outputs come back through the ``unperm`` row permutation operand
+    unperm: bool = False
+    #: 1-D mesh of the sharded executor (None: one device)
+    mesh: Optional[Mesh] = None
+
+    @property
+    def acc(self):
+        return jnp.bfloat16 if self.bf16 else jnp.float32
+
+
+def _gather_partial(gcol, tgt, val, bf, *, g: Geometry):
+    """Fused-gather routing over one slot stream ``[n_chunks, chunk]``: B-row
+    gather per slot, one scatter-add into final output rows (row_map
+    precomposed). Chunked so the [chunk, kdim] intermediate stays bounded
+    on million-edge graphs."""
+    acc = g.acc
+    out = jnp.zeros((g.m, bf.shape[1]), acc)
+    if g.n_chunks == 1:
+        rows = jnp.take(bf, gcol[0], axis=0) * val[0].astype(acc)[:, None]
+        return out.at[tgt[0]].add(rows)
+
+    def chunk(i, a_):
+        rows = jnp.take(bf, gcol[i], axis=0) * val[i].astype(acc)[:, None]
+        return a_.at[tgt[i]].add(rows)
+
+    return jax.lax.fori_loop(0, g.n_chunks, chunk, out)
+
+
+def _onehot_partial(win, cblk, val, lrow, lcol, rm, bf, *, g: Geometry):
+    """Dense-routing emulation over one step stream: scan over steps, each
+    doing the Pallas kernel's two one-hot MXU contractions against the
+    step's [CB, kdim] B-panel, then the scatter epilogue (adder tree:
+    permuted window slots → matrix rows). The measurable XLA twin of the
+    kernel."""
+    acc = g.acc
+    kdim = bf.shape[1]
+    cb, r = g.cb, g.r
+    ncb = -(-g.n // cb)
+    bp = jnp.pad(bf, ((0, ncb * cb - g.n), (0, 0))).reshape(ncb, cb, kdim)
+
+    # HIGHEST keeps the one-hot selections exact in f32 on a TPU, whose
+    # default matmul precision rounds f32 operands to bfloat16
+    dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+
+    def step(out_perm, s):
+        w, cblk_s, val_s, lrow_s, lcol_s = s
+        bb = bp[cblk_s]  # [CB, kdim]
+        gather = (lcol_s[:, None] == jnp.arange(cb)[None, :]).astype(acc)  # [K, CB]
+        contrib = dot(gather, bb) * val_s.astype(acc)[:, None]  # [K, kdim]
+        scatter = (lrow_s[:, None] == jnp.arange(r)[None, :]).astype(acc)  # [K, R]
+        return out_perm.at[w].add(dot(scatter.T, contrib)), None
+
+    out_perm = jnp.zeros((g.n_windows, r, kdim), acc)
+    out_perm, _ = jax.lax.scan(step, out_perm, (win, cblk, val, lrow, lcol))
+    valid = rm >= 0
+    contrib = jnp.where(valid[:, None], out_perm.reshape(-1, kdim), 0.0)
+    return jnp.zeros((g.m, kdim), acc).at[jnp.where(valid, rm, 0)].add(contrib)
+
+
+def _spmm_body(geom: Geometry, ops: dict, b: jax.Array) -> jax.Array:
+    """C = A @ b for the schedule arrays ``ops``. On a mesh each device runs
+    the single-device body over its own step shard under ``shard_map`` and
+    a ``psum`` merges the partial outputs — the distributed adder tree that
+    also reunites evil-row chunks and windows straddling a shard
+    boundary."""
+    bf = b.astype(geom.acc)
+    if geom.routing == GATHER:
+        partial = functools.partial(_gather_partial, g=geom)
+        stepwise = [ops["gcol"], ops["tgt"], ops["val"]]
+        shared = []
+    else:
+        partial = functools.partial(_onehot_partial, g=geom)
+        stepwise = [ops[key] for key in ("win", "cblk", "val", "lrow", "lcol")]
+        shared = [ops["row_map"]]
+    if geom.mesh is None:
+        out = partial(*stepwise, *shared, bf)
+    else:
+        axis = geom.mesh.axis_names[0]
+        nk = len(stepwise)
+
+        def body(*arrs):
+            local = [x[0] for x in arrs[:nk]]  # drop the device dim
+            return jax.lax.psum(partial(*local, *arrs[nk:]), axis)
+
+        # step-sharded schedule arrays; row_map and the operand replicated.
+        # check_vma=False: the body ends in an explicit psum, which makes
+        # the P() output replicated by construction
+        specs = (P(axis),) * nk + (P(),) * (len(shared) + 1)
+        out = jax.shard_map(
+            body, mesh=geom.mesh, in_specs=specs, out_specs=P(), check_vma=False
+        )(*stepwise, *shared, bf)
+    if geom.unperm:
+        out = jnp.take(out, ops["unperm"], axis=0)
+    return out.astype(b.dtype)
+
+
+def _forward_body(geom: Geometry, ops: dict, params: dict, x: jax.Array) -> jax.Array:
+    """Whole-GCN logits: every layer runs A × (X × W) through the schedule."""
+    h = x
+    n_layers = len(params)
+    for i in range(n_layers):
+        h = _spmm_body(geom, ops, h @ params[f"w{i}"])
+        if i < n_layers - 1:
+            h = jax.nn.relu(h)
+    return h
+
+
+def _batched_forward_body(
+    geom: Geometry, ops: dict, params: dict, xs: jax.Array
+) -> jax.Array:
+    """``_forward_body`` vmapped over a leading request axis of ``xs``."""
+    fwd = functools.partial(_forward_body, geom)
+    return jax.vmap(fwd, in_axes=(None, None, 0))(ops, params, xs)
+
+
+# module-level jits keyed on the static geometry: executors of one geometry
+# share their compiled programs
+_spmm_jit = jax.jit(_spmm_body, static_argnames="geom")
+_forward_jit = jax.jit(_forward_body, static_argnames="geom")
+_batched_forward_jit = jax.jit(_batched_forward_body, static_argnames="geom")
+
+
 class _ExecutorBase:
     """Shared surface of the single- and multi-device executors: operand
-    validation, the jitted-closure call protocol, and the whole-GCN forward
-    loop (every layer's A × (X × W) through ``self._spmm_impl``)."""
+    validation and the call protocol of the jitted entry points, which take
+    the executor's static ``geometry`` and its device-resident schedule
+    arrays (``operands``) as arguments."""
 
     sched: Schedule
     routing: str
@@ -334,16 +480,52 @@ class _ExecutorBase:
     #: live on (None = jax's default device; always None for the sharded
     #: executor, whose mesh is the placement).
     device = None
+    #: the sharded executor's mesh (None: one device)
+    mesh: Optional[Mesh] = None
+    _unperm = None
 
-    @property
-    def _acc_dtype(self):
-        return jnp.bfloat16 if self.bf16_accumulate else jnp.float32
+    # geometry and operands are computed once, on first use: an executor is
+    # immutable after construction (repairs build a new one)
+    @functools.cached_property
+    def geometry(self) -> Geometry:
+        s = self.sched
+        m, n = s.shape
+        if self.routing == GATHER:
+            # the slot stream carries the window layout already: fields the
+            # gather body never reads stay 0, so they cannot split programs
+            n = r = cb = n_windows = 0
+            n_chunks = self._n_chunks
+        else:
+            r, cb, n_windows = s.rows_per_window, s.cols_per_block, s.n_windows
+            n_chunks = 0
+        return Geometry(
+            routing=self.routing,
+            m=m,
+            n=n,
+            r=r,
+            cb=cb,
+            n_windows=n_windows,
+            n_chunks=n_chunks,
+            bf16=self.bf16_accumulate,
+            unperm=self._unperm is not None,
+            mesh=self.mesh,
+        )
+
+    @functools.cached_property
+    def operands(self) -> dict:
+        """The device-resident schedule arrays, as the jitted entry points
+        take them."""
+        if self.routing == GATHER:
+            ops = {"gcol": self._gcol, "tgt": self._tgt, "val": self._val}
+        else:
+            ops = dict(self._steps)
+        if self._unperm is not None:
+            ops["unperm"] = self._unperm
+        return ops
 
     def commit(self, x: jax.Array) -> jax.Array:
         """Commit a dense operand to this executor's placement device, so
-        the jitted closures run where the schedule arrays already live (an
-        uncommitted operand would pull the computation — and a copy of
-        every captured array — onto jax's default device)."""
+        the computation runs where the schedule arrays already live."""
         if self.device is None:
             return x
         return jax.device_put(x, self.device)
@@ -356,7 +538,7 @@ class _ExecutorBase:
                 f"{self.sched.shape[1]} (A is {self.sched.shape}) — XLA "
                 "would silently clamp gather indices otherwise"
             )
-        return self._spmm(self.commit(b))
+        return _spmm_jit(self.geometry, self.operands, self.commit(b))
 
     __call__ = spmm
 
@@ -370,29 +552,28 @@ class _ExecutorBase:
             )
         if self.device is not None:
             params = jax.tree.map(self.commit, params)
-        return self._forward(params, self.commit(x))
+        return _forward_jit(self.geometry, self.operands, params, self.commit(x))
+
+    def forward_batch(self, params: dict, xs: jax.Array) -> jax.Array:
+        """``forward`` over a leading request axis — the serving engine's
+        batch dispatch (one compiled program per geometry and batch size).
+        ``params`` and ``xs`` must already live on this executor's
+        placement."""
+        return _batched_forward_jit(self.geometry, self.operands, params, xs)
 
     @property
     def utilization(self) -> float:
         return self.sched.utilization
-
-    def _forward_impl(self, params: dict, x: jax.Array) -> jax.Array:
-        h = x
-        n_layers = len(params)
-        for i in range(n_layers):
-            h = self._spmm_impl(h @ params[f"w{i}"])  # A × (X × W)
-            if i < n_layers - 1:
-                h = jax.nn.relu(h)
-        return h
 
 
 class ScheduleExecutor(_ExecutorBase):
     """Device-resident executor of one converged AWB schedule.
 
     Construction uploads every schedule array to one device once; the
-    jitted closures capture those arrays, so repeated ``spmm``/``forward``
-    calls move only the dense operand. ``device_bytes`` reports the
-    resident footprint — what the serving engine's LRU budget meters.
+    jitted entry points take those arrays as arguments, so repeated
+    ``spmm``/``forward`` calls move only the dense operand. ``device_bytes``
+    reports the resident footprint — what the serving engine's LRU budget
+    meters.
 
     ``device`` is the placement handle: pass a specific ``jax.Device`` to
     pin the schedule arrays (and therefore the computation — operands are
@@ -474,12 +655,6 @@ class ScheduleExecutor(_ExecutorBase):
         if self._unperm is not None:
             self.device_bytes += int(self._unperm.nbytes)
 
-        self._spmm_impl = (
-            self._gather_impl if self.routing == GATHER else self._onehot_impl
-        )
-        self._spmm = jax.jit(self._spmm_impl)
-        self._forward = jax.jit(self._forward_impl)
-
     @classmethod
     def _from_repair(
         cls, old_ex: "ScheduleExecutor", new_sched: Schedule, repair
@@ -494,7 +669,7 @@ class ScheduleExecutor(_ExecutorBase):
         keeps serving in-flight batches untouched). ONEHOT or any fallback
         repair rebuilds from scratch — a fresh full upload.
 
-        The result is a **new** executor object with fresh jit closures;
+        The result is a **new** executor object;
         never mutates ``old_ex``. Device contents are bit-identical to a
         cold ``ScheduleExecutor(new_sched, ...)`` with the same kwargs.
         """
@@ -592,9 +767,6 @@ class ScheduleExecutor(_ExecutorBase):
         self.device_bytes = int(self._gcol.nbytes + self._tgt.nbytes + self._val.nbytes)
         if self._unperm is not None:
             self.device_bytes += int(self._unperm.nbytes)
-        self._spmm_impl = self._gather_impl
-        self._spmm = jax.jit(self._spmm_impl)
-        self._forward = jax.jit(self._forward_impl)
         return self
 
     @classmethod
@@ -656,84 +828,7 @@ class ScheduleExecutor(_ExecutorBase):
             self._val = _scatter_set(old_ex._val, idx.astype(np.int32), val[idx])
         self.scoped_upload = True
         self.device_bytes = old_ex.device_bytes
-        self._spmm_impl = self._gather_impl
-        self._spmm = jax.jit(self._spmm_impl)
-        self._forward = jax.jit(self._forward_impl)
         return self
-
-    # ---- jitted bodies -----------------------------------------------------
-
-    def _gather_impl(self, b: jax.Array) -> jax.Array:
-        """Fused-gather routing: B-row gather per slot, one scatter-add into
-        final output rows (row_map precomposed). Chunked over the slot
-        stream so the [chunk, kdim] intermediate stays bounded on
-        million-edge graphs."""
-        m, _ = self.sched.shape
-        kdim = b.shape[-1]
-        acc = self._acc_dtype
-        bf = b.astype(acc)
-        out = jnp.zeros((m, kdim), acc)
-
-        if self._n_chunks == 1:
-            g = jnp.take(bf, self._gcol[0], axis=0) * self._val[0].astype(acc)[:, None]
-            out = out.at[self._tgt[0]].add(g)
-        else:
-
-            def body(i, a_):
-                g = (
-                    jnp.take(bf, self._gcol[i], axis=0)
-                    * self._val[i].astype(acc)[:, None]
-                )
-                return a_.at[self._tgt[i]].add(g)
-
-            out = jax.lax.fori_loop(0, self._n_chunks, body, out)
-        if self._unperm is not None:
-            out = jnp.take(out, self._unperm, axis=0)
-        return out.astype(b.dtype)
-
-    def _onehot_impl(self, b: jax.Array) -> jax.Array:
-        """Dense-routing emulation: scan over steps, each step doing the
-        Pallas kernel's two one-hot MXU contractions against the step's
-        [CB, kdim] B-panel. The measurable XLA twin of the kernel."""
-        m, n = self.sched.shape
-        k = self.sched.nnz_per_step
-        r = self.sched.rows_per_window
-        cb = self.sched.cols_per_block
-        kdim = b.shape[-1]
-        acc = self._acc_dtype
-        ncb = -(-n // cb)
-        bp = jnp.pad(b.astype(acc), ((0, ncb * cb - n), (0, 0)))
-        bp = bp.reshape(ncb, cb, kdim)
-
-        def step(out_perm, s):
-            win, cblk, val, lrow, lcol = s
-            bb = bp[cblk]  # [CB, kdim]
-            gather = (lcol[:, None] == jnp.arange(cb)[None, :]).astype(acc)  # [K, CB]
-            contrib = (gather @ bb) * val.astype(acc)[:, None]  # [K, kdim]
-            scatter = (lrow[:, None] == jnp.arange(r)[None, :]).astype(acc)  # [K, R]
-            out_perm = out_perm.at[win].add(scatter.T @ contrib)
-            return out_perm, None
-
-        out_perm = jnp.zeros((self.sched.n_windows, r, kdim), acc)
-        out_perm, _ = jax.lax.scan(
-            step,
-            out_perm,
-            (
-                self._steps["win"],
-                self._steps["cblk"],
-                self._steps["val"],
-                self._steps["lrow"],
-                self._steps["lcol"],
-            ),
-        )
-        # scatter epilogue (adder tree): permuted window slots → matrix rows
-        rm = self._steps["row_map"]
-        valid = rm >= 0
-        contrib = jnp.where(valid[:, None], out_perm.reshape(-1, kdim), 0.0)
-        out = jnp.zeros((m, kdim), acc).at[jnp.where(valid, rm, 0)].add(contrib)
-        if self._unperm is not None:
-            out = jnp.take(out, self._unperm, axis=0)
-        return out.astype(b.dtype)
 
 
 class ShardedScheduleExecutor(_ExecutorBase):
@@ -868,14 +963,6 @@ class ShardedScheduleExecutor(_ExecutorBase):
             if self._unperm is not None:
                 self.device_bytes += int(self._unperm.nbytes)
 
-        self._spmm_impl = (
-            self._sharded_gather_impl
-            if self.routing == GATHER
-            else self._sharded_onehot_impl
-        )
-        self._spmm = jax.jit(self._spmm_impl)
-        self._forward = jax.jit(self._forward_impl)
-
     @classmethod
     def _from_repair(
         cls, old_ex: "ShardedScheduleExecutor", new_sched: Schedule, repair
@@ -888,7 +975,7 @@ class ShardedScheduleExecutor(_ExecutorBase):
         otherwise — or for ONEHOT routing or a fallback repair — this
         rebuilds from scratch. Clean devices keep their existing on-device
         shard buffers via ``make_array_from_single_device_arrays``; the new
-        executor is a distinct object with fresh jit closures, and the old
+        executor is a distinct object, and the old
         one keeps serving in-flight batches."""
         if (
             old_ex.routing != GATHER
@@ -959,9 +1046,6 @@ class ShardedScheduleExecutor(_ExecutorBase):
         self.device_bytes = int(self._gcol.nbytes + self._tgt.nbytes + self._val.nbytes)
         if self._unperm is not None:
             self.device_bytes += int(self._unperm.nbytes)
-        self._spmm_impl = self._sharded_gather_impl
-        self._spmm = jax.jit(self._spmm_impl)
-        self._forward = jax.jit(self._forward_impl)
         return self
 
     @classmethod
@@ -1038,105 +1122,7 @@ class ShardedScheduleExecutor(_ExecutorBase):
         self.scoped_upload = True
         self.dirty_devices = int(sum(dirty))
         self.device_bytes = old_ex.device_bytes
-        self._spmm_impl = self._sharded_gather_impl
-        self._spmm = jax.jit(self._spmm_impl)
-        self._forward = jax.jit(self._forward_impl)
         return self
-
-    def _shard_map(self, body, in_specs):
-        # check_rep=False: the bodies end in an explicit psum, which makes
-        # the P() output replicated by construction; the static replication
-        # checker has no rule for scatter-add on some jax versions.
-        return shard_map(
-            body, mesh=self.mesh, in_specs=in_specs, out_specs=P(), check_rep=False
-        )
-
-    # ---- jitted bodies -----------------------------------------------------
-
-    def _sharded_gather_impl(self, b: jax.Array) -> jax.Array:
-        """Fused-gather routing per device shard + psum merge."""
-        m, _ = self.sched.shape
-        axis = self.axis
-        acc = self._acc_dtype
-        n_chunks = self._n_chunks
-
-        def body(gcol, tgt, val, bf):
-            gcol, tgt, val = gcol[0], tgt[0], val[0]  # [n_chunks, chunk]
-            out = jnp.zeros((m, bf.shape[1]), acc)
-            if n_chunks == 1:
-                g = jnp.take(bf, gcol[0], axis=0) * val[0].astype(acc)[:, None]
-                out = out.at[tgt[0]].add(g)
-            else:
-
-                def chunk(i, a_):
-                    g = jnp.take(bf, gcol[i], axis=0) * val[i].astype(acc)[:, None]
-                    return a_.at[tgt[i]].add(g)
-
-                out = jax.lax.fori_loop(0, n_chunks, chunk, out)
-            return jax.lax.psum(out, axis)
-
-        fn = self._shard_map(body, (P(axis), P(axis), P(axis), P()))
-        out = fn(self._gcol, self._tgt, self._val, b.astype(acc))
-        if self._unperm is not None:
-            out = jnp.take(out, self._unperm, axis=0)
-        return out.astype(b.dtype)
-
-    def _sharded_onehot_impl(self, b: jax.Array) -> jax.Array:
-        """Per-device one-hot step scan + local scatter epilogue, then a
-        psum of the per-device partial outputs."""
-        m, n = self.sched.shape
-        r = self.sched.rows_per_window
-        cb = self.sched.cols_per_block
-        n_windows = self.sched.n_windows
-        axis = self.axis
-        acc = self._acc_dtype
-        ncb = -(-n // cb)
-
-        def body(win, cblk, val, lrow, lcol, rm, bf):
-            win, cblk = win[0], cblk[0]  # [S] / [S, K]
-            val, lrow, lcol = val[0], lrow[0], lcol[0]
-            kdim = bf.shape[1]
-            bp = jnp.pad(bf, ((0, ncb * cb - n), (0, 0)))
-            bp = bp.reshape(ncb, cb, kdim)
-
-            def step(out_perm, s):
-                w, cblk_s, val_s, lrow_s, lcol_s = s
-                bb = bp[cblk_s]  # [CB, kdim]
-                gather = (lcol_s[:, None] == jnp.arange(cb)[None, :]).astype(
-                    acc
-                )  # [K, CB]
-                contrib = (gather @ bb) * val_s.astype(acc)[:, None]
-                scatter = (lrow_s[:, None] == jnp.arange(r)[None, :]).astype(
-                    acc
-                )  # [K, R]
-                out_perm = out_perm.at[w].add(scatter.T @ contrib)
-                return out_perm, None
-
-            out_perm = jnp.zeros((n_windows, r, kdim), acc)
-            out_perm, _ = jax.lax.scan(step, out_perm, (win, cblk, val, lrow, lcol))
-            # device-local scatter epilogue, then the cross-device adder
-            # tree: one psum of [m, kdim] partials
-            valid = rm >= 0
-            contrib = jnp.where(valid[:, None], out_perm.reshape(-1, kdim), 0.0)
-            out = jnp.zeros((m, kdim), acc).at[jnp.where(valid, rm, 0)].add(contrib)
-            return jax.lax.psum(out, axis)
-
-        fn = self._shard_map(
-            body, (P(axis), P(axis), P(axis), P(axis), P(axis), P(), P())
-        )
-        s = self._steps
-        out = fn(
-            s["win"],
-            s["cblk"],
-            s["val"],
-            s["lrow"],
-            s["lcol"],
-            s["row_map"],
-            b.astype(acc),
-        )
-        if self._unperm is not None:
-            out = jnp.take(out, self._unperm, axis=0)
-        return out.astype(b.dtype)
 
 
 def repaired_executor(old_ex, new_sched: Schedule, repair):
@@ -1145,7 +1131,7 @@ def repaired_executor(old_ex, new_sched: Schedule, repair):
     untouched — the scoped re-upload path of DESIGN.md §11.
 
     Dispatches on the old executor's class; always returns a **new**
-    executor object (fresh jit closures) and never mutates ``old_ex``, so
+    executor object and never mutates ``old_ex``, so
     the serving tier can atomically swap while in-flight batches finish on
     the old one. Guaranteed bit-identical device state to a cold build of
     the same class on ``new_sched`` with the same construction kwargs."""
@@ -1163,8 +1149,8 @@ def value_patched_executor(old_ex, new_sched: Schedule, slots, vals):
     The O(|delta|) fast lane of DESIGN.md §11: gcol/tgt device arrays are
     shared with ``old_ex`` and only the changed values are scattered (or
     the dirty ``val`` shards re-uploaded, for the sharded class). Same
-    contract as ``repaired_executor``: a new object with fresh jit
-    closures, bit-identical device state to a cold build on ``new_sched``.
+    contract as ``repaired_executor``: a new object with bit-identical
+    device state to a cold build on ``new_sched``.
     """
     slots = np.asarray(slots, np.int64)
     vals = np.asarray(vals)

@@ -17,11 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams → CompilerParams across versions; take
-# whichever this install provides
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -91,9 +86,13 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
                     scale: float | None = None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True) -> jax.Array:
+                    block_k: int = 128,
+                    interpret: bool | None = None) -> jax.Array:
     """q: [B, Sq, H, D]; k, v: [B, Sk, Hkv, D] (GQA when Hkv < H).
-    Returns [B, Sq, H, D]."""
+    Returns [B, Sq, H, D]. ``interpret`` defaults to the backend: compiled
+    on a TPU, the interpreter everywhere else."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     b, sq, h, d = q.shape
     _, sk, hkv, _ = k.shape
     assert h % hkv == 0
@@ -137,7 +136,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qt, kt, vt)
 

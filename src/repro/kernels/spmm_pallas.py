@@ -19,8 +19,9 @@ TPU adaptation of the paper's engine (DESIGN.md §2):
     couple of MXU tiles instead of spanning the whole matrix width.
   - ``"gather"``: a dynamic **VPU gather** of B rows by slot index
     (``b_block[local_col]``) followed by the same one-hot scatter. Routing
-    work scales with K alone — the right path for ultra-sparse operands
-    whose natural block is the full width.
+    work scales with K alone. Interpret mode only: Mosaic does not lower
+    the in-kernel ``jnp.take``, so compiled kernels route one-hot and the
+    served path's gather routing is the XLA executor body.
 
 * The window accumulator lives in the output block; steps of one window are
   consecutive (schedule contract), so it is zeroed on window entry and
@@ -45,11 +46,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import csc as _fmt
 from repro.core.schedule import Schedule
 
-# jax renamed TPUCompilerParams → CompilerParams across versions; take
-# whichever this install provides
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _kernel(win_ref, cblk_ref,            # scalar prefetch
             val_ref, lrow_ref, lcol_ref,  # [1, K] step slots
@@ -66,7 +62,7 @@ def _kernel(win_ref, cblk_ref,            # scalar prefetch
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    k = val_ref.shape[1]
+    k = val_ref.shape[-1]
     cb = b_ref.shape[0]
 
     val = val_ref[0, :].astype(acc_dtype)           # [K]
@@ -75,10 +71,13 @@ def _kernel(win_ref, cblk_ref,            # scalar prefetch
 
     if routing == "onehot":
         # gather B rows via one-hot contraction (the omega network as a
-        # dense MXU contraction — [K, CB] must be capped to stay cheap)
+        # dense MXU contraction — [K, CB] must be capped to stay cheap).
+        # HIGHEST: at the MXU's default precision an f32 operand is rounded
+        # to bfloat16, and the selection would no longer be exact
         gather = (lcol[:, None] == jax.lax.broadcasted_iota(
             jnp.int32, (k, cb), 1)).astype(acc_dtype)       # [K, CB]
         rows = jax.lax.dot(gather, b_ref[...].astype(acc_dtype),
+                           precision=jax.lax.Precision.HIGHEST,
                            preferred_element_type=acc_dtype)  # [K, ktile]
     else:
         # dynamic VPU gather by slot index: routing work scales with K
@@ -90,6 +89,7 @@ def _kernel(win_ref, cblk_ref,            # scalar prefetch
     scatter = (lrow[:, None] == jax.lax.broadcasted_iota(
         jnp.int32, (k, n_rows_window), 1)).astype(acc_dtype)  # [K, R]
     acc = jax.lax.dot(scatter.T, contrib,
+                      precision=jax.lax.Precision.HIGHEST,
                       preferred_element_type=acc_dtype)        # [R, ktile]
     out_ref[...] += acc.astype(out_ref.dtype)
 
@@ -101,6 +101,17 @@ def _spmm_pallas_perm(val, lrow, lcol, win, cblk, b,
                       ktile: int, interpret: bool, routing: str):
     n, kdim = b.shape
     n_steps = win.shape[0]
+    if not interpret:
+        # no-op tail steps (val 0, last window: they accumulate nothing)
+        # to a multiple of 1024. Mosaic's compile time and code size blow
+        # up on scalar-prefetch arrays of other lengths: at 42,437 steps
+        # 16 s and 16.6 MB of code, at 43,008 steps 0.8 s and 0.7 MB
+        # (compiled for a described v5e)
+        pad_s = (-n_steps) % 1024
+        win = jnp.pad(win, (0, pad_s), mode="edge")
+        cblk = jnp.pad(cblk, (0, pad_s))
+        val, lrow, lcol = (jnp.pad(x, (0, pad_s * k)) for x in (val, lrow, lcol))
+        n_steps += pad_s
 
     pad_k = (-kdim) % ktile
     bp = jnp.pad(b, ((0, (-n) % cb), (0, pad_k)))
@@ -108,6 +119,11 @@ def _spmm_pallas_perm(val, lrow, lcol, win, cblk, b,
 
     grid = (kd // ktile, n_steps)
     out_shape = jax.ShapeDtypeStruct((n_windows * r, kd), b.dtype)
+    # step slots live as [n_steps, 1, K] with the step dim squeezed out of
+    # the block: Mosaic needs a block's last two dims to be (8, 128)
+    # multiples or the full extents, and (1, K) is the full extent
+    slot_spec = pl.BlockSpec((pl.Squeezed(), 1, k),
+                             lambda j, i, win, cblk: (i, 0, 0))
 
     out = pl.pallas_call(
         functools.partial(_kernel, n_rows_window=r, acc_dtype=jnp.float32,
@@ -116,9 +132,7 @@ def _spmm_pallas_perm(val, lrow, lcol, win, cblk, b,
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, k), lambda j, i, win, cblk: (i, 0)),
-                pl.BlockSpec((1, k), lambda j, i, win, cblk: (i, 0)),
-                pl.BlockSpec((1, k), lambda j, i, win, cblk: (i, 0)),
+                slot_spec, slot_spec, slot_spec,
                 pl.BlockSpec((cb, ktile),
                              lambda j, i, win, cblk: (cblk[i], j)),
             ],
@@ -127,29 +141,41 @@ def _spmm_pallas_perm(val, lrow, lcol, win, cblk, b,
         ),
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-    )(win, cblk, val.reshape(n_steps, k), lrow.reshape(n_steps, k),
-      lcol.reshape(n_steps, k), bp)
+    )(win, cblk, val.reshape(n_steps, 1, k), lrow.reshape(n_steps, 1, k),
+      lcol.reshape(n_steps, 1, k), bp)
     return out[:, :kdim]
 
 
 def spmm_balanced(sched: Schedule, b: jax.Array, *, ktile: int = 128,
-                  interpret: bool = True,
+                  interpret: bool | None = None,
                   routing: str = "auto") -> jax.Array:
-    """C = A @ B through the AWB schedule. ``interpret=True`` runs the
-    kernel body on CPU (validation mode); on TPU pass ``interpret=False``.
+    """C = A @ B through the AWB schedule.
 
-    ``routing`` is ``"onehot"`` (MXU dense routing), ``"gather"`` (VPU
-    dynamic gather), or ``"auto"`` (the executor cost model decides from the
-    schedule's K/CB/R geometry).
+    ``interpret`` defaults to the backend: compiled by Mosaic on a TPU,
+    the interpreter (validation mode) everywhere else. ``routing`` is
+    ``"onehot"`` (MXU dense routing), ``"gather"`` (VPU dynamic gather —
+    interpret mode only: Mosaic cannot lower an in-kernel ``jnp.take``
+    over a VMEM block), or ``"auto"``: the executor cost model decides
+    from the schedule's K/CB/R geometry in interpret mode, and compiled
+    kernels take the one routing that lowers, ``"onehot"``.
     """
-    from repro.core.executor import device_step_arrays, select_routing
+    from repro.core.executor import GATHER, ONEHOT, device_step_arrays, \
+        select_routing
     from repro.core.schedule import scatter_epilogue
 
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     if routing == "auto":
         routing = select_routing(sched.nnz_per_step, sched.cols_per_block,
-                                 sched.rows_per_window, ktile)
+                                 sched.rows_per_window, ktile) \
+            if interpret else ONEHOT
+    if routing == GATHER and not interpret:
+        raise ValueError(
+            "routing='gather' runs only with interpret=True: its in-kernel "
+            "dynamic row gather does not lower through Mosaic; use "
+            "routing='onehot' (or 'auto') for the compiled kernel")
     # device-resident copies of the schedule arrays, uploaded once per
     # schedule (shared with one-hot executors) — repeated calls move no
     # schedule bytes
@@ -177,7 +203,7 @@ def transpose_coo(a: "_fmt.COO") -> "_fmt.COO":
 
 def make_spmm_fn(a: "_fmt.COO", *, nnz_per_step: int = 256,
                  rows_per_window: int = 64, ktile: int = 128,
-                 interpret: bool = True,
+                 interpret: bool | None = None,
                  schedules: tuple[Schedule, Schedule] | None = None,
                  routing: str = "auto"):
     """Returns a differentiable ``f(b) = A @ b`` backed by the Pallas kernel

@@ -228,8 +228,9 @@ class _Request:
 @dataclasses.dataclass
 class _Unit:
     """One device-resident serving clone of a graph (the primary or a
-    replica): a pinned executor, the uploaded weights, and the jitted
-    vmapped whole-GCN forward that serves batches through them."""
+    replica): a pinned executor, the uploaded weights, and the executor's
+    batched whole-GCN forward (``forward_batch``) that serves batches
+    through them."""
     device_index: Optional[int]  # None: sharded (spans the mesh)
     executor: object
     fwd: callable
@@ -266,7 +267,7 @@ class _Resident:
     params: Optional[dict] = None  # device weight tree; guarded-by: _swap_lock
     #: ScheduleExecutor or ShardedScheduleExecutor (None while evicted)
     executor: Optional[object] = None  # guarded-by: _swap_lock
-    fwd: Optional[callable] = None  # jitted vmapped fwd; guarded-by: _swap_lock
+    fwd: Optional[callable] = None  # batched fwd; guarded-by: _swap_lock
     bytes: int = 0  # schedule + weight device bytes; guarded-by: _swap_lock
     #: secondary replicas by device index (the primary lives in the
     #: fields above, on the placement's ``device_index``)
@@ -786,7 +787,7 @@ class GCNServingEngine:
         row_unperm: Optional[np.ndarray] = None,
     ):
         """Cold executor for one serving clone (the re-tune fallback's
-        builder — full upload, fresh jit closures)."""
+        builder — full upload)."""
         if device_index is None:  # sharded: spans the mesh
             return ShardedScheduleExecutor(
                 sched,
@@ -811,29 +812,27 @@ class GCNServingEngine:
         graph — primary and secondary replicas — via ``build(old_executor,
         device_index)``. Runs *outside* the swap lock: device memory
         transiently holds old and new copies while in-flight batches keep
-        serving on the old closures. Weights are reused in place (an edge
+        serving on the old executors. Weights are reused in place (an edge
         delta never changes them), so no weight re-upload."""
         with self._swap_lock:
             old_ex, params = rec.executor, rec.params
             old_reps = dict(rec.replicas)
         primary_dev = None if p.kind == SHARDED else p.device_index
         ex = build(old_ex, primary_dev)
-        fwd = jax.jit(jax.vmap(ex._forward_impl, in_axes=(None, 0)))
         primary = _Unit(
             primary_dev,
             ex,
-            fwd,
+            ex.forward_batch,
             params,
             ex.device_bytes + self._weight_bytes(params),
         )
         reps = {}
         for d, unit in old_reps.items():
             rex = build(unit.executor, d)
-            rfwd = jax.jit(jax.vmap(rex._forward_impl, in_axes=(None, 0)))
             reps[d] = _Unit(
                 d,
                 rex,
-                rfwd,
+                rex.forward_batch,
                 unit.params,
                 rex.device_bytes + self._weight_bytes(unit.params),
             )
@@ -1277,11 +1276,8 @@ class GCNServingEngine:
             params = jax.tree.map(jnp.asarray, rec.params_host)
         else:
             params = jax.device_put(rec.params_host, dev)
-        # one jitted dispatch per (clone, batch size): the whole-GCN body
-        # vmapped over the request axis
-        fwd = jax.jit(jax.vmap(ex._forward_impl, in_axes=(None, 0)))
         nbytes = ex.device_bytes + sum(int(x.nbytes) for x in jax.tree.leaves(params))
-        return _Unit(device_index, ex, fwd, params, nbytes)
+        return _Unit(device_index, ex, ex.forward_batch, params, nbytes)
 
     def _admit(self, rec: _Resident) -> None:
         """Ensure ``rec`` is device-resident on its placement (LRU-touch +
@@ -1304,7 +1300,7 @@ class GCNServingEngine:
                     row_unperm=rec.inv,
                 )
                 params = jax.tree.map(jnp.asarray, rec.params_host)
-                fwd = jax.jit(jax.vmap(ex._forward_impl, in_axes=(None, 0)))
+                fwd = ex.forward_batch
                 w_bytes = sum(int(x.nbytes) for x in jax.tree.leaves(params))
                 nbytes = ex.device_bytes + w_bytes
             else:
@@ -1323,9 +1319,9 @@ class GCNServingEngine:
         self._maybe_rebalance(keep=rec.graph_id)
 
     def _evict(self, rec: _Resident, *, pressure: bool = True) -> None:
-        # dropping the executor, weights, and the jitted closure releases
-        # the device arrays they capture; the host schedule/config/weights
-        # stay for re-upload. One-hot executors also memoize their step
+        # dropping the executor and weights releases the device arrays
+        # they hold; the host schedule/config/weights stay for
+        # re-upload. One-hot executors also memoize their step
         # arrays in the executor module's LRU — purge that too, or the
         # bytes survive the eviction. A replicated victim first sheds its
         # secondary replicas (collapsing its placement to SINGLE, so
@@ -1387,7 +1383,7 @@ class GCNServingEngine:
         self, rec: _Resident, device_index: int, *, shrink: bool = True
     ) -> None:
         """Release one secondary replica: its executor, weights, jitted
-        closure, and — for one-hot executors — exactly its own device's
+        forward, and — for one-hot executors — exactly its own device's
         memoized step arrays (surviving replicas keep theirs)."""
         with self._swap_lock:
             unit = rec.replicas.pop(device_index)

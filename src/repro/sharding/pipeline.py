@@ -82,13 +82,11 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *, mesh,
 
     other_axes = tuple(a for a in mesh.axis_names if a != axis)
     spec_p = P(axis, *([None] * 0))
-    from jax.experimental.shard_map import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     outs = fn(stage_params, micro)
     return outs.reshape(b, *x.shape[1:])
