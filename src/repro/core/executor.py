@@ -437,18 +437,25 @@ def _spmm_body(geom: Geometry, ops: dict, b: jax.Array) -> jax.Array:
             body, mesh=geom.mesh, in_specs=specs, out_specs=P(), check_vma=False
         )(*stepwise, *shared, bf)
     if geom.unperm:
-        out = jnp.take(out, ops["unperm"], axis=0)
+        with jax.named_scope("unperm"):
+            out = jnp.take(out, ops["unperm"], axis=0)
     return out.astype(b.dtype)
 
 
 def _forward_body(geom: Geometry, ops: dict, params: dict, x: jax.Array) -> jax.Array:
-    """Whole-GCN logits: every layer runs A × (X × W) through the schedule."""
+    """Whole-GCN logits: every layer runs A × (X × W) through the schedule.
+    Each layer's ops carry the scopes ``l<i>.xw``, ``l<i>.spmm`` and
+    ``l<i>.relu`` in their metadata, for the profiler."""
     h = x
     n_layers = len(params)
     for i in range(n_layers):
-        h = _spmm_body(geom, ops, h @ params[f"w{i}"])
+        with jax.named_scope(f"l{i}.xw"):
+            h = h @ params[f"w{i}"]
+        with jax.named_scope(f"l{i}.spmm"):
+            h = _spmm_body(geom, ops, h)
         if i < n_layers - 1:
-            h = jax.nn.relu(h)
+            with jax.named_scope(f"l{i}.relu"):
+                h = jax.nn.relu(h)
     return h
 
 

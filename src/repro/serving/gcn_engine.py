@@ -120,6 +120,7 @@ from repro.serving.policy import (
     PolicyState,
     SchedulingPolicy,
 )
+from repro.serving.spans import Spans
 from repro.serving.types import ACCEPTED, REJECTED, SHED, SubmitTicket
 from repro.tuning import registry, runner, space
 from repro.tuning.space import TunedConfig
@@ -514,6 +515,9 @@ class GCNServingEngine:
         #: bounded reservoir of recent request latencies (seconds) for
         #: the percentile figures in stats()
         self._lat_samples: "deque[float]" = deque(maxlen=_LAT_RESERVOIR)
+        #: host time per stage of the serving path (``engine.*`` profiler
+        #: spans; ``stats()["stages"]``)
+        self._spans = Spans()
         # the overload accounting identity over the queue path:
         #   submitted == queue_served + shed + rejected + dropped + pending
         # (`requests` also counts direct serve_batch work, so the queue
@@ -541,6 +545,8 @@ class GCNServingEngine:
             "chunk_retries": 0,
             "graph_updates": 0,
             "update_retunes": 0,
+            # bytes of request features copied from host arrays to the device
+            "h2d_bytes": 0,
         }
 
     # ---- policy state snapshot ---------------------------------------------
@@ -1538,9 +1544,10 @@ class GCNServingEngine:
         single execution body behind both the worker-thread path and the
         sibling-replica retry path (so the ``replica_chunk`` fault seam
         covers both)."""
-        FAULTS.check("replica_chunk", graph=graph_id, device=unit.device_index)
-        out = unit.fwd(unit.params, unit.executor.commit(chunk))
-        _block_until_ready(out)
+        with self._spans.span("chunk", device=unit.device_index, n=len(chunk)):
+            FAULTS.check("replica_chunk", graph=graph_id, device=unit.device_index)
+            out = unit.fwd(unit.params, unit.executor.commit(chunk))
+            _block_until_ready(out)
         return out
 
     def _pool_run(self, unit: _Unit, graph_id: str, chunk):
@@ -1571,7 +1578,8 @@ class GCNServingEngine:
         if hasattr(xs, "ndim") and xs.ndim == 3:
             xb = xs
         else:
-            xb = jnp.stack([jnp.asarray(x) for x in xs])
+            with self._spans.span("stack", n=len(xs)):
+                xb = jnp.stack([jnp.asarray(x) for x in xs])
         n = rec.sched.shape[1]
         if xb.shape[1] != n:
             raise ValueError(
@@ -1717,11 +1725,15 @@ class GCNServingEngine:
             # (or a sibling retry) served the whole batch — which replica
             # served must stay unobservable, placement included
             if p.kind == REPLICATED:
-                out0 = jax.device_put(outs[0][1], self.devices[p.device_index])
+                with self._spans.span("merge", parts=1):
+                    out0 = jax.device_put(outs[0][1], self.devices[p.device_index])
                 return out0, failures
             return outs[0][1], failures
         target = self.devices[p.device_index]
-        merged = jnp.concatenate([jax.device_put(o, target) for _, o in outs], axis=0)
+        with self._spans.span("merge", parts=len(outs)):
+            merged = jnp.concatenate(
+                [jax.device_put(o, target) for _, o in outs], axis=0
+            )
         return merged, failures
 
     def _note_service(self, gid: str, svc_s: float, n_requests: int) -> None:
@@ -1759,8 +1771,10 @@ class GCNServingEngine:
         direct path is all-or-nothing — ``.partial`` carries any
         successful sub-batches, but nothing is counted served)."""
         t0 = time.monotonic()
-        parts = self._dispatch_with_retry(graph_id, xs)
-        out, part_failures = self._await_batch(graph_id, parts)
+        with self._spans.span("dispatch", graph=graph_id, n=len(xs)):
+            parts = self._dispatch_with_retry(graph_id, xs)
+        with self._spans.span("await", graph=graph_id, n=len(xs)):
+            out, part_failures = self._await_batch(graph_id, parts)
         if part_failures:
             n_failed = sum(f.n for f in part_failures)
             self._count("request_failures", n_failed)
@@ -1801,50 +1815,55 @@ class GCNServingEngine:
         pin it, and an open-loop driver passes the *intended* arrival
         time so latency and deadlines measure from the schedule, not
         from when the driver got around to calling."""
-        rec = self._graphs.get(graph_id)
-        if rec is None:
-            raise UnknownGraphError(graph_id, "submit")
-        x = jnp.asarray(x)
-        n = rec.sched.shape[1]
-        if x.ndim != 2 or x.shape[0] != n:
-            raise ValueError(
-                f"request for graph {graph_id!r} must be [n={n}, features]; "
-                f"got shape {x.shape}"
+        with self._spans.span("submit", rid=self._next_rid, graph=graph_id):
+            rec = self._graphs.get(graph_id)
+            if rec is None:
+                raise UnknownGraphError(graph_id, "submit")
+            nbytes = x.nbytes if isinstance(x, np.ndarray) else 0
+            with self._spans.span("copy", rid=self._next_rid, bytes=nbytes):
+                x = jnp.asarray(x)
+            if nbytes:
+                self._count("h2d_bytes", nbytes)
+            n = rec.sched.shape[1]
+            if x.ndim != 2 or x.shape[0] != n:
+                raise ValueError(
+                    f"request for graph {graph_id!r} must be [n={n}, features]; "
+                    f"got shape {x.shape}"
+                )
+            if now is None:
+                now = time.monotonic()
+            self._count("submitted")
+            depth = len(self._pending.get(graph_id) or ())
+            if self.max_queue_depth is not None and depth >= self.max_queue_depth:
+                self._count("rejected")
+                return SubmitTicket(
+                    None,
+                    REJECTED,
+                    f"queue for graph {graph_id!r} is at max_queue_depth="
+                    f"{self.max_queue_depth}",
+                )
+            deadline = None if deadline_s is None else now + float(deadline_s)
+            if self.shed_unmeetable and deadline is not None:
+                dec = self.policy.shed_on_submit(
+                    self._policy_state(now), graph_id, deadline
+                )
+                if dec.shed:
+                    self._count("shed")
+                    return SubmitTicket(None, SHED, dec.reason)
+            rid = self._next_rid
+            self._next_rid += 1
+            self._pending.setdefault(graph_id, []).append(
+                _Request(rid=rid, x=x, submit_t=now, deadline=deadline)
             )
-        if now is None:
-            now = time.monotonic()
-        self._count("submitted")
-        depth = len(self._pending.get(graph_id) or ())
-        if self.max_queue_depth is not None and depth >= self.max_queue_depth:
-            self._count("rejected")
-            return SubmitTicket(
-                None,
-                REJECTED,
-                f"queue for graph {graph_id!r} is at max_queue_depth="
-                f"{self.max_queue_depth}",
-            )
-        deadline = None if deadline_s is None else now + float(deadline_s)
-        if self.shed_unmeetable and deadline is not None:
-            dec = self.policy.shed_on_submit(
-                self._policy_state(now), graph_id, deadline
-            )
-            if dec.shed:
-                self._count("shed")
-                return SubmitTicket(None, SHED, dec.reason)
-        rid = self._next_rid
-        self._next_rid += 1
-        self._pending.setdefault(graph_id, []).append(
-            _Request(rid=rid, x=x, submit_t=now, deadline=deadline)
-        )
-        if len(self._pending[graph_id]) >= self.max_batch:
-            # a queue hot enough to hit the threshold is the saturation
-            # signal's strongest form — give replication a chance to grow
-            # before the batch serves
-            self._update_replication(now)
-            served = self._serve_queues([graph_id], now=now)
-            for gid, out in served.items():
-                self._ready.setdefault(gid, []).append(out)
-        return SubmitTicket(rid, ACCEPTED)
+            if len(self._pending[graph_id]) >= self.max_batch:
+                # a queue hot enough to hit the threshold is the saturation
+                # signal's strongest form — give replication a chance to grow
+                # before the batch serves
+                self._update_replication(now)
+                served = self._serve_queues([graph_id], now=now)
+                for gid, out in served.items():
+                    self._ready.setdefault(gid, []).append(out)
+            return SubmitTicket(rid, ACCEPTED)
 
     def _absorb(self, load: Dict[int, float], p: Placement, est: float) -> float:
         """Fold one queue's service estimate into a per-device load map
@@ -1909,14 +1928,15 @@ class GCNServingEngine:
         this from the serving loop; ``now`` defaults to
         ``time.monotonic()`` (tests inject a clock). Replica sets grow or
         shrink here too (see ``_update_replication``)."""
-        if now is None:
-            now = time.monotonic()
-        self._update_replication(now)
-        due = set(self.policy.due_queues(self._policy_state(now)))
-        # max_batch threshold queues serve regardless of deadlines — the
-        # batching bound is the engine's, not the policy's
-        due |= {g for g, q in self._pending.items() if len(q) >= self.max_batch}
-        return self._drain(self._serve_queues(list(due), now=now))
+        with self._spans.span("poll"):
+            if now is None:
+                now = time.monotonic()
+            self._update_replication(now)
+            due = set(self.policy.due_queues(self._policy_state(now)))
+            # max_batch threshold queues serve regardless of deadlines — the
+            # batching bound is the engine's, not the policy's
+            due |= {g for g, q in self._pending.items() if len(q) >= self.max_batch}
+            return self._drain(self._serve_queues(list(due), now=now))
 
     def flush(self) -> Dict[str, jax.Array]:
         """Serve all queued requests, batched per graph. Returns
@@ -1930,9 +1950,10 @@ class GCNServingEngine:
         when multiple graphs fail in one flush), and the raised
         ``FlushError`` carries the successful results in ``.partial`` —
         no computed logits are lost."""
-        return self._drain(
-            self._serve_queues([g for g, q in self._pending.items() if q])
-        )
+        with self._spans.span("flush"):
+            return self._drain(
+                self._serve_queues([g for g, q in self._pending.items() if q])
+            )
 
     def _drain(self, served: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
         """Merge freshly served batches with threshold-auto-flushed ones
@@ -1944,7 +1965,8 @@ class GCNServingEngine:
             if len(parts) == 1:
                 served[gid] = parts[0]
             else:
-                served[gid] = jnp.concatenate(parts, axis=0)
+                with self._spans.span("drain", parts=len(parts)):
+                    served[gid] = jnp.concatenate(parts, axis=0)
         return served
 
     def _serve_queues(
@@ -2001,7 +2023,9 @@ class GCNServingEngine:
                     continue
             t_disp = time.monotonic()
             try:
-                parts = self._dispatch_with_retry(gid, [r.x for r in reqs])
+                ids = dict(graph=gid, rid0=reqs[0].rid, n=len(reqs))
+                with self._spans.span("dispatch", **ids):
+                    parts = self._dispatch_with_retry(gid, [r.x for r in reqs])
             except Exception as e:
                 failures[gid] = e
                 restore(gid, reqs)
@@ -2009,8 +2033,10 @@ class GCNServingEngine:
             inflight.append((gid, reqs, parts, t_disp))
         t_prev = None
         for gid, reqs, parts, t_disp in inflight:
+            ids = dict(graph=gid, rid0=reqs[0].rid, n=len(reqs))
             try:
-                out, part_failures = self._await_batch(gid, parts)
+                with self._spans.span("await", **ids):
+                    out, part_failures = self._await_batch(gid, parts)
             except Exception as e:
                 failures[gid] = e
                 restore(gid, reqs)
@@ -2081,6 +2107,7 @@ class GCNServingEngine:
         self.counters = {k: 0 for k in self.counters}
         self._lat_n, self._lat_total, self._lat_max = 0, 0.0, 0.0
         self._lat_samples.clear()
+        self._spans.reset()
 
     def latency_percentiles(self) -> Dict[str, float]:
         """p50/p95/p99 of the recent-request latency reservoir, in
@@ -2141,4 +2168,5 @@ class GCNServingEngine:
             per_device=self.placer.device_report(
                 extra={d: {"saturation_s": s} for d, s in sat.items()}
             ),
+            stages=self._spans.snapshot(),
         )
