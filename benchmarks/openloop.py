@@ -227,7 +227,7 @@ def _drive(eng, variants, pops, arrivals, sla_s):
         if wait > 0:
             time.sleep(min(wait, 0.002))
     give_up = time.monotonic() + sla_s + 5.0
-    while eng.stats()["pending_requests"]:
+    while eng.stats()["pending_requests"] or eng.stats()["inflight_requests"]:
         eng.poll()
         if time.monotonic() > give_up:
             eng.flush()  # never hang the bench on a scheduling bug

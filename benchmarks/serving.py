@@ -90,7 +90,7 @@ def _run_deadline(eng, feats) -> list:
                 eng.submit(name, x * mask, deadline_s=DEADLINE_S)
                 n_req += 1
         deadline_at = time.monotonic() + DEADLINE_S
-        while eng.stats()["pending_requests"]:
+        while eng.stats()["pending_requests"] or eng.stats()["inflight_requests"]:
             eng.poll()
             if time.monotonic() > deadline_at + 1.0:
                 eng.flush()  # never hang the bench on a scheduling bug

@@ -321,6 +321,28 @@ def four_chips(seed: int) -> int:
         check(f"cora replicated vs 1 device, request {i}", got[i], base[i],
               ROUTE_TOL)
         check(f"cora replicated vs reference, request {i}", got[i], ref(x), TOL)
+
+    # batches left in flight by submit (three of four requests each, so
+    # the third dispatch awaits the first) and handed back by later polls:
+    # rows in submission order on both routes
+    mesh.max_batch = 4
+    for gid, ds, s in (("pubmed", big, seed + 4), ("cora", hot, seed + 5)):
+        xs = requests(ds, 12, s)
+        for x in xs:
+            assert mesh.submit(gid, x).accepted
+        st = mesh.stats()
+        log(f"{gid}: {st['inflight_requests']} requests in flight, "
+            f"{st['overlapped_batches']} batches overlapped so far")
+        rows, polls = [], 0
+        while len(rows) < len(xs):
+            rows.extend(np.asarray(mesh.poll()[gid]))
+            polls += 1
+        base = np.concatenate(
+            [np.asarray(one.serve_batch(gid, xs[i:i + 4])) for i in (0, 4, 8)])
+        log(f"{gid}: 12 rows back in {polls} polls")
+        for i in range(len(xs)):
+            check(f"{gid} in flight vs 1 device, request {i}", rows[i], base[i],
+                  ROUTE_TOL)
     for d in devs[:4]:
         log(f"  {d}: peak_bytes_in_use {peak_bytes(d)}")
     return 4

@@ -127,9 +127,11 @@ def main():
                     engine.submit(name, x * mask, deadline_s=sla_s)
             # the poll loop is the serving thread: queues auto-flush
             # earliest-deadline-first as their SLAs come due
-            while engine.stats()["pending_requests"]:
+            st = engine.stats()
+            while st["pending_requests"] or st["inflight_requests"]:
                 engine.poll()
                 time.sleep(0.01)
+                st = engine.stats()
         st = engine.stats()
         judged = st["deadline_met"] + st["deadline_misses"]
         print(f"deadline serving ({sla_s * 1e3:.0f}ms SLA): "

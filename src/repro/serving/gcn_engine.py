@@ -35,14 +35,17 @@ the tuning subsystem into that shape:
   lifted to placement).
 * **Deadline-aware batching.** ``submit(graph_id, x, deadline_s=...)``
   queues a request; queues auto-flush when a graph reaches the
-  ``max_batch`` threshold, and ``poll()`` serves every queue whose
-  earliest deadline is due (earliest-deadline-first across graphs; all
-  batches are dispatched before any result is awaited, so batches placed
-  on different devices run concurrently). Each graph's queue serves
-  through **one jitted vmapped whole-GCN forward** per replica —
-  bit-identical to the direct ``serve_batch`` path. Per-request latency
-  and deadline hits/misses surface in ``stats()``; ``flush()`` remains
-  the serve-everything-now path, in deterministic EDF order.
+  ``max_batch`` threshold — the batch is dispatched inside ``submit`` and
+  left in flight, awaited later by ``poll()``, so the next batch's
+  host→device copies overlap its forward — and ``poll()`` serves every
+  queue whose earliest deadline is due (earliest-deadline-first across
+  graphs; all batches are dispatched before any result is awaited, so
+  batches placed on different devices run concurrently). Each graph's
+  queue serves through **one jitted vmapped whole-GCN forward** per
+  replica — bit-identical to the direct ``serve_batch`` path.
+  Per-request latency and deadline hits/misses surface in ``stats()``;
+  ``flush()`` remains the serve-everything-now path, in deterministic EDF
+  order.
 * **Bounded residency.** Each resident graph's device footprint — its
   executor's schedule arrays (``device_bytes``) *plus* its uploaded
   weights — counts against its device's budget, one full footprint per
@@ -148,6 +151,12 @@ _sleep = time.sleep
 #: bounded reservoir of recent per-request latencies (seconds) backing
 #: the p50/p95/p99 percentiles in ``stats()``.
 _LAT_RESERVOIR = 65536
+
+#: batches of one graph left in flight unawaited at most: two keep the
+#: next batch's host→device copies overlapping the running forward; a
+#: third dispatch first awaits the oldest, so a caller that submits
+#: without polling holds at most this many batches' inputs on the device
+_MAX_INFLIGHT = 2
 
 # SubmitTicket / ACCEPTED / REJECTED / SHED and the typed errors
 # (ServingError, UnknownGraphError, RequestFailure, FlushError) moved to
@@ -259,6 +268,15 @@ class _Part:
 
 
 @dataclasses.dataclass
+class _Batch:
+    """One dispatched queue batch awaiting completion: its requests in
+    queue order, the dispatched parts, and when the dispatch started."""
+    reqs: List[_Request]
+    parts: List[_Part]
+    t_disp: float
+
+
+@dataclasses.dataclass
 class _Resident:
     graph_id: str
     fingerprint: str  # guarded-by: _swap_lock (persist worker back-fills)
@@ -366,6 +384,15 @@ class GCNServingEngine:
     ``replica_shrink_after`` consecutive calm ``poll``s below a quarter of
     that).
 
+    Requests queue per graph (``submit``) and come back from ``poll`` /
+    ``flush``. A queue that reaches ``max_batch`` is dispatched inside
+    ``submit`` without waiting for the device; the batch stays in flight
+    (``stats()["inflight_requests"]``) until a ``poll`` or ``flush``
+    awaits it, so the host copies the next requests to the device while
+    the forward runs. At most ``_MAX_INFLIGHT`` (2) batches of a graph
+    stay in flight unawaited: the dispatch of a third awaits the oldest
+    first and keeps its logits for the next ``poll``.
+
     ``device_budget_bytes`` bounds each device's resident schedule+weight
     bytes; the graph being served is always kept resident, even if it
     alone exceeds the budget (a budget smaller than one graph cannot be
@@ -378,9 +405,10 @@ class GCNServingEngine:
     behavior decision-for-decision; ``LearnedServiceTimePolicy()`` swaps
     the EWMA service-time model for an online-fitted predictor.
 
-    Admission control: ``max_queue_depth`` bounds every per-graph queue
-    (``submit`` returns a REJECTED ``SubmitTicket`` at the bound; None =
-    unbounded, the historical behaviour). ``shed_unmeetable=True`` turns
+    Admission control: ``max_queue_depth`` bounds every graph's
+    requests not yet handed back, in flight included (``submit`` returns a REJECTED
+    ``SubmitTicket`` at the bound; None = unbounded, the historical
+    behaviour). ``shed_unmeetable=True`` turns
     on deadline-aware shedding: a request whose deadline the EDF load
     map's EWMA-predicted wait already rules out is dropped — at submit
     time and again at dispatch time — instead of burning device time on
@@ -496,9 +524,20 @@ class GCNServingEngine:
         self._autotune_kwargs.setdefault("warmup", autotune_warmup)
         self._graphs: "OrderedDict[str, _Resident]" = OrderedDict()
         self._pending: Dict[str, List[_Request]] = {}
-        #: batches completed by a threshold-triggered auto-flush, awaiting
-        #: pickup by the next poll()/flush()
-        self._ready: Dict[str, List[jax.Array]] = {}
+        #: per-graph FIFO of dispatched batches not yet awaited (a
+        #: threshold auto-flush leaves its batch here for ``poll``/``flush``
+        #: to await and hand back); a graph's key exists only while its
+        #: FIFO is non-empty, so key order is the order of each graph's
+        #: oldest in-flight batch
+        self._inflight: Dict[str, "deque[_Batch]"] = {}
+        #: logits of batches awaited early — by a dispatch that found
+        #: ``_MAX_INFLIGHT`` batches of its graph in flight — already
+        #: counted served, handed back (ahead of the FIFO) by the next
+        #: ``poll``/``flush``
+        self._done: Dict[str, List[jax.Array]] = {}
+        #: device index → when the batch last awaited on it completed (the
+        #: start of the next batch's service time on that device)
+        self._last_done: Dict[int, float] = {}
         self._svc_ewma: Dict[str, float] = {}  # per-graph batch seconds
         #: per-graph per-*request* EWMA seconds — the saturation signal
         #: (× queue depth = backlog a single replica would need)
@@ -520,9 +559,11 @@ class GCNServingEngine:
         self._spans = Spans()
         # the overload accounting identity over the queue path:
         #   submitted == queue_served + shed + rejected + dropped + pending
+        #                + inflight
         # (`requests` also counts direct serve_batch work, so the queue
         # path gets its own served counter; `dropped` settles requests a
-        # remove_graph failed while still queued)
+        # remove_graph failed while queued or in flight; inflight counts
+        # requests dispatched but not yet awaited)
         self.counters = {
             "store_hits": 0,
             "store_misses": 0,
@@ -547,6 +588,8 @@ class GCNServingEngine:
             "update_retunes": 0,
             # bytes of request features copied from host arrays to the device
             "h2d_bytes": 0,
+            # batches dispatched while an earlier batch was still in flight
+            "overlapped_batches": 0,
         }
 
     # ---- policy state snapshot ---------------------------------------------
@@ -747,22 +790,25 @@ class GCNServingEngine:
     def remove_graph(self, graph_id: str) -> None:
         """Drop a graph entirely: executors, replicas, placement, queues.
 
-        Pending queued requests cannot be served once the graph is gone;
-        silently discarding them would break the accounting identity
-        (``submitted == queue_served + shed + rejected + dropped +
-        pending``), so they are **failed**: settled exactly once into the
-        ``dropped`` counter and surfaced as one typed ``RequestFailure``
-        raised *after* the removal fully completed — the engine state is
-        clean whether or not the caller catches it."""
+        Queued and in-flight requests cannot be handed back once the graph
+        is gone; silently discarding them would break the accounting
+        identity (``submitted == queue_served + shed + rejected + dropped
+        + pending + inflight``), so they are **failed**: settled exactly
+        once into the ``dropped`` counter and surfaced as one typed
+        ``RequestFailure`` raised *after* the removal fully completed —
+        the engine state is clean whether or not the caller catches it.
+        In-flight batches are awaited first, so their outstanding-work
+        charges settle and the device is done with the graph's arrays."""
         if graph_id not in self._graphs:
             raise UnknownGraphError(graph_id, "remove_graph")
+        inflight = self._abandon(graph_id, self._inflight.pop(graph_id, ()))
+        self._done.pop(graph_id, None)
         rec = self._graphs.pop(graph_id)
         with self._swap_lock:
             replica_devs = list(rec.replicas)
         for d in replica_devs:
             self._drop_replica(rec, d, shrink=False)
-        dropped = self._pending.pop(graph_id, None) or []
-        self._ready.pop(graph_id, None)
+        dropped = inflight + (self._pending.pop(graph_id, None) or [])
         self._svc_ewma.pop(graph_id, None)
         self._svc_req_ewma.pop(graph_id, None)
         self._calm_polls.pop(graph_id, None)
@@ -775,7 +821,7 @@ class GCNServingEngine:
             self._count("dropped", len(dropped))
             raise RequestFailure(
                 graph_id,
-                RuntimeError("graph removed while requests were queued"),
+                RuntimeError("graph removed with requests queued or in flight"),
                 len(dropped),
             )
 
@@ -1802,13 +1848,23 @@ class GCNServingEngine:
 
         ``deadline_s`` is the SLA in seconds from now (None = no deadline;
         the request serves on the next ``flush()`` or when its graph's
-        queue reaches ``max_batch`` — which auto-flushes that graph
-        immediately). Shape is validated here so one malformed request can
-        never poison a later flush — malformed submissions *raise*
+        queue reaches ``max_batch``). The request that fills a queue to
+        ``max_batch`` auto-flushes it: the batch is dispatched here and
+        left in flight — ``submit`` does not wait for the device — and a
+        later ``poll``/``flush`` awaits it and hands its logits back, so
+        the next requests' host→device copies overlap this batch's
+        forward. A dispatch that fails synchronously restores the batch's
+        requests and raises ``FlushError``; so does a fault in the oldest
+        in-flight batch when a third batch's dispatch has to await it.
+
+        Shape is validated here so one malformed request can never poison
+        a later flush — malformed submissions *raise*
         (``UnknownGraphError``/``ValueError``: caller bugs, not load).
 
-        Admission control runs before anything is queued: a queue at
-        ``max_queue_depth`` returns a REJECTED ticket, and with
+        Admission control runs before anything is queued: a graph whose
+        requests not yet handed back (queued, in flight, or awaited and
+        held for ``poll``) are at ``max_queue_depth`` returns a REJECTED
+        ticket, and with
         ``shed_unmeetable`` on, a deadline the EDF load map's
         EWMA-predicted wait already rules out returns a SHED ticket (see
         ``_predicted_wait``). ``now`` injects the arrival clock — tests
@@ -1833,7 +1889,13 @@ class GCNServingEngine:
             if now is None:
                 now = time.monotonic()
             self._count("submitted")
-            depth = len(self._pending.get(graph_id) or ())
+            # every request of the graph not yet handed back counts:
+            # queued, in flight, or awaited early and held in ``_done``
+            depth = (
+                len(self._pending.get(graph_id) or ())
+                + sum(len(b.reqs) for b in self._inflight.get(graph_id, ()))
+                + sum(int(o.shape[0]) for o in self._done.get(graph_id, ()))
+            )
             if self.max_queue_depth is not None and depth >= self.max_queue_depth:
                 self._count("rejected")
                 return SubmitTicket(
@@ -1860,9 +1922,9 @@ class GCNServingEngine:
                 # signal's strongest form — give replication a chance to grow
                 # before the batch serves
                 self._update_replication(now)
-                served = self._serve_queues([graph_id], now=now)
-                for gid, out in served.items():
-                    self._ready.setdefault(gid, []).append(out)
+                _, failures = self._dispatch_queues([graph_id], now)
+                if failures:
+                    raise FlushError(failures, {})
             return SubmitTicket(rid, ACCEPTED)
 
     def _absorb(self, load: Dict[int, float], p: Placement, est: float) -> float:
@@ -1905,9 +1967,20 @@ class GCNServingEngine:
         return self.policy.predicted_wait(self._policy_state(), graph_id, deadline)
 
     def poll(self, now: Optional[float] = None) -> Dict[str, jax.Array]:
-        """Serve every queue that is *due* and return its batched logits
-        (merged with any batches a ``max_batch`` threshold already
-        auto-flushed).
+        """Serve every queue that is *due*, await batches in flight, and
+        return each graph's logits, rows in submission order.
+
+        Per graph, ``poll`` hands back a prefix of the batches in flight
+        (those a ``max_batch`` threshold auto-flushed inside ``submit``):
+        it awaits the oldest, blocking if needed — so it never comes back
+        empty-handed while a batch is in flight — then takes every later
+        one whose logits are already on the device, stopping at the first
+        that is not. A graph whose queue is due here has its queue
+        dispatched, and everything it has in flight awaited with it.
+        Logits of a batch awaited early inside ``submit`` (the graph had
+        ``_MAX_INFLIGHT`` batches in flight) come back first. When a
+        batch fails, it and the graph's later in-flight batches go back
+        on the queue in submission order and ``FlushError`` is raised.
 
         A queue is due when its earliest deadline, minus 1.5× its
         estimated completion time (plus a small floor), has arrived. The
@@ -1936,11 +2009,11 @@ class GCNServingEngine:
             # max_batch threshold queues serve regardless of deadlines — the
             # batching bound is the engine's, not the policy's
             due |= {g for g, q in self._pending.items() if len(q) >= self.max_batch}
-            return self._drain(self._serve_queues(list(due), now=now))
+            return self._serve_queues(list(due), now=now)
 
     def flush(self) -> Dict[str, jax.Array]:
-        """Serve all queued requests, batched per graph. Returns
-        ``{graph_id: [B, n, classes] logits}``.
+        """Serve all queued requests and await every batch in flight,
+        batched per graph. Returns ``{graph_id: [B, n, classes] logits}``.
 
         Queues serve in deterministic earliest-deadline-first order
         (deadline-free graphs last, ties broken by graph id — never by
@@ -1951,17 +2024,15 @@ class GCNServingEngine:
         ``FlushError`` carries the successful results in ``.partial`` —
         no computed logits are lost."""
         with self._spans.span("flush"):
-            return self._drain(
-                self._serve_queues([g for g, q in self._pending.items() if q])
+            return self._serve_queues(
+                [g for g, q in self._pending.items() if q], settle_all=True
             )
 
-    def _drain(self, served: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
-        """Merge freshly served batches with threshold-auto-flushed ones
-        awaiting pickup."""
-        ready, self._ready = self._ready, {}
-        for gid, parts in ready.items():
-            if gid in served:
-                parts = parts + [served[gid]]
+    def _drain(self, outs: Dict[str, list]) -> Dict[str, jax.Array]:
+        """Join each graph's awaited batches, oldest first, into one
+        ``[B, n, classes]`` result."""
+        served = {}
+        for gid, parts in outs.items():
             if len(parts) == 1:
                 served[gid] = parts[0]
             else:
@@ -1969,44 +2040,46 @@ class GCNServingEngine:
                     served[gid] = jnp.concatenate(parts, axis=0)
         return served
 
-    def _serve_queues(
-        self, graph_ids, now: Optional[float] = None
-    ) -> Dict[str, jax.Array]:
-        """Serve the named graphs' queues: EDF dispatch order, then await.
+    def _restore(self, gid: str, reqs: List[_Request]) -> None:
+        """Put failed or undispatched requests back on their queue in
+        submission order (ahead of every later arrival)."""
+        q = reqs + self._pending.get(gid, [])
+        self._pending[gid] = sorted(q, key=lambda r: r.rid)
 
-        All batches are **dispatched** (async jit calls; per-replica
-        sub-batches on worker threads) before any result is awaited, so
-        batches placed on different mesh devices execute concurrently;
-        awaiting then happens in the same EDF order. ``batches``/
-        ``requests``/``queue_served`` count a batch only once its
-        completion is proven — a dispatch that fails later never inflates
-        the served-work stats.
+    def _dispatch_queues(
+        self, graph_ids, now: Optional[float] = None
+    ) -> Tuple[List[str], Dict[str, Exception]]:
+        """Dispatch the named graphs' queues in EDF order and leave each
+        batch in flight (appended to its graph's FIFO). Returns the
+        dispatch order and the graphs whose dispatch failed (their
+        requests restored).
+
+        A graph that already has ``_MAX_INFLIGHT`` batches in flight has
+        its oldest awaited first — completed and counted as at ``poll``,
+        its logits kept in ``_done`` for the next ``poll``/``flush`` —
+        so the device never holds more than that many unawaited batches
+        of one graph. If that batch failed, the graph's queue is not
+        dispatched this time and its failure is returned.
 
         With ``shed_unmeetable`` on, requests whose deadline even the
-        graph's own batch estimate can no longer meet are shed here —
-        the last gate before device time is spent. Failures surface
-        per-request: a batch whose every recovery path (bounded dispatch
-        retries, sibling-replica chunk retries) was exhausted gets
-        exactly its failed requests restored at the queue front — served
-        chunks still deliver — and one ``FlushError`` reports all failed
-        graphs after every healthy graph was served."""
+        graph's own batch estimate can no longer meet are shed here — the
+        last gate before device time is spent."""
         if now is None:
             now = time.monotonic()
         # one snapshot serves every ordering + shed decision of this
-        # cycle: EWMAs and queues only mutate in the await loop below,
-        # after all dispatch decisions are made
+        # cycle: EWMAs and queues only mutate once batches are awaited
         state = self._policy_state(now)
         order = self.policy.dispatch_order(
             state, [g for g in graph_ids if self._pending.get(g)]
         ).graph_ids
-        served: Dict[str, jax.Array] = {}
         failures: Dict[str, Exception] = {}
-        inflight = []
-
-        def restore(gid, reqs):
-            self._pending[gid] = reqs + self._pending.get(gid, [])
-
         for gid in order:
+            if len(self._inflight.get(gid, ())) >= _MAX_INFLIGHT:
+                out, failed = self._await_oldest(gid, failures)
+                if out is not None:
+                    self._done.setdefault(gid, []).append(out)
+                if failed:
+                    continue
             reqs = self._pending.pop(gid)
             if self.shed_unmeetable:
                 keep = []
@@ -2028,50 +2101,144 @@ class GCNServingEngine:
                     parts = self._dispatch_with_retry(gid, [r.x for r in reqs])
             except Exception as e:
                 failures[gid] = e
-                restore(gid, reqs)
+                self._restore(gid, reqs)
                 continue
-            inflight.append((gid, reqs, parts, t_disp))
-        t_prev = None
-        for gid, reqs, parts, t_disp in inflight:
-            ids = dict(graph=gid, rid0=reqs[0].rid, n=len(reqs))
-            try:
-                with self._spans.span("await", **ids):
-                    out, part_failures = self._await_batch(gid, parts)
-            except Exception as e:
-                failures[gid] = e
-                restore(gid, reqs)
-                continue
-            ok_reqs = reqs
-            if part_failures:
-                failed_idx = set()
-                for f in part_failures:
-                    failed_idx.update(range(f.offset, f.offset + f.n))
-                failed = [r for i, r in enumerate(reqs) if i in failed_idx]
-                ok_reqs = [r for i, r in enumerate(reqs) if i not in failed_idx]
-                restore(gid, failed)
-                self._count("request_failures", len(failed))
-                failures[gid] = part_failures[-1].exc
-            if out is None:
-                continue
-            t_done = time.monotonic()
-            self._count("batches")
-            self._count("requests", len(ok_reqs))
-            self._count("queue_served", len(ok_reqs))
-            # service EWMAs fold the *incremental* completion time of this
-            # batch: everything was dispatched before anything was
-            # awaited, so on shared devices a later batch's await-since-
-            # dispatch span contains every earlier batch's compute —
-            # folding that cumulative span would inflate every EWMA
-            # toward the whole cycle's cost, and the shed predicate
-            # (which already sums EDF-ahead queues itself) would double-
-            # count the serialization and shed far too eagerly
-            svc_t0 = t_disp if t_prev is None else max(t_disp, t_prev)
-            self._note_served(gid, ok_reqs, svc_t0, t_done)
-            t_prev = t_done
-            served[gid] = out
+            if self._inflight:
+                self._count("overlapped_batches")
+            self._inflight.setdefault(gid, deque()).append(_Batch(reqs, parts, t_disp))
+        return order, failures
+
+    @staticmethod
+    def _settled(b: _Batch) -> bool:
+        """Whether every part of an in-flight batch has finished, checked
+        without blocking (a replica future is done only once its chunk's
+        logits are ready: ``_run_unit`` awaits them on the pool thread)."""
+        return all(
+            p.future.done() if p.future is not None else p.out.is_ready()
+            for p in b.parts
+        )
+
+    def _serve_queues(
+        self, graph_ids, now: Optional[float] = None, *, settle_all: bool = False
+    ) -> Dict[str, jax.Array]:
+        """Dispatch the named graphs' queues (EDF order), then await
+        batches in flight and return ``{graph_id: logits}``.
+
+        Every graph whose queue was named here — and every graph, with
+        ``settle_all`` — has all its in-flight batches awaited; any other
+        graph in flight has its oldest batch awaited plus each later one
+        already settled. Logits kept in ``_done`` come first, then
+        batches awaited oldest first per graph, so a graph's rows come
+        back in submission order; when a batch fails, the graph's later
+        batches go back on its queue with it (see ``_await_oldest``).
+        All of this call's batches are dispatched before any is awaited,
+        so batches placed on different mesh devices execute concurrently.
+
+        ``batches``/``requests``/``queue_served`` count a batch only once
+        its completion is proven — a dispatch that fails later never
+        inflates the served-work stats. Failures surface per-request: a
+        batch whose every recovery path (bounded dispatch retries,
+        sibling-replica chunk retries) was exhausted gets exactly its
+        failed requests restored at the queue front — served chunks still
+        deliver — and one ``FlushError`` reports all failed graphs after
+        every healthy batch was awaited."""
+        order, failures = self._dispatch_queues(graph_ids, now)
+        outs, self._done = self._done, {}
+        for gid in list(self._inflight):
+            every = settle_all or gid in order
+            first = True
+            while gid in self._inflight and (
+                first or every or self._settled(self._inflight[gid][0])
+            ):
+                first = False
+                out, _ = self._await_oldest(gid, failures)
+                if out is not None:
+                    outs.setdefault(gid, []).append(out)
+        served = self._drain(outs)
         if failures:
             raise FlushError(failures, served)
         return served
+
+    def _await_oldest(
+        self, gid: str, failures: Dict[str, Exception]
+    ) -> Tuple[Optional[jax.Array], bool]:
+        """Pop and complete the oldest in-flight batch of ``gid``
+        (``_complete``). When any of its requests failed, every later
+        in-flight batch of the graph is awaited too and its requests go
+        back on the queue with the failed ones: the queue re-forms in
+        submission order, and no later row is handed back ahead of a
+        failed earlier one. Returns the logits served and whether the
+        batch failed."""
+        fifo = self._inflight[gid]
+        out, failed = self._complete(gid, fifo.popleft(), failures)
+        if failed:
+            self._restore(gid, self._abandon(gid, fifo))
+            fifo.clear()
+        if not fifo:
+            del self._inflight[gid]
+        return out, failed
+
+    def _abandon(self, gid: str, batches) -> List[_Request]:
+        """Await in-flight batches only to settle their outstanding-work
+        charges, discard their logits, and return their requests."""
+        reqs: List[_Request] = []
+        for b in batches:
+            try:
+                self._await_batch(gid, b.parts)
+            except Exception:
+                pass  # _await_batch settles every charge either way
+            reqs.extend(b.reqs)
+        return reqs
+
+    def _complete(
+        self, gid: str, b: _Batch, failures: Dict[str, Exception]
+    ) -> Tuple[Optional[jax.Array], bool]:
+        """Await one in-flight batch and settle its bookkeeping: served
+        counters, latency and deadline outcomes, service EWMAs — or, for
+        the requests that failed, restore them at the queue front, count
+        them and record the graph's failure. Returns the logits of the
+        requests served (None when none was) and whether any failed."""
+        ids = dict(graph=gid, rid0=b.reqs[0].rid, n=len(b.reqs))
+        try:
+            with self._spans.span("await", **ids):
+                out, part_failures = self._await_batch(gid, b.parts)
+        except Exception as e:
+            failures[gid] = e
+            self._restore(gid, b.reqs)
+            return None, True
+        ok_reqs = b.reqs
+        if part_failures:
+            failed_idx = set()
+            for f in part_failures:
+                failed_idx.update(range(f.offset, f.offset + f.n))
+            failed = [r for i, r in enumerate(b.reqs) if i in failed_idx]
+            ok_reqs = [r for i, r in enumerate(b.reqs) if i not in failed_idx]
+            self._restore(gid, failed)
+            self._count("request_failures", len(failed))
+            failures[gid] = part_failures[-1].exc
+        if out is None:
+            return None, True
+        t_done = time.monotonic()
+        self._count("batches")
+        self._count("requests", len(ok_reqs))
+        self._count("queue_served", len(ok_reqs))
+        # the service time of this batch is its *incremental* completion
+        # time on its devices: from its dispatch, or from when the batch
+        # awaited before it on the same device completed, whichever is
+        # later. A batch dispatched behind another (in this call or left
+        # in flight by an earlier one) would otherwise fold the earlier
+        # batch's compute, and the time the host was not looking, into
+        # the EWMAs — and the shed predicate (which already sums
+        # EDF-ahead queues itself) would double-count the serialization
+        devs = set()
+        for p in b.parts:  # a sharded part (device None) spans the mesh
+            d = p.device_index
+            devs.update(range(self.n_devices) if d is None else (d,))
+        svc_t0 = max([b.t_disp] + [self._last_done.get(d, b.t_disp) for d in devs])
+        self._note_served(gid, ok_reqs, svc_t0, t_done)
+        for d in devs:
+            self._last_done[d] = t_done
+        return out, bool(part_failures)
 
     def _note_served(
         self, gid: str, reqs: List[_Request], t_disp: float, t_done: float
@@ -2156,6 +2323,9 @@ class GCNServingEngine:
             n_graphs=len(self._graphs),
             n_resident=len(self.resident_graphs),
             pending_requests=sum(len(q) for q in self._pending.values()),
+            inflight_requests=sum(
+                len(b.reqs) for q in self._inflight.values() for b in q
+            ),
             queue_depth={g: len(q) for g, q in self._pending.items() if q},
             saturation_s=sat,
             latency_n=self._lat_n,

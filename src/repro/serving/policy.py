@@ -291,6 +291,10 @@ class HeuristicPolicy:
       its earliest deadline minus ``SVC_SAFETY ×`` its absorbed
       completion estimate (plus ``SVC_FLOOR_S``) has arrived.
 
+    Both EDF walks start their per-device load map from
+    ``outstanding_s``, so work still in flight on a device delays the
+    queues behind it.
+
     Subclasses customize the service-time model by overriding
     ``_queue_est`` / ``_req_est`` — every decision reads its estimates
     through those two hooks."""
@@ -367,7 +371,8 @@ class HeuristicPolicy:
         my_key = g.earliest_deadline
         if deadline is not None:
             my_key = min(my_key, deadline)
-        load: Dict[int, float] = {}
+        # work already dispatched (a batch left in flight) delays the queue
+        load = dict(enumerate(state.outstanding_s))
         ahead = (
             gid
             for gid, gs in state.graphs.items()
@@ -421,7 +426,7 @@ class HeuristicPolicy:
         every EDF-predecessor dispatches with it."""
         pending = (g for g, gs in state.graphs.items() if gs.queue_depth)
         order = _edf_order(state, pending)
-        load: Dict[int, float] = {}
+        load = dict(enumerate(state.outstanding_s))
         due_upto = -1
         for i, (gid, gs) in enumerate(order):
             done = absorb_load(

@@ -141,7 +141,7 @@ def test_queue_dispatch_fault_flusherror_restores_then_recovers(
     assert len(eng._pending["g"]) == 2        # both requests survived
     st = eng.stats()
     assert st["submitted"] == st["queue_served"] + st["shed"] \
-        + st["rejected"] + st["pending_requests"]
+        + st["rejected"] + st["pending_requests"] + st["inflight_requests"]
     _outstanding_settled(eng)
     FAULTS.clear()
     out = eng.flush()
@@ -261,7 +261,8 @@ KW = dict(iters=1, warmup=1, sweep=SWEEP, bf16_report=False)
 def identity(eng):
     st = eng.stats()
     assert st["submitted"] == (st["queue_served"] + st["shed"]
-                               + st["rejected"] + st["pending_requests"]), st
+                               + st["rejected"] + st["pending_requests"]
+                               + st["inflight_requests"]), st
 
 n = 300
 a = synth.power_law_adjacency(n, 0.03, 0.9, seed=5)

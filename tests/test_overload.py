@@ -54,7 +54,8 @@ def _engine(root, **kw):
 def _identity(eng):
     st = eng.stats()
     assert st["submitted"] == (st["queue_served"] + st["shed"]
-                               + st["rejected"] + st["pending_requests"]), st
+                               + st["rejected"] + st["pending_requests"]
+                               + st["inflight_requests"]), st
     return st
 
 
@@ -206,10 +207,12 @@ def test_threshold_autoflush_counts_queue_served(tmp_path):
     assert eng.submit("g", x).accepted
     t = eng.submit("g", x * 0.5)     # reaches max_batch: auto-flush
     assert t.accepted
-    st = _identity(eng)
-    assert st["queue_served"] == 2 and st["pending_requests"] == 0
-    out = eng.poll()                 # picks up the auto-flushed batch
+    st = _identity(eng)              # dispatched, left in flight
+    assert st["pending_requests"] == 0 and st["inflight_requests"] == 2
+    out = eng.poll()                 # awaits the auto-flushed batch
     assert out["g"].shape == (2, N_NODES, N_CLASSES)
+    st = _identity(eng)
+    assert st["queue_served"] == 2 and st["inflight_requests"] == 0
 
 
 def test_unknown_graph_error_unified_across_paths(tmp_path):

@@ -329,12 +329,13 @@ def test_max_batch_threshold_auto_flushes(tmp_path):
     eng.add_graph("g", a, params)
     eng.submit("g", x)
     assert eng.stats()["pending_requests"] == 1
-    eng.submit("g", x * 0.5)  # hits the threshold: batch serves now
+    eng.submit("g", x * 0.5)  # hits the threshold: batch dispatches now
     assert eng.stats()["pending_requests"] == 0
-    assert eng.counters["batches"] == 1
-    # the auto-flushed results await pickup by the next poll/flush
+    assert eng.stats()["inflight_requests"] == 2
+    # the auto-flushed batch is awaited by the next poll/flush
     out = eng.flush()
     assert out["g"].shape == (2, N_NODES, N_CLASSES)
+    assert eng.counters["batches"] == 1
     np.testing.assert_allclose(
         np.asarray(out["g"][1]),
         np.asarray(gcn.forward(params, a, jnp.asarray(x * 0.5))), atol=1e-3)

@@ -25,10 +25,10 @@ def G(gid="g", *, depth=0, ed=float("inf"), ewma=0.0, req_ewma=0.0,
         earliest_deadline=ed, svc_ewma=ewma, svc_req_ewma=req_ewma)
 
 
-def S(graphs, *, now=1000.0):
+def S(graphs, *, now=1000.0, outstanding=0.0):
     return PolicyState(
         now=now, n_devices=1, budget_bytes=64 << 20, used_bytes=(0,),
-        outstanding_s=(0.0,), max_replicas=1, replicate_after_s=0.25,
+        outstanding_s=(outstanding,), max_replicas=1, replicate_after_s=0.25,
         replica_shrink_after=3, max_batch=32,
         graphs={g.graph_id: g for g in graphs})
 
@@ -149,6 +149,21 @@ def test_learned_estimate_drives_shed_decision():
     assert not HeuristicPolicy().shed_on_submit(st, "big", dl).shed
     assert pol.shed_on_submit(st, "big", dl).shed
     assert not pol.shed_on_submit(st, "big", st.now + 2 * true_t).shed
+
+
+@pytest.mark.parametrize("pol", [HeuristicPolicy(), LearnedServiceTimePolicy()])
+def test_outstanding_work_delays_dueness_and_predicted_wait(pol):
+    """Work already in flight on a device is the start of its EDF load
+    map: the same queue is due, and a deadline shed, earlier than on an
+    idle device."""
+    g = G(depth=1, ed=1000.08, ewma=0.02)
+    idle, busy = S([g]), S([g], outstanding=0.05)
+    assert pol.due_queues(idle) == ()         # due at 1000.08 - 1.5·0.02 - 0.01
+    assert pol.due_queues(busy) == ("g",)     # ... at 1000.08 - 1.5·0.07 - 0.01
+    assert pol.predicted_wait(idle, "g") == pytest.approx(0.02)
+    assert pol.predicted_wait(busy, "g") == pytest.approx(0.07)
+    assert not pol.shed_on_submit(idle, "g", 1000.05).shed
+    assert pol.shed_on_submit(busy, "g", 1000.05).shed
 
 
 def test_nonpositive_prediction_falls_back_and_counts():

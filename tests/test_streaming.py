@@ -449,10 +449,10 @@ def test_update_graph_on_evicted_graph_is_host_only(tmp_path):
 
 def _accounting(eng):
     c = eng.counters
-    pending = sum(len(q) for q in eng._pending.values())
+    st = eng.stats()
     lhs = c["submitted"]
     rhs = (c["queue_served"] + c["shed"] + c["rejected"] + c["dropped"]
-           + pending)
+           + st["pending_requests"] + st["inflight_requests"])
     return lhs, rhs
 
 
