@@ -51,7 +51,8 @@ def readings(cell, seed: int, seconds: float, store: Path, *, control: bool,
     control's too."""
     import jax
 
-    from bench import harness, reference
+    from bench import harness
+    from bench.reference import lower_precision, max_rel_err
 
     devs = harness.check_chips(cell.chips) if require_tpu else jax.devices()
     platform = devs[0].platform
@@ -77,12 +78,13 @@ def readings(cell, seed: int, seconds: float, store: Path, *, control: bool,
     s.eng = None
     del sides
     if control:
-        lower = reference.lower_precision(stated)
+        lower = lower_precision(stated)
+        ref = cell.model.reference_logits
         worst = 0.0
         for x in s.xs:
-            want = reference.reference_logits(x, s.weights, s.graph_dev, stated, platform)
-            got = reference.reference_logits(x, s.weights, s.graph_dev, lower, platform)
-            worst = max(worst, reference.max_rel_err(got, want))
+            want = ref(x, s.weights, s.graph_dev, stated, platform)
+            got = ref(x, s.weights, s.graph_dev, lower, platform)
+            worst = max(worst, max_rel_err(got, want))
         out.append({"seed": seed, "side": "control_reference_lower",
                     "reference": "stated", "max_rel_err": worst, "rows": len(s.xs)})
     return out

@@ -4,8 +4,13 @@ the check against the reference, and the metrics.
 Everything particular to a configuration, a traffic mix or a metric is in
 a file of its own, found by name from ``BENCHMARK.json``:
 
-* ``bench/configs/<config>.json`` — sizes, graph, features, stated
-  precision, the limit of the check, and the deployment (engine settings);
+* ``bench/configs/<config>.json`` — the architecture (``arch``), sizes,
+  graph, features, stated precision, the limit of the check, and the
+  deployment (engine settings, and ``add_graph``'s keyword arguments);
+* ``bench/models/<arch>.py`` — the architecture: ``init_weights``, the
+  plain ``reference_logits``, the work counts ``flops_per_request`` and
+  ``batch_bytes``, and ``FORWARD_MODULE``, the program whose executions
+  are the forward. The GCN is ``bench/models/gcn.py``;
 * ``bench/traffic/<mix>.json`` — a generator kind and its parameters, read
   by ``bench/traffic/kinds/<kind>.py``;
 * ``bench/metrics/<metric>.py`` — ``read(run) -> float | None`` for each
@@ -32,8 +37,9 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import devtrace, graph, reference
+from bench import devtrace, graph
 from bench.clock import CompileClock
+from bench.reference import max_rel_err
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -43,8 +49,6 @@ GID = "g"
 KEEP_ROWS = 256
 #: how long past the window's close a request may still be answered
 DRAIN_S = 60.0
-#: the jitted program whose executions are the batched forward
-FORWARD_MODULE = "_batched_forward_body"
 
 
 class NoChip(RuntimeError):
@@ -72,8 +76,8 @@ def load_json(path: Path) -> dict:
 
 
 def load_module(path: Path):
-    """Import one file of the benchmark (a generator kind or a metric) by
-    its path; its name may hold dots."""
+    """Import one file of the benchmark (a generator kind, a metric or an
+    architecture) by its path; its name may hold dots."""
     if not path.is_file():
         raise FileNotFoundError(f"no such benchmark file: {path}")
     spec = importlib.util.spec_from_file_location(f"bench_file_{path.stem}", path)
@@ -89,6 +93,8 @@ class Cell:
     name: str
     chips: int
     config: dict
+    #: ``bench/models/<arch>.py`` of the configuration's ``arch``
+    model: object
     traffic: dict
     kind: object
     end_to_end: list
@@ -99,20 +105,30 @@ def _reports(metric: dict, workload: str) -> bool:
     return "workloads" not in metric or workload in metric["workloads"]
 
 
+def load_model(arch: str):
+    """The architecture module ``bench/models/<arch>.py``."""
+    return load_module(BENCH / "models" / f"{arch}.py")
+
+
 def resolve(workload: str, bm: dict | None = None, root: Path = ROOT) -> Cell:
-    """Find a workload's configuration, traffic, generator kind and metric
-    files by the names ``BENCHMARK.json`` gives."""
+    """Find a workload's configuration, architecture, traffic, generator
+    kind and metric files by the names ``BENCHMARK.json`` gives."""
     bm = load_json(root / "BENCHMARK.json") if bm is None else bm
     cells = {w["name"]: w for w in bm["workloads"]}
     if workload not in cells:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
     w = cells[workload]
     entry = {c["name"]: c for c in bm["configs"]}[w["config"]]
+    config = load_json(root / entry["file"])
+    if "arch" not in config:
+        raise KeyError(f"{root / entry['file']} names no 'arch': add the name of "
+                       f"its bench/models/<arch>.py")
     traffic = load_json(BENCH / "traffic" / f"{w['traffic']}.json")
     return Cell(
         name=workload,
         chips=int(w["chips"]),
-        config=load_json(root / entry["file"]),
+        config=config,
+        model=load_model(config["arch"]),
         traffic=traffic,
         kind=load_module(BENCH / "traffic" / "kinds" / f"{traffic['kind']}.py"),
         end_to_end=[m for m in bm["end_to_end"] if _reports(m, workload)],
@@ -240,14 +256,15 @@ class Reservoir:
 
 def build_engine(cell: Cell, params: dict, coo, store_root: Path, **overrides):
     """A ``GCNServingEngine`` with the deployment's settings (``overrides``
-    replace some), and the graph admitted. Returns it and the admission
-    report."""
+    replace some), and the graph admitted with the deployment's
+    ``add_graph`` keyword arguments. Returns it and the admission report."""
     from repro.serving.gcn_engine import GCNServingEngine
 
     kw = dict(cell.config["deployment"]["engine"], **overrides)
     eng = GCNServingEngine(store_root=store_root, **kw)
     t0 = time.perf_counter()
-    rep = eng.add_graph(GID, coo, params)
+    rep = eng.add_graph(GID, coo, params,
+                        **cell.config["deployment"].get("add_graph", {}))
     c = rep.config
     log(
         f"admitted in {time.perf_counter() - t0:.3f} s (warm_start={rep.warm_start}, "
@@ -285,7 +302,7 @@ def setup(cell: Cell, seed: int, store: Path, **overrides) -> Setup:
     (rows, cols, vals), graph_dev = make_graph(cell.config)
     n = cell.config["sizes"]["num_nodes"]
     coo = csc.coo_from_arrays(rows, cols, vals, (n, n))
-    weights = reference.init_weights(cell.config["sizes"], seed)
+    weights = cell.model.init_weights(cell.config["sizes"], seed)
     eng, rep = build_engine(cell, weights, coo, store, **overrides)
     xs = make_requests(cell.config, seed)
     warm_up(eng, cell, xs, cell.traffic.get("deadline_s"))
@@ -300,7 +317,8 @@ def replicas(eng) -> int:
 def warm_up(eng, cell: Cell, xs: list, deadline_s) -> None:
     """Drive every shape the window will use through ``submit`` + ``poll``:
     first until the graph holds the deployment's replicas, then the traffic
-    kind's own warm-up plan."""
+    kind's own warm-up plan, then one round of each of its sizes with every
+    batch awaited and joined (``flush``)."""
     max_batch = int(cell.config["deployment"]["engine"].get("max_batch", 32))
     want = int(cell.config["deployment"].get("replicas", 1))
     plan = cell.kind.make(cell.traffic, 0, 1.0).warmup(max_batch)
@@ -315,18 +333,23 @@ def warm_up(eng, cell: Cell, xs: list, deadline_s) -> None:
         rounds += 1
     for n in plan:
         _warm_round(eng, xs, n, deadline_s)
+    # a poll that finds every batch in flight settled joins them into one
+    # result, a program per shape that the rounds above reach only by
+    # chance; flush() always joins, so each compiles here, not in the window
+    for n in sorted(set(plan)):
+        _warm_round(eng, xs, n, deadline_s, join=True)
     if replicas(eng) != want:
         raise RuntimeError(f"warm-up left {replicas(eng)} replicas; want {want}")
 
 
-def _warm_round(eng, xs, n: int, deadline_s) -> None:
+def _warm_round(eng, xs, n: int, deadline_s, join: bool = False) -> None:
     import jax
 
     got = 0
     for i in range(n):
         eng.submit(GID, xs[i % len(xs)], deadline_s=deadline_s)
     while got < n:
-        out = eng.poll().get(GID)
+        out = (eng.flush() if join else eng.poll()).get(GID)
         if out is not None:
             got += int(jax.block_until_ready(out).shape[0])
 
@@ -438,10 +461,10 @@ def check(keep: Reservoir, variant: np.ndarray, xs: list, weights: dict,
         for row, rid in enumerate(ids):
             v = int(variant[rid])
             if v not in refs:
-                refs[v] = reference.reference_logits(
+                refs[v] = cell.model.reference_logits(
                     xs[v], weights, graph_dev, precision, platform
                 )
-            err = reference.max_rel_err(got[row], refs[v])
+            err = max_rel_err(got[row], refs[v])
             worst = max(worst, err)
             wrong += int(not err <= limit)
             rows += 1
@@ -478,12 +501,12 @@ def stop_trace(save_to: Path | None = None) -> devtrace.Trace:
     return tr
 
 
-def read_trace(save_to: Path | None, chips: int, require_tpu: bool) -> devtrace.Trace:
+def read_trace(save_to: Path | None, cell: Cell, require_tpu: bool) -> devtrace.Trace:
     """Stop the profiler and load its trace; on a chip, refuse a trace the
     readers could not read (``devtrace.require``)."""
     tr = stop_trace(save_to)
     if require_tpu:
-        devtrace.require(tr, chips, FORWARD_MODULE)
+        devtrace.require(tr, cell.chips, cell.model.FORWARD_MODULE)
     return tr
 
 
@@ -531,7 +554,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *, t_start: float
     setup_s = t0 - t_start
     times, auto, variant = drive(eng, cell, s.xs, seed, seconds, spans, keep, t0)
     comp_s, n_comp, n_hits, names = clock.take()
-    tr = read_trace(save_trace, cell.chips, require_tpu) if trace else None
+    tr = read_trace(save_trace, cell, require_tpu) if trace else None
     stats = eng.stats()
     mem = max(
         int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))

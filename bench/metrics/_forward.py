@@ -1,12 +1,14 @@
 """Executions of the batched forward program in a trace, shared by the
 readers of the batched-forward layer."""
 from bench import devtrace, shapes
-from bench.harness import FORWARD_MODULE
 
 
 def runs(run):
-    """``(device, start_ns, end_ns)`` of every forward execution traced."""
-    return devtrace.module_runs(run.trace, FORWARD_MODULE) if run.trace else []
+    """``(device, start_ns, end_ns)`` of every execution of the cell's
+    forward program (its model's ``FORWARD_MODULE``) traced."""
+    if not run.trace:
+        return []
+    return devtrace.module_runs(run.trace, run.cell.model.FORWARD_MODULE)
 
 
 def median_ms(run):
@@ -26,15 +28,14 @@ def least_and_spent(run):
     r = runs(run)
     if not r or run.peak is None:
         return None
-    cfg = run.cell.config
+    sizes, model = run.cell.config["sizes"], run.cell.model
     served = int(run.stats["requests"])
-    flops = shapes.flops_per_request(cfg["sizes"], run.nnz) * served
+    flops = model.flops_per_request(sizes, run.nnz) * served
     # bytes are affine in the batch size, so the executions' total is the
     # per-execution constant once per execution plus the per-request part
-    per_req = shapes.batch_bytes(cfg["sizes"], run.nnz, 1) - shapes.batch_bytes(
-        cfg["sizes"], run.nnz, 0
-    )
-    nbytes = per_req * served + shapes.batch_bytes(cfg["sizes"], run.nnz, 0) * len(r)
+    fixed = model.batch_bytes(sizes, run.nnz, 0)
+    per_req = model.batch_bytes(sizes, run.nnz, 1) - fixed
+    nbytes = per_req * served + fixed * len(r)
     least, bound = shapes.least_seconds(flops, nbytes, run.peak)
     spent = sum(e - s for _, s, e in r) * 1e-9
     return least, bound, spent

@@ -1,8 +1,8 @@
 """Batched forward, as one program until the program names its kernels:
 the least time of the traced executions over their device time, in %. The
-least time is max(FLOPs / peak FLOP/s, bytes / peak bytes/s) from
-``bench/shapes.py``; the log line of the run says which bound it
-(device trace)."""
+least time is max(FLOPs / peak FLOP/s, bytes / peak bytes/s), with the
+counts of the cell's ``bench/models/<arch>.py`` and ``bench/shapes.py``'s
+arithmetic; the log line of the run says which bound it (device trace)."""
 from bench.metrics import _forward
 
 

@@ -1,12 +1,11 @@
-"""The benchmark's plain GCN: weights from the seed, and the reference
-forward that decides ``correct``.
+"""The benchmark's precision arithmetic, shared by every architecture's
+reference (``bench/models/<arch>.py``): the weight seed drawn from
+``--seed``, the operand types of a stated precision, rounding to them, and
+the comparison that decides ``correct``.
 
-It imports nothing of the program and takes nothing the program made. The
-reference is the 2-layer GCN of Kipf & Welling in straightforward
-``jax.numpy``: per layer ``H' = A · (H · W)``, A·(XW) as a ``segment_sum``
-over the COO, ReLU between layers, no ReLU on the logits. Every matmul runs
-at "highest" precision; the precision that the configuration states is
-applied to the operands explicitly (``precision`` block of a config):
+A reference runs every matmul at "highest" precision and applies the
+precision that the configuration states to the operands explicitly
+(``precision`` block of a config):
 
 * ``"xw": "default"`` — X·W at the device's default matmul precision. On a
   TPU that rounds both operands to bfloat16 and accumulates in float32, so
@@ -15,11 +14,11 @@ applied to the operands explicitly (``precision`` block of a config):
 * ``"aggregate": "float32"`` — A·(XW) summed in float32.
 
 ``lower_precision`` gives the configuration one step down (everything that
-was float32 in bfloat16), for the control of ``bench/control.py``.
+was float32 in bfloat16), for the control of ``bench/control.py``. The GCN
+weights and reference forward that lived here are in
+``bench/models/gcn.py``.
 """
 from __future__ import annotations
-
-import functools
 
 import numpy as np
 
@@ -30,28 +29,6 @@ DEFAULT_MATMUL_OPERAND = {"tpu": "bfloat16", "cpu": "float32"}
 def weight_key(seed: int) -> int:
     """A 31-bit PRNG seed for the weights, drawn from ``--seed``."""
     return int(np.random.default_rng([seed, 1]).integers(0, 2**31 - 1))
-
-
-def init_weights(sizes: dict, seed: int) -> dict:
-    """Glorot-uniform float32 weights ``w0..w{L-1}``, made on the device in
-    one jitted call from the seed."""
-    import jax
-    import jax.numpy as jnp
-
-    from bench.shapes import layer_dims
-
-    dims = tuple(layer_dims(sizes))
-
-    @jax.jit
-    def make(key):
-        out = {}
-        for i, (din, dout) in enumerate(dims):
-            key, sub = jax.random.split(key)
-            lim = float(np.sqrt(6.0 / (din + dout)))
-            out[f"w{i}"] = jax.random.uniform(sub, (din, dout), jnp.float32, -lim, lim)
-        return out
-
-    return jax.block_until_ready(make(jax.random.PRNGKey(weight_key(seed))))
 
 
 def operand_types(precision: dict, platform: str) -> tuple[str, str, str]:
@@ -86,43 +63,6 @@ def round_to(x, dtype):
     u = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
     u = (u + jnp.uint32(0x7FFF) + ((u >> 16) & 1)) & jnp.uint32(0xFFFF0000)
     return jax.lax.bitcast_convert_type(u, jnp.float32)
-
-
-@functools.lru_cache(maxsize=8)
-def _forward(n: int, n_layers: int, storage: str, xw: str, agg: str):
-    import jax
-    import jax.numpy as jnp
-
-    st, op, acc = jnp.dtype(storage), jnp.dtype(xw), jnp.dtype(agg)
-
-    @jax.jit
-    def fwd(x, weights, rows, cols, vals):
-        h = x.astype(st)
-        for i in range(n_layers):
-            w = weights[f"w{i}"].astype(st)
-            with jax.default_matmul_precision("highest"):
-                xw_ = jnp.matmul(
-                    round_to(h.astype(jnp.float32), op),
-                    round_to(w.astype(jnp.float32), op),
-                ).astype(st)
-            msg = xw_[cols].astype(acc) * vals.astype(st).astype(acc)[:, None]
-            h = jax.ops.segment_sum(msg, rows, num_segments=n).astype(st)
-            if i < n_layers - 1:
-                h = jax.nn.relu(h)
-        return h.astype(jnp.float32)
-
-    return fwd
-
-
-def reference_logits(x, weights, graph, precision: dict, platform: str):
-    """Logits ``[n, classes]`` (NumPy float32) of one request's features
-    ``x`` under the stated ``precision``."""
-    import jax
-
-    storage, xw, agg = operand_types(precision, platform)
-    fwd = _forward(graph["n"], len(weights), storage, xw, agg)
-    out = fwd(x, weights, graph["rows"], graph["cols"], graph["vals"])
-    return np.asarray(jax.device_get(out))
 
 
 def max_rel_err(got: np.ndarray, ref: np.ndarray) -> float:

@@ -1,12 +1,12 @@
 """``BENCHMARK.json`` and the files it names: every workload resolves its
-configuration, traffic mix, generator kind and metric readers by name, and
-the file keeps the shape the benchmark's runner relies on."""
+configuration, architecture, traffic mix, generator kind and metric readers
+by name, and the file keeps the shape the benchmark's runner relies on."""
 import json
 import re
 
 import pytest
 
-from bench import harness, shapes
+from bench import harness
 
 BM = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
 WORKLOADS = [w["name"] for w in BM["workloads"]]
@@ -17,6 +17,10 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 def test_workload_resolves_its_files_by_name(workload):
     cell = harness.resolve(workload, BM)
     assert cell.config["deployment"]["chips"] == cell.chips
+    for name in ("init_weights", "reference_logits", "flops_per_request",
+                 "batch_bytes"):
+        assert callable(getattr(cell.model, name)), name
+    assert isinstance(cell.model.FORWARD_MODULE, str) and cell.model.FORWARD_MODULE
     gen = cell.kind.make(cell.traffic, 1, 1.0)
     assert callable(gen.take) and callable(gen.warmup)
     for m in cell.end_to_end + cell.per_layer:
@@ -62,5 +66,22 @@ def test_config_files_match_their_flop_counts(entry):
         seed=cfg["graph"]["seed"], max_degree=cfg["graph"]["max_degree"],
     )
     assert rows.shape[0] == cfg["graph"]["nnz"]
-    assert shapes.flops_per_request(cfg["sizes"], rows.shape[0]) > 0
+    model = harness.load_model(cfg["arch"])
+    assert model.flops_per_request(cfg["sizes"], rows.shape[0]) > 0
     assert isinstance(cfg["correct"]["max_rel_err"], float)
+
+
+@pytest.mark.parametrize("arch, says", [
+    ("no-such-arch", "bench/models/no-such-arch.py"),
+    (None, "names no 'arch'"),
+], ids=["no-file", "no-arch"])
+def test_a_config_must_name_an_architecture_that_has_a_file(tmp_path, arch, says):
+    cfg = json.loads((harness.ROOT / BM["configs"][0]["file"]).read_text())
+    del cfg["arch"]
+    if arch is not None:
+        cfg["arch"] = arch
+    (tmp_path / "cfg.json").write_text(json.dumps(cfg))
+    bm = dict(BM, configs=[dict(BM["configs"][0], file="cfg.json")],
+              workloads=[dict(BM["workloads"][0], name="w")])
+    with pytest.raises((KeyError, FileNotFoundError), match=re.escape(says)):
+        harness.resolve("w", bm, root=tmp_path)

@@ -4,8 +4,9 @@ Each test drives the rest of a run on the CPU at a tiny size — the look for
 a chip skipped — with the served path broken underneath, and sees
 ``correct`` come out false: an answer altered where it is produced, half
 of a batch left out, and (on four virtual devices) the exchange of a
-replica's chunk left out. The sound run reads true, and the control (the
-program's own bfloat16 accumulation) reads above the limit.
+replica's chunk left out; and a reference that reads twice the logits. The
+sound run reads true, and the control (the program's own bfloat16
+accumulation) reads above the limit.
 """
 import json
 import os
@@ -16,7 +17,7 @@ import textwrap
 import jax.numpy as jnp
 import pytest
 
-from bench import control
+from bench import control, harness
 from bench.harness import ROOT
 from bench.tests import tiny
 from repro.core import executor
@@ -59,6 +60,44 @@ def test_half_of_the_batch_left_out_is_not_correct(store, monkeypatch):
     monkeypatch.setattr(executor, "_batched_forward_jit", half)
     line = tiny.run(tiny.cell("closed", outstanding=8), store)
     assert not line["correct"] and line["failed"] > 0
+
+
+def test_a_model_whose_reference_doubles_the_logits_is_not_correct(store, tmp_path):
+    """The check takes its reference from the cell's model file: a copy of
+    ``bench/models/gcn.py`` whose reference doubles the logits fails a sound
+    run of the program."""
+    src = (harness.BENCH / "models" / "gcn.py").read_text()
+    path = tmp_path / "gcn_doubled_reference.py"
+    path.write_text(src + "\n\n_reference_logits = reference_logits\n\n\n"
+                    "def reference_logits(*args):\n"
+                    "    return 2 * _reference_logits(*args)\n")
+    c = tiny.cell("closed", model=harness.load_module(path), outstanding=8)
+    line = tiny.run(c, store)
+    assert not line["correct"] and line["failed"] > 0
+    assert line["checks"]["max_rel_err"]["value"] == pytest.approx(0.5, rel=1e-3)
+
+
+def test_warm_up_compiles_the_join_of_settled_batches(store, monkeypatch):
+    """A poll that finds both batches of the closed loop settled joins them
+    into one result. Set-up compiles that join even where no warm-up poll
+    finds a later batch settled (as on a chip, where the next batch's copy
+    is still running), so serving such a poll compiles nothing."""
+    import jax
+
+    from bench.clock import CompileClock
+    from repro.serving.gcn_engine import GCNServingEngine
+
+    jax.clear_caches()
+    monkeypatch.setattr(GCNServingEngine, "_settled", staticmethod(lambda b: False))
+    c = tiny.cell("closed", outstanding=8)
+    s = harness.setup(c, 11, store)
+    clock = CompileClock()
+    for i in range(8):
+        s.eng.submit(harness.GID, s.xs[i % len(s.xs)], deadline_s=0)
+    out = s.eng.flush()[harness.GID]
+    assert out.shape[0] == 8
+    _, compiles, _, names = clock.take()
+    assert compiles == 0, names
 
 
 def test_control_reads_above_the_limit_and_the_program_below(store):

@@ -8,6 +8,7 @@ import pytest
 from bench import devtrace, harness
 
 MS = 1_000_000  # nanoseconds
+FORWARD = harness.load_model("gcn").FORWARD_MODULE
 
 
 def _trace():
@@ -61,7 +62,8 @@ def test_op_seconds_and_breakdown():
 
 def _run(tr, chips=2):
     cfg = harness.load_json(harness.BENCH / "configs" / "gcn-pubmed.json")
-    cell = types.SimpleNamespace(chips=chips, config=cfg)
+    cell = types.SimpleNamespace(chips=chips, config=cfg,
+                                 model=harness.load_model(cfg["arch"]))
     done = np.array([0.05, 0.09, 0.2])
     return types.SimpleNamespace(
         trace=tr, cell=cell, nnz=cfg["graph"]["nnz"], seconds=0.1,
@@ -77,15 +79,28 @@ def test_per_layer_readers_reduce_the_trace():
     fwd = harness.metric_reader("forward_device_ms.backlog").read(run)
     assert fwd == pytest.approx(20.0)  # median of 20, 10 and 50 ms
     roof = harness.metric_reader("forward_roofline").read(run)
-    from bench import shapes
-
-    sizes, nnz = run.cell.config["sizes"], run.nnz
-    per_req = shapes.batch_bytes(sizes, nnz, 1) - shapes.batch_bytes(sizes, nnz, 0)
-    nbytes = 24 * per_req + 3 * shapes.batch_bytes(sizes, nnz, 0)
+    sizes, nnz, gcn = run.cell.config["sizes"], run.nnz, run.cell.model
+    per_req = gcn.batch_bytes(sizes, nnz, 1) - gcn.batch_bytes(sizes, nnz, 0)
+    nbytes = 24 * per_req + 3 * gcn.batch_bytes(sizes, nnz, 0)
     assert roof == pytest.approx(100 * nbytes / 819e9 / 0.08)
     mfu = harness.metric_reader("mfu").read(run)
     assert mfu == pytest.approx(
         100 * 2 * 321_909_024 / (0.1 * 2 * 197e12))
+
+
+def test_a_model_that_doubles_its_flops_doubles_mfu(tmp_path):
+    """The readers take the work counts from the cell's model file: a copy
+    of ``bench/models/gcn.py`` whose ``flops_per_request`` doubles reads
+    twice the ``mfu`` of the GCN on the same run."""
+    src = (harness.BENCH / "models" / "gcn.py").read_text()
+    path = tmp_path / "gcn_doubled_flops.py"
+    path.write_text(src + "\n\n_flops = flops_per_request\n\n\n"
+                    "def flops_per_request(sizes, nnz):\n"
+                    "    return 2 * _flops(sizes, nnz)\n")
+    run = _run(_trace())
+    mfu = harness.metric_reader("mfu").read(run)
+    run.cell.model = harness.load_module(path)
+    assert harness.metric_reader("mfu").read(run) == pytest.approx(2 * mfu)
 
 
 def test_readers_find_nothing_without_a_trace():
@@ -96,7 +111,7 @@ def test_readers_find_nothing_without_a_trace():
 
 
 def test_require_accepts_a_readable_trace():
-    devtrace.require(_trace(), 2, harness.FORWARD_MODULE)
+    devtrace.require(_trace(), 2, FORWARD)
 
 
 @pytest.mark.parametrize("broken, says", [
@@ -110,8 +125,8 @@ def test_require_refuses_a_trace_the_readers_cannot_read(broken, says):
         tr.devices[1].ops = []
     elif broken == "no_forward":
         for d in tr.devices:
-            d.modules = [m for m in d.modules if harness.FORWARD_MODULE not in m[2]]
+            d.modules = [m for m in d.modules if FORWARD not in m[2]]
     else:
         tr.devices = tr.devices[:1]
     with pytest.raises(ValueError, match=says):
-        devtrace.require(tr, 2, harness.FORWARD_MODULE)
+        devtrace.require(tr, 2, FORWARD)

@@ -1,10 +1,15 @@
 """The benchmark's yardstick on the CPU: its generators are deterministic
-per seed, its FLOP and byte counts give the published sizes' numbers, and
-its reference agrees with a dense ``A @ (X @ W)``."""
+per seed, the GCN's FLOP and byte counts (``bench/models/gcn.py``) give the
+published sizes' numbers, and its reference agrees with a dense
+``A @ (X @ W)``."""
+import hashlib
+
 import numpy as np
 import pytest
 
 from bench import graph, harness, reference, shapes
+
+GCN = harness.load_model("gcn")
 
 PUBMED = {"num_nodes": 19717, "num_features": 500, "hidden": 16, "num_classes": 3,
           "n_layers": 2}
@@ -68,12 +73,12 @@ def test_closed_loop_keeps_its_outstanding_count():
 
 
 def test_flops_per_request_at_published_sizes():
-    assert shapes.flops_per_request(PUBMED, 119584) == 321_909_024
-    assert shapes.flops_per_request(CORA, 14770) == 125_464_060
+    assert GCN.flops_per_request(PUBMED, 119584) == 321_909_024
+    assert GCN.flops_per_request(CORA, 14770) == 125_464_060
 
 
 def test_batch_bytes_and_roofline_bound_at_pubmed_batch_8():
-    nbytes = shapes.batch_bytes(PUBMED, 119584, 8)
+    nbytes = GCN.batch_bytes(PUBMED, 119584, 8)
     assert nbytes == 319_468_112
     peak = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
     least, bound = shapes.least_seconds(8 * 321_909_024, nbytes, peak)
@@ -106,10 +111,10 @@ def test_reference_agrees_with_dense_a_x_w():
     dense, g = _dense_graph()
     sizes = {"num_nodes": 60, "num_features": 24, "hidden": 16, "num_classes": 5,
              "n_layers": 2}
-    w = reference.init_weights(sizes, 9)
+    w = GCN.init_weights(sizes, 9)
     x = graph.sparse_features(60, 24, 0.2, seed=4)
     exact = {"storage": "float32", "xw": "float32", "aggregate": "float32"}
-    got = reference.reference_logits(x, w, g, exact, "cpu")
+    got = GCN.reference_logits(x, w, g, exact, "cpu")
     w0, w1 = (np.asarray(w[k], np.float64) for k in ("w0", "w1"))
     want = dense @ (np.maximum(dense @ (x.astype(np.float64) @ w0), 0) @ w1)
     assert reference.max_rel_err(got, want) < 1e-5
@@ -129,11 +134,11 @@ def test_lower_precision_reference_reads_far_above_rounding():
     _, g = _dense_graph(n=200, seed=2)
     sizes = {"num_nodes": 200, "num_features": 64, "hidden": 16, "num_classes": 3,
              "n_layers": 2}
-    w = reference.init_weights(sizes, 3)
+    w = GCN.init_weights(sizes, 3)
     x = graph.sparse_features(200, 64, 0.1, seed=1)
     prec = {"storage": "float32", "xw": "default", "aggregate": "float32"}
-    want = reference.reference_logits(x, w, g, prec, "cpu")
-    low = reference.reference_logits(x, w, g, reference.lower_precision(prec), "cpu")
+    want = GCN.reference_logits(x, w, g, prec, "cpu")
+    low = GCN.reference_logits(x, w, g, reference.lower_precision(prec), "cpu")
     assert reference.max_rel_err(low, want) > 1e-3
 
 
@@ -149,11 +154,11 @@ def test_max_rel_err_refuses_wrong_shapes_and_non_finite():
 def test_weights_are_glorot_and_fixed_by_the_seed():
     sizes = {"num_nodes": 10, "num_features": 500, "hidden": 16, "num_classes": 3,
              "n_layers": 2}
-    a, b = reference.init_weights(sizes, 2**33), reference.init_weights(sizes, 2**33)
+    a, b = GCN.init_weights(sizes, 2**33), GCN.init_weights(sizes, 2**33)
     np.testing.assert_array_equal(np.asarray(a["w0"]), np.asarray(b["w0"]))
     assert a["w0"].shape == (500, 16) and a["w1"].shape == (16, 3)
     assert float(np.abs(np.asarray(a["w0"])).max()) <= np.sqrt(6 / 516)
-    c = reference.init_weights(sizes, 2**33 + 1)
+    c = GCN.init_weights(sizes, 2**33 + 1)
     assert not np.array_equal(np.asarray(a["w0"]), np.asarray(c["w0"]))
 
 
@@ -165,3 +170,28 @@ def test_bf16_rounding_on_the_bits_matches_a_cast():
     want = np.asarray(jnp.asarray(x).astype(jnp.bfloat16).astype(jnp.float32))
     np.testing.assert_array_equal(got, want)
     assert reference.round_to(x, "float32") is x
+
+
+def test_gcn_weights_and_logits_are_those_of_before_the_move():
+    """Digests taken from the GCN weights and reference of
+    ``bench/reference.py`` before they moved to ``bench/models/gcn.py``:
+    the tiny fixture at one seed, the stated precision as on the CPU and as
+    on a TPU (X·W operands rounded to bfloat16)."""
+    cfg = harness.load_json(harness.BENCH / "tests" / "fixtures" / "tiny.json")
+    seed = 2**33 + 7
+    w = GCN.init_weights(cfg["sizes"], seed)
+    h = hashlib.sha256()
+    for k in sorted(w):
+        a = np.asarray(w[k])
+        h.update(k.encode() + str(a.shape).encode() + a.tobytes())
+    assert h.hexdigest() == (
+        "fb610ecb4dff236346227d6996bfaa98647d80bd6bdde584a5e5741f9e066c18")
+    _, g = harness.make_graph(cfg)
+    x = harness.make_requests(cfg, seed)[0]
+    for platform, digest in (
+        ("cpu", "c5bc1da19c51db7c11e8464801aa0c1d258df7bf55c5dd100917a531ddf86cd2"),
+        ("tpu", "4f98b9cf2cfcc122e59be6e6809669326b01c77625776f054359ffc87e8c725e"),
+    ):
+        out = GCN.reference_logits(x, w, g, cfg["precision"], platform)
+        assert out.dtype == np.float32 and out.shape == (300, 3)
+        assert hashlib.sha256(out.tobytes()).hexdigest() == digest, platform

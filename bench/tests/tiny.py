@@ -17,12 +17,18 @@ def config(**deployment) -> dict:
     return cfg
 
 
-def cell(kind: str, cfg: dict | None = None, chips: int = 1, **traffic) -> harness.Cell:
+def cell(kind: str, cfg: dict | None = None, chips: int = 1, model=None,
+         **traffic) -> harness.Cell:
+    """A cell of the tiny configuration (or ``cfg``) under a traffic ``kind``;
+    ``model`` takes the place of the architecture module its ``arch``
+    names."""
+    cfg = config() if cfg is None else cfg
     traffic = dict({"kind": kind, "deadline_s": 0}, **traffic)
     return harness.Cell(
         name=f"tiny-{kind}",
         chips=chips,
-        config=config() if cfg is None else cfg,
+        config=cfg,
+        model=harness.load_model(cfg["arch"]) if model is None else model,
         traffic=traffic,
         kind=harness.load_module(harness.BENCH / "traffic" / "kinds" / f"{kind}.py"),
         end_to_end=[],
