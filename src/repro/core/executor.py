@@ -10,7 +10,9 @@ machinery** for that plan:
   once at construction and serves ``spmm(b) = A @ b`` (fused-gather VPU
   routing or step-scanned one-hot MXU routing, chosen by
   ``select_routing``'s cost model), a whole-GCN ``forward`` and its
-  request-batched ``forward_batch``. The compiled programs take the schedule
+  request-batched ``forward_batch``, and the GAT's edge-attention forward
+  (``gat_forward_batch``, ``core.gat``) over the same gather slot stream.
+  The compiled programs take the schedule
   arrays as arguments, keyed on a static ``Geometry``, so they never hold a
   copy of the schedule and executors of equal geometry share them.
 * ``ShardedScheduleExecutor`` runs the same plan across a 1-D device mesh
@@ -55,6 +57,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.core.gat import NEGATIVE_SLOPE
 from repro.core.schedule import Schedule
 from repro.lazyexports import lazy_exports
 from repro.sharding.schedule_shard import shard_schedule
@@ -467,11 +470,123 @@ def _batched_forward_body(
     return jax.vmap(fwd, in_axes=(None, None, 0))(ops, params, xs)
 
 
+class UnsupportedRoutingError(ValueError):
+    """An architecture's body has no implementation on this executor's
+    routing or mesh: the GAT's attention body runs on the gather routing
+    of one device."""
+
+
+def _over_chunks(body, init, *, n_chunks: int):
+    """``body(i, carry)`` over the slot stream's chunks; a single chunk is
+    one straight-line call, as ``_gather_partial`` does."""
+    if n_chunks == 1:
+        return body(0, init)
+    return jax.lax.fori_loop(0, n_chunks, body, init)
+
+
+def _attention_partial(gcol, tgt, val, s_row, s_col, wh, *, g: Geometry, layer: int):
+    """Edge attention over one slot stream ``[n_chunks, chunk]``, the GAT's
+    A-side of a layer: per slot and head the score ``LeakyReLU(s_row[tgt] +
+    s_col[gcol])``, a softmax over each output row, and the sum of ``Wh``
+    rows weighted by it. ``s_row`` ``[m, K]`` is in the stream's row order,
+    ``s_col`` ``[n, K]`` and ``wh`` ``[n, K·F]`` in node order; returns
+    ``[m, K, F]`` in the stream's row order.
+
+    The balanced schedule splits a heavy row over several PEs and steps,
+    so its slots lie anywhere in the stream: the row max is one
+    scatter-max over every chunk before any ``exp`` (a pass of its own),
+    and the exp-weighted ``[Wh ‖ 1]`` rows are one scatter-add, whose last
+    column per head is the softmax's denominator. Slots with ``val == 0``
+    — chunk padding, and empty PE slots whose clamped target is a real
+    row — are masked out of both."""
+    acc = g.acc
+    k = s_row.shape[1]
+    f = wh.shape[1] // k
+
+    def scores(i):
+        with jax.named_scope(f"l{layer}.score"):
+            e = s_row[tgt[i]] + s_col[gcol[i]]  # [chunk, K]
+            return jnp.where(e > 0, e, NEGATIVE_SLOPE * e), (val[i] != 0)[:, None]
+
+    def row_max(i, top):
+        e, live = scores(i)
+        with jax.named_scope(f"l{layer}.softmax"):
+            return top.at[tgt[i]].max(jnp.where(live, e, -jnp.inf))
+
+    top = jnp.full((g.m, k), -jnp.inf, acc)
+    top = _over_chunks(row_max, top, n_chunks=g.n_chunks)
+
+    def weighted_sum(i, sums):
+        e, live = scores(i)
+        with jax.named_scope(f"l{layer}.softmax"):
+            p = jnp.where(live, jnp.exp(e - top[tgt[i]]), 0.0)  # [chunk, K]
+        with jax.named_scope(f"l{layer}.aggregate"):
+            msg = p[:, :, None] * jnp.take(wh, gcol[i], axis=0).reshape(-1, k, f)
+            rows = jnp.concatenate([msg, p[:, :, None]], axis=2)
+            return sums.at[tgt[i]].add(rows.reshape(-1, k * (f + 1)))
+
+    sums = jnp.zeros((g.m, k * (f + 1)), acc)
+    sums = _over_chunks(weighted_sum, sums, n_chunks=g.n_chunks)
+    with jax.named_scope(f"l{layer}.aggregate"):
+        sums = sums.reshape(g.m, k, f + 1)
+        den = sums[:, :, f:]
+        return sums[:, :, :f] / jnp.where(den > 0, den, 1.0)
+
+
+def _gat_forward_body(
+    ops: dict, params: dict, x: jax.Array, *, geom: Geometry
+) -> jax.Array:
+    """Whole-GAT logits (``core.gat``): per layer ``l<i>.xw`` X·W, then
+    ``l<i>.score``, ``l<i>.softmax`` and ``l<i>.aggregate`` over the gather
+    slot stream (``_attention_partial``), then ``l<i>.elu`` on the
+    concatenated heads or ``l<i>.mean`` of the last layer's heads. Scores,
+    softmax and sums run in ``geom.acc``."""
+    acc = geom.acc
+    n_layers = len(params) // 2
+    h = x
+    for i in range(n_layers):
+        att = params[f"a{i}"]
+        k, f = att.shape[0], att.shape[1] // 2
+        with jax.named_scope(f"l{i}.xw"):
+            wh = (h @ params[f"w{i}"]).astype(acc)
+        with jax.named_scope(f"l{i}.score"):
+            whk = wh.reshape(-1, k, f)
+            s_row = (whk * att[:, :f].astype(acc)).sum(-1)
+            s_col = (whk * att[:, f:].astype(acc)).sum(-1)
+            if geom.unperm:
+                # a reordered stream's targets are permuted rows: row
+                # ``unperm[v]`` holds node v, so its own score moves there
+                s_row = jnp.zeros_like(s_row).at[ops["unperm"]].set(s_row)
+        out = _attention_partial(
+            ops["gcol"], ops["tgt"], ops["val"], s_row, s_col, wh, g=geom, layer=i
+        )
+        if geom.unperm:
+            with jax.named_scope("unperm"):
+                out = jnp.take(out, ops["unperm"], axis=0)
+        out = out.astype(x.dtype)
+        if i < n_layers - 1:
+            with jax.named_scope(f"l{i}.elu"):
+                h = jax.nn.elu(out.reshape(out.shape[0], k * f))
+        else:
+            with jax.named_scope(f"l{i}.mean"):
+                h = out.mean(axis=1)
+    return h
+
+
+def _batched_gat_body(
+    geom: Geometry, ops: dict, params: dict, xs: jax.Array
+) -> jax.Array:
+    """``_gat_forward_body`` vmapped over a leading request axis of ``xs``."""
+    fwd = functools.partial(_gat_forward_body, geom=geom)
+    return jax.vmap(fwd, in_axes=(None, None, 0))(ops, params, xs)
+
+
 # module-level jits keyed on the static geometry: executors of one geometry
 # share their compiled programs
 _spmm_jit = jax.jit(_spmm_body, static_argnames="geom")
 _forward_jit = jax.jit(_forward_body, static_argnames="geom")
 _batched_forward_jit = jax.jit(_batched_forward_body, static_argnames="geom")
+_batched_gat_jit = jax.jit(_batched_gat_body, static_argnames="geom")
 
 
 class _ExecutorBase:
@@ -567,6 +682,24 @@ class _ExecutorBase:
         ``params`` and ``xs`` must already live on this executor's
         placement."""
         return _batched_forward_jit(self.geometry, self.operands, params, xs)
+
+    def _check_attention(self) -> None:
+        if self.routing != GATHER or self.mesh is not None:
+            where = (
+                "a device mesh" if self.mesh is not None else f"{self.routing!r} routing"
+            )
+            raise UnsupportedRoutingError(
+                f"the GAT's attention body runs on the gather routing of one "
+                f"device, not on {where}"
+            )
+
+    def gat_forward_batch(self, params: dict, xs: jax.Array) -> jax.Array:
+        """Whole-GAT logits (``core.gat`` parameters) over a leading request
+        axis, through this executor's slot stream inside one jit — the
+        serving engine's batch dispatch for a GAT graph. ``params`` and
+        ``xs`` must already live on this executor's placement."""
+        self._check_attention()
+        return _batched_gat_jit(self.geometry, self.operands, params, xs)
 
     @property
     def utilization(self) -> float:

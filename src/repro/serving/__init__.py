@@ -44,6 +44,7 @@ __all__ = [
     "ShedDecision",
     "SubmitTicket",
     "UnknownGraphError",
+    "UnsupportedArchitectureError",
     "UpdateReport",
 ]
 
@@ -76,6 +77,7 @@ __getattr__, __dir__ = lazy_exports(
         "UnknownGraphError": "repro.serving.errors",
         "RequestFailure": "repro.serving.errors",
         "FlushError": "repro.serving.errors",
+        "UnsupportedArchitectureError": "repro.serving.errors",
     },
     globals(),
 )

@@ -69,3 +69,15 @@ class FlushError(ServingError, RuntimeError):
         )
         self.failures = failures
         self.partial = partial
+
+
+class UnsupportedArchitectureError(ServingError, ValueError):
+    """``add_graph`` was asked for an architecture it does not serve, or for
+    one on a route or routing that architecture has no body for (the GAT
+    runs on the gather routing of one device: not on the sharded route,
+    and not on one-hot routing). Raised before anything is tuned or
+    uploaded; there is no fallback. Subclasses ``ValueError``."""
+
+    def __init__(self, arch: str, why: str):
+        super().__init__(f"architecture {arch!r}: {why}")
+        self.arch = arch

@@ -91,8 +91,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import csc as fmt
+from repro.core import gat
 from repro.core.executor import (
     FAULTS,
+    GATHER,
     ScheduleExecutor,
     ShardedScheduleExecutor,
     release_device_steps,
@@ -110,6 +112,7 @@ from repro.serving.errors import (
     RequestFailure,
     ServingError,
     UnknownGraphError,
+    UnsupportedArchitectureError,
 )
 from repro.serving.placement import REPLICATED, SHARDED, SINGLE, MeshPlacer, Placement
 from repro.serving.policy import (
@@ -152,6 +155,10 @@ _sleep = time.sleep
 #: the p50/p95/p99 percentiles in ``stats()``.
 _LAT_RESERVOIR = 65536
 
+#: the architectures ``add_graph`` serves: the GCN (``core.gcn``,
+#: parameters ``w<i>``) and the GAT (``core.gat``, ``w<i>`` and ``a<i>``)
+ARCHS = ("gcn", "gat")
+
 #: batches of one graph left in flight unawaited at most: two keep the
 #: next batch's host→device copies overlapping the running forward; a
 #: third dispatch first awaits the oldest, so a caller that submits
@@ -171,6 +178,7 @@ __all_reexports__ = (
     "UnknownGraphError",
     "RequestFailure",
     "FlushError",
+    "UnsupportedArchitectureError",
 )
 
 
@@ -239,7 +247,8 @@ class _Request:
 class _Unit:
     """One device-resident serving clone of a graph (the primary or a
     replica): a pinned executor, the uploaded weights, and the executor's
-    batched whole-GCN forward (``forward_batch``) that serves batches
+    batched forward of the graph's architecture (``forward_batch``, or
+    ``gat_forward_batch`` for a GAT) that serves batches
     through them."""
     device_index: Optional[int]  # None: sharded (spans the mesh)
     executor: object
@@ -284,6 +293,11 @@ class _Resident:
     sched: Schedule  # host copy — survives eviction
     params_host: dict  # host copy — survives eviction
     params: Optional[dict] = None  # device weight tree; guarded-by: _swap_lock
+    #: ``"gcn"`` or ``"gat"`` (``ARCHS``): which forward the units run
+    arch: str = "gcn"
+    #: a GAT's heads summed over its layers (0 for a GCN): the edge-heads
+    #: of one request are this times the graph's nnz
+    heads: int = 0
     #: ScheduleExecutor or ShardedScheduleExecutor (None while evicted)
     executor: Optional[object] = None  # guarded-by: _swap_lock
     fwd: Optional[callable] = None  # batched fwd; guarded-by: _swap_lock
@@ -590,6 +604,9 @@ class GCNServingEngine:
             "h2d_bytes": 0,
             # batches dispatched while an earlier batch was still in flight
             "overlapped_batches": 0,
+            # a GAT's non-zeros x heads x layers x requests served, counted
+            # when the batch is awaited (0 for GCN graphs)
+            "attention_edge_heads": 0,
         }
 
     # ---- policy state snapshot ---------------------------------------------
@@ -654,12 +671,45 @@ class GCNServingEngine:
 
     # ---- admission ---------------------------------------------------------
 
-    def _estimate_bytes(self, a: fmt.COO, params: dict) -> int:
+    def _estimate_bytes(self, a: fmt.COO, params: dict, arch: str = "gcn") -> int:
         """Pre-tune footprint estimate (schedule + weights) — routes giant
-        graphs to the sharded path before any sweep runs."""
+        graphs to the sharded path before any sweep runs. A GAT adds its
+        attention's per-slot working set at its widest layer: per slot and
+        head the gathered ``Wh`` row, its softmax weight and its score, in
+        float32, over the slot count the padding slack allows."""
         nnz = int(np.asarray(a.row).shape[0])
         weights = sum(int(np.asarray(w).nbytes) for w in jax.tree.leaves(params))
-        return nnz * _BYTES_PER_NNZ_EST + weights
+        est = nnz * _BYTES_PER_NNZ_EST + weights
+        if arch == "gat":
+            slots = nnz * _BYTES_PER_NNZ_EST // 12
+            atts = [np.shape(params[f"a{i}"]) for i in range(len(params) // 2)]
+            est += slots * 4 * max(k * (f2 // 2 + 2) for k, f2 in atts)
+        return est
+
+    def _tune_route(self, arch: str, a: fmt.COO, sharded: bool) -> Tuple[dict, int]:
+        """The autotune kwargs and ``max_devices`` of a graph's route. A
+        GAT's sweep holds gather candidates only (the default sweep without
+        its one-hot points, when none is given): its attention body has no
+        one-hot routing, and a sweep that offers one raises."""
+        if sharded:
+            return self._sharded_autotune_kwargs(a), self.n_devices
+        kw = self._autotune_kwargs
+        if arch == "gat":
+            sweep = kw.get("sweep")
+            if sweep is None:
+                sweep = [c for c in space.default_sweep(a) if c["routing"] == GATHER]
+            elif any(c["routing"] != GATHER for c in sweep):
+                raise UnsupportedArchitectureError(
+                    arch,
+                    "its attention body runs on the gather routing only; "
+                    "autotune_kwargs['sweep'] offers another",
+                )
+            kw = dict(kw, sweep=sweep)
+        return kw, 1
+
+    def _forward_of(self, rec: "_Resident", ex):
+        """The batched forward of ``rec``'s architecture on executor ``ex``."""
+        return ex.gat_forward_batch if rec.arch == "gat" else ex.forward_batch
 
     def _sharded_autotune_kwargs(self, a: fmt.COO) -> dict:
         """The autotune kwargs of the sharded route: every sweep candidate
@@ -677,7 +727,13 @@ class GCNServingEngine:
         return kw
 
     def add_graph(
-        self, graph_id: str, a: fmt.COO, params: dict, *, kdim: Optional[int] = None
+        self,
+        graph_id: str,
+        a: fmt.COO,
+        params: dict,
+        *,
+        kdim: Optional[int] = None,
+        arch: str = "gcn",
     ) -> AdmitReport:
         """Register a graph + trained weights and make it servable.
 
@@ -687,20 +743,38 @@ class GCNServingEngine:
         otherwise the **single-device route** (store key + sweep pinned to
         one device, then bin-packed placement). Either route warm-starts
         from the store when populated. ``kdim`` is the tuning probe width;
-        it defaults to the first layer's output width."""
+        it defaults to the first layer's output width.
+
+        ``arch`` names the architecture (``ARCHS``). ``"gcn"`` takes
+        ``params`` ``w<i>``; ``"gat"`` takes ``w<i>`` and ``a<i>``
+        (``core.gat``: ``ValueError`` on any other tree, or on a graph that
+        is not square) and serves through the executor's attention body,
+        which runs on the gather routing of one device. A GAT whose
+        footprint would take the sharded route, or whose sweep offers
+        another routing, raises ``UnsupportedArchitectureError``, as does
+        an unknown ``arch``; nothing is tuned or uploaded then."""
+        if arch not in ARCHS:
+            raise UnsupportedArchitectureError(arch, f"add_graph serves {ARCHS}")
         if graph_id in self._graphs:
             raise ValueError(f"graph {graph_id!r} already registered")
+        heads = 0
+        if arch == "gat":
+            heads = sum(gat.layer_heads(params))
+            if a.shape[0] != a.shape[1]:
+                raise ValueError(f"a GAT's graph is square; A is {a.shape}")
         if kdim is None:
             kdim = int(np.asarray(params["w0"]).shape[1])
         fp = registry.graph_fingerprint(a)
-        est = self._estimate_bytes(a, params)
+        est = self._estimate_bytes(a, params, arch)
         sharded_route = est > self.device_budget_bytes and self.n_devices > 1
-        if sharded_route:
-            tune_kw = self._sharded_autotune_kwargs(a)
-            max_devices = self.n_devices
-        else:
-            tune_kw = self._autotune_kwargs
-            max_devices = 1
+        if sharded_route and arch == "gat":
+            raise UnsupportedArchitectureError(
+                arch,
+                f"graph {graph_id!r} ({est} bytes estimated) exceeds one "
+                f"device's budget of {self.device_budget_bytes} and would take "
+                "the sharded route; its attention body runs on one device",
+            )
+        tune_kw, max_devices = self._tune_route(arch, a, sharded_route)
         key = runner.store_key(self.store, fp, kdim, max_devices=max_devices, **tune_kw)
         t0 = time.perf_counter()
         entry = self.store.load(key)
@@ -708,7 +782,7 @@ class GCNServingEngine:
         if warm:
             self._count("store_hits")
             cfg, sched, perm = entry
-            self._check_route(graph_id, cfg, sharded_route, "stored")
+            self._check_route(graph_id, cfg, sharded_route, "stored", arch)
             # the entry's permutation is adopted verbatim — it is the one
             # the persisted schedule was built under, which a fresh
             # recompute is not guaranteed to reproduce after repairs
@@ -724,7 +798,7 @@ class GCNServingEngine:
                 store=self.store,
                 **tune_kw,
             )
-            self._check_route(graph_id, cfg, sharded_route, "tuned")
+            self._check_route(graph_id, cfg, sharded_route, "tuned", arch)
             sched = registry.get_schedule(a, **cfg.as_schedule_kwargs(), fingerprint=fp)
             perm, inv = registry.get_reorder(a, cfg.reorder, fingerprint=fp)
             # release the graph from the registry's unbounded caches: the
@@ -749,6 +823,8 @@ class GCNServingEngine:
             config=cfg,
             sched=sched,
             params_host=jax.tree.map(np.asarray, params),
+            arch=arch,
+            heads=heads,
             coo=host_coo,
             per_row=np.bincount(row.astype(np.int64), minlength=a.shape[0]),
             kdim=int(kdim),
@@ -771,8 +847,19 @@ class GCNServingEngine:
         )
 
     def _check_route(
-        self, graph_id: str, cfg: TunedConfig, sharded_route: bool, origin: str
+        self,
+        graph_id: str,
+        cfg: TunedConfig,
+        sharded_route: bool,
+        origin: str,
+        arch: str = "gcn",
     ) -> None:
+        if arch == "gat" and cfg.routing != GATHER:
+            raise UnsupportedArchitectureError(
+                arch,
+                f"the {origin} config of graph {graph_id!r} routes "
+                f"{cfg.routing!r}; its attention body runs on the gather routing",
+            )
         if sharded_route:
             if cfg.n_devices != self.n_devices:
                 raise ValueError(
@@ -874,7 +961,7 @@ class GCNServingEngine:
         primary = _Unit(
             primary_dev,
             ex,
-            ex.forward_batch,
+            self._forward_of(rec, ex),
             params,
             ex.device_bytes + self._weight_bytes(params),
         )
@@ -884,7 +971,7 @@ class GCNServingEngine:
             reps[d] = _Unit(
                 d,
                 rex,
-                rex.forward_batch,
+                self._forward_of(rec, rex),
                 unit.params,
                 rex.device_bytes + self._weight_bytes(unit.params),
             )
@@ -1146,12 +1233,7 @@ class GCNServingEngine:
         state with zero sweeps and zero rebuilds."""
         p = self.placer.placement_of(rec.graph_id)
         sharded = p is not None and p.kind == SHARDED
-        if sharded:
-            tune_kw = self._sharded_autotune_kwargs(coo)
-            max_devices = self.n_devices
-        else:
-            tune_kw = self._autotune_kwargs
-            max_devices = 1
+        tune_kw, max_devices = self._tune_route(rec.arch, coo, sharded)
         key = runner.store_key(
             self.store, fingerprint, rec.kdim, max_devices=max_devices, **tune_kw
         )
@@ -1230,12 +1312,7 @@ class GCNServingEngine:
         fp2 = registry.graph_fingerprint(new_coo)
         p = self.placer.placement_of(gid)
         sharded = p is not None and p.kind == SHARDED
-        if sharded:
-            tune_kw = self._sharded_autotune_kwargs(new_coo)
-            max_devices = self.n_devices
-        else:
-            tune_kw = self._autotune_kwargs
-            max_devices = 1
+        tune_kw, max_devices = self._tune_route(rec.arch, new_coo, sharded)
         key = runner.store_key(
             self.store, fp2, rec.kdim, max_devices=max_devices, **tune_kw
         )
@@ -1243,7 +1320,7 @@ class GCNServingEngine:
         if entry is not None:
             self._count("store_hits")
             cfg, sched, perm2 = entry
-            self._check_route(gid, cfg, sharded, "stored")
+            self._check_route(gid, cfg, sharded, "stored", rec.arch)
             registry.adopt_reorder(fp2, cfg.reorder, perm2)
             perm2, inv2 = registry.get_reorder(
                 new_coo, cfg.reorder, fingerprint=fp2
@@ -1257,7 +1334,7 @@ class GCNServingEngine:
                 store=self.store,
                 **tune_kw,
             )
-            self._check_route(gid, cfg, sharded, "tuned")
+            self._check_route(gid, cfg, sharded, "tuned", rec.arch)
             sched = registry.get_schedule(
                 new_coo, **cfg.as_schedule_kwargs(), fingerprint=fp2
             )
@@ -1329,7 +1406,7 @@ class GCNServingEngine:
         else:
             params = jax.device_put(rec.params_host, dev)
         nbytes = ex.device_bytes + sum(int(x.nbytes) for x in jax.tree.leaves(params))
-        return _Unit(device_index, ex, ex.forward_batch, params, nbytes)
+        return _Unit(device_index, ex, self._forward_of(rec, ex), params, nbytes)
 
     def _admit(self, rec: _Resident) -> None:
         """Ensure ``rec`` is device-resident on its placement (LRU-touch +
@@ -1352,7 +1429,7 @@ class GCNServingEngine:
                     row_unperm=rec.inv,
                 )
                 params = jax.tree.map(jnp.asarray, rec.params_host)
-                fwd = ex.forward_batch
+                fwd = self._forward_of(rec, ex)
                 w_bytes = sum(int(x.nbytes) for x in jax.tree.leaves(params))
                 nbytes = ex.device_bytes + w_bytes
             else:
@@ -1827,6 +1904,7 @@ class GCNServingEngine:
             raise RequestFailure(graph_id, part_failures[-1].exc, n_failed, partial=out)
         self._count("batches")
         self._count("requests", sum(p.n for p in parts))
+        self._note_attention(graph_id, sum(p.n for p in parts))
         self._note_service(graph_id, time.monotonic() - t0, sum(p.n for p in parts))
         return out
 
@@ -2222,6 +2300,7 @@ class GCNServingEngine:
         self._count("batches")
         self._count("requests", len(ok_reqs))
         self._count("queue_served", len(ok_reqs))
+        self._note_attention(gid, len(ok_reqs))
         # the service time of this batch is its *incremental* completion
         # time on its devices: from its dispatch, or from when the batch
         # awaited before it on the same device completed, whichever is
@@ -2239,6 +2318,14 @@ class GCNServingEngine:
         for d in devs:
             self._last_done[d] = t_done
         return out, bool(part_failures)
+
+    def _note_attention(self, gid: str, n_requests: int) -> None:
+        """Count the edge-heads a GAT graph's served requests took through
+        the attention body (``attention_edge_heads``)."""
+        rec = self._graphs.get(gid)
+        if rec is not None and rec.heads:
+            nnz = int(np.asarray(rec.coo.row).shape[0])
+            self._count("attention_edge_heads", rec.heads * nnz * n_requests)
 
     def _note_served(
         self, gid: str, reqs: List[_Request], t_disp: float, t_done: float

@@ -159,6 +159,28 @@ def test_served_forward_compiles_at_pubmed_width(one_chip, routing):
     assert mem.generated_code_size_in_bytes < 3 << 20
 
 
+def test_served_gat_forward_compiles_at_pubmed_width(one_chip):
+    """The GAT of gat-pubmed (8 heads of 8, then 8 of 3) over full Pubmed's
+    gather stream, a batch of 8: the attention body compiles for the chip,
+    fits its memory, and is its own program beside the GCN's."""
+    ops, n_chunks = _gather_ops(GATHER_STEPS * GATHER_K, one_chip)
+    geom = _gather_geometry(PUBMED, n_chunks)
+    n, f = PUBMED["n"], PUBMED["f"]
+    params = {"w0": _sds((f, 64), jnp.float32, one_chip),
+              "a0": _sds((8, 16), jnp.float32, one_chip),
+              "w1": _sds((64, 24), jnp.float32, one_chip),
+              "a1": _sds((8, 6), jnp.float32, one_chip)}
+    xs = _sds((8, n, f), jnp.float32, one_chip)
+    compiled = exe._batched_gat_jit.lower(geom, ops, params, xs).compile()
+    mem = compiled.memory_analysis()
+    assert _largest_constant(compiled) < 1024
+    assert mem.argument_size_in_bytes >= _schedule_bytes(ops)
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES // 4, total
+    assert "_batched_gat_body" in compiled.as_text().split("\n", 1)[0]
+
+
 def test_served_forward_at_reddit_size_fits_one_chip(one_chip):
     ops, n_chunks = _gather_ops(REDDIT_SLOTS, one_chip)
     geom = _gather_geometry(REDDIT, n_chunks)
