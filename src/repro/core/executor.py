@@ -293,6 +293,39 @@ def _gather_slots_steps(sched: Schedule, steps: np.ndarray):
     return gcol, tgt, sched.val[sl]
 
 
+def _window_operands(sched: Schedule, n_chunks: int, chunk: int):
+    """Host arrays of the attention's window-local reductions over a slot
+    stream chunked ``[n_chunks, chunk]`` (``chunk`` a multiple of K), and
+    whether every step is its own window in one chunk (then ``win`` is
+    left out: the step→window map is the identity):
+
+    * ``win`` ``[n_chunks, chunk // K]``: each step's window (0 for the
+      last chunk's padding steps, whose slots are all ``val == 0``);
+    * ``row_slot`` ``[m]``: each row's first window slot (``n_windows·r``,
+      out of range, for a row no slot maps to);
+    * ``xslot``/``xrow``: a split row's further window slots (its evil
+      chunks after the first) and their owner rows — empty when no row is
+      split."""
+    m = sched.shape[0]
+    k, n_steps = sched.nnz_per_step, sched.n_steps
+    in_order = np.array_equal(sched.win_id, np.arange(sched.n_windows))
+    one_step = n_chunks == 1 and in_order
+    slots = np.nonzero(sched.row_map >= 0)[0]
+    order = np.argsort(sched.row_map[slots], kind="stable")
+    slots = slots[order].astype(np.int32)
+    rows = sched.row_map[slots]
+    first = np.ones(rows.shape, bool)
+    first[1:] = rows[1:] != rows[:-1]
+    row_slot = np.full(m, sched.n_windows * sched.rows_per_window, np.int32)
+    row_slot[rows[first]] = slots[first]
+    ops = {"row_slot": row_slot, "xslot": slots[~first], "xrow": rows[~first]}
+    if not one_step:
+        win = np.zeros(n_chunks * (chunk // k), np.int32)
+        win[:n_steps] = sched.win_id
+        ops["win"] = win.reshape(n_chunks, -1)
+    return ops, one_step
+
+
 def _spliced_host_slots(old_host, new_sched: Schedule, repair):
     """Host gather-slot arrays of a repaired schedule, spliced from the old
     executor's retained host slots plus freshly derived slots for the
@@ -340,7 +373,8 @@ class Geometry:
     routing: str
     m: int
     #: one-hot path only (0 on the gather path): A's column count, window
-    #: rows, column-block width and window count
+    #: rows, column-block width and window count. A gather executor's
+    #: ``attention_geometry`` sets ``r`` and ``n_windows`` too
     n: int
     r: int
     cb: int
@@ -352,6 +386,11 @@ class Geometry:
     unperm: bool = False
     #: 1-D mesh of the sharded executor (None: one device)
     mesh: Optional[Mesh] = None
+    #: ``attention_geometry`` only (0 and False elsewhere): slots per step,
+    #: and whether the stream is one chunk of steps that are each their
+    #: own window, in order — the window combine is then the identity
+    k: int = 0
+    one_step_windows: bool = False
 
     @property
     def acc(self):
@@ -484,51 +523,101 @@ def _over_chunks(body, init, *, n_chunks: int):
     return jax.lax.fori_loop(0, n_chunks, body, init)
 
 
-def _attention_partial(gcol, tgt, val, s_row, s_col, wh, *, g: Geometry, layer: int):
+def _window_reduce(step_part, ops, *, g: Geometry, width: int, identity, scatter):
+    """Reduce per-step window partials onto matrix rows: ``step_part(i)``
+    gives chunk ``i``'s steps as ``[steps, r, width]`` partials of their
+    windows' local rows. A window's steps (contiguous, possibly across two
+    chunks) are combined by ``scatter`` over step indices, skipped where
+    every step is its own window (``g.one_step_windows``); then each row
+    gathers its first window slot through ``row_slot`` and ``scatter``
+    folds in a split row's further chunk slots (``xslot`` → ``xrow``).
+    ``identity`` is what a slot no step wrote reads."""
+    if g.one_step_windows:
+        flat = step_part(0).reshape(-1, width)
+    else:
+
+        def combine(i, windows):
+            return scatter(windows, ops["win"][i], step_part(i))
+
+        init = jnp.full((g.n_windows, g.r, width), identity, g.acc)
+        flat = _over_chunks(combine, init, n_chunks=g.n_chunks).reshape(-1, width)
+    rows = jnp.take(flat, ops["row_slot"], axis=0, mode="fill", fill_value=identity)
+    if ops["xslot"].shape[0]:
+        rows = scatter(rows, ops["xrow"], flat[ops["xslot"]])
+    return rows
+
+
+def _attention_partial(ops, s_row, s_col, wh, *, g: Geometry, layer: int):
     """Edge attention over one slot stream ``[n_chunks, chunk]``, the GAT's
     A-side of a layer: per slot and head the score ``LeakyReLU(s_row[tgt] +
     s_col[gcol])``, a softmax over each output row, and the sum of ``Wh``
-    rows weighted by it. ``s_row`` ``[m, K]`` is in the stream's row order,
-    ``s_col`` ``[n, K]`` and ``wh`` ``[n, K·F]`` in node order; returns
-    ``[m, K, F]`` in the stream's row order.
+    rows weighted by it. ``s_row`` ``[m, H]`` (H heads) is in the stream's
+    row order, ``s_col`` ``[n, H]`` and ``wh`` ``[n, H·F]`` in node order;
+    returns ``[m, H, F]`` in the stream's row order.
 
-    The balanced schedule splits a heavy row over several PEs and steps,
-    so its slots lie anywhere in the stream: the row max is one
-    scatter-max over every chunk before any ``exp`` (a pass of its own),
-    and the exp-weighted ``[Wh ‖ 1]`` rows are one scatter-add, whose last
-    column per head is the softmax's denominator. Slots with ``val == 0``
-    — chunk padding, and empty PE slots whose clamped target is a real
-    row — are masked out of both."""
+    Both row reductions are window-local (``_window_reduce``): a step's
+    slots all write one window of ``g.r`` rows, so each step reduces its
+    slots densely onto those rows — the row max as a masked max over the
+    step's slots, the exp-weighted ``[Wh ‖ 1]`` sum (whose last column per
+    head is the softmax's denominator) as a one-hot ``[r, k]`` contraction
+    on the MXU, ``k = g.k`` slots per step — and only window slots are
+    mapped to rows. The row max is
+    complete, split rows included, before any ``exp``. Slots with ``val ==
+    0`` — chunk padding, and empty PE slots — are masked out of both."""
+    gcol, tgt, val, lrow = ops["gcol"], ops["tgt"], ops["val"], ops["lrow"]
     acc = g.acc
-    k = s_row.shape[1]
-    f = wh.shape[1] // k
+    h = s_row.shape[1]
+    f = wh.shape[1] // h
+    steps = gcol.shape[1] // g.k
+    # HIGHEST keeps the one-hot selection exact and the sums in float32 on
+    # a TPU, as in ``_onehot_partial``
+    dot = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
 
     def scores(i):
         with jax.named_scope(f"l{layer}.score"):
-            e = s_row[tgt[i]] + s_col[gcol[i]]  # [chunk, K]
-            return jnp.where(e > 0, e, NEGATIVE_SLOPE * e), (val[i] != 0)[:, None]
+            e = s_row[tgt[i]] + s_col[gcol[i]]  # [chunk, heads]
+            e = jnp.where(e > 0, e, NEGATIVE_SLOPE * e).reshape(steps, g.k, h)
+            return e, (val[i] != 0).reshape(steps, g.k, 1)
 
-    def row_max(i, top):
+    def onehot(i):  # [steps, k, r]: each slot's row in its step's window
+        return lrow[i].reshape(steps, g.k)[:, :, None] == jnp.arange(g.r)
+
+    def step_max(i):
         e, live = scores(i)
         with jax.named_scope(f"l{layer}.softmax"):
-            return top.at[tgt[i]].max(jnp.where(live, e, -jnp.inf))
+            e = jnp.where(live, e, -jnp.inf)
+            # [steps, k, heads, r], reduced over k: the r rows lie on the
+            # lanes (heads there would pad them 16-fold), and the masked
+            # operand is fused into the reduction, never materialised
+            top = jnp.where(onehot(i)[:, :, None], e[..., None], -jnp.inf).max(1)
+            return top.transpose(0, 2, 1)
 
-    top = jnp.full((g.m, k), -jnp.inf, acc)
-    top = _over_chunks(row_max, top, n_chunks=g.n_chunks)
+    def scatter_max(a, idx, v):
+        return a.at[idx].max(v)
 
-    def weighted_sum(i, sums):
+    def scatter_add(a, idx, v):
+        return a.at[idx].add(v)
+
+    top = _window_reduce(
+        step_max, ops, g=g, width=h, identity=-jnp.inf, scatter=scatter_max
+    )
+
+    def step_sum(i):
         e, live = scores(i)
         with jax.named_scope(f"l{layer}.softmax"):
-            p = jnp.where(live, jnp.exp(e - top[tgt[i]]), 0.0)  # [chunk, K]
+            shift = top[tgt[i]].reshape(steps, g.k, h)
+            p = jnp.where(live, jnp.exp(e - shift), 0.0)  # [steps, k, heads]
         with jax.named_scope(f"l{layer}.aggregate"):
-            msg = p[:, :, None] * jnp.take(wh, gcol[i], axis=0).reshape(-1, k, f)
-            rows = jnp.concatenate([msg, p[:, :, None]], axis=2)
-            return sums.at[tgt[i]].add(rows.reshape(-1, k * (f + 1)))
+            wh_i = jnp.take(wh, gcol[i], axis=0).reshape(steps, g.k, h, f)
+            rows = jnp.concatenate([p[..., None] * wh_i, p[..., None]], axis=3)
+            rows = rows.reshape(steps, g.k, h * (f + 1))
+            return dot("skr,skc->src", onehot(i).astype(acc), rows)
 
-    sums = jnp.zeros((g.m, k * (f + 1)), acc)
-    sums = _over_chunks(weighted_sum, sums, n_chunks=g.n_chunks)
     with jax.named_scope(f"l{layer}.aggregate"):
-        sums = sums.reshape(g.m, k, f + 1)
+        sums = _window_reduce(
+            step_sum, ops, g=g, width=h * (f + 1), identity=0.0, scatter=scatter_add
+        )
+        sums = sums.reshape(g.m, h, f + 1)
         den = sums[:, :, f:]
         return sums[:, :, :f] / jnp.where(den > 0, den, 1.0)
 
@@ -557,9 +646,7 @@ def _gat_forward_body(
                 # a reordered stream's targets are permuted rows: row
                 # ``unperm[v]`` holds node v, so its own score moves there
                 s_row = jnp.zeros_like(s_row).at[ops["unperm"]].set(s_row)
-        out = _attention_partial(
-            ops["gcol"], ops["tgt"], ops["val"], s_row, s_col, wh, g=geom, layer=i
-        )
+        out = _attention_partial(ops, s_row, s_col, wh, g=geom, layer=i)
         if geom.unperm:
             with jax.named_scope("unperm"):
                 out = jnp.take(out, ops["unperm"], axis=0)
@@ -645,6 +732,25 @@ class _ExecutorBase:
             ops["unperm"] = self._unperm
         return ops
 
+    @functools.cached_property
+    def attention_geometry(self) -> Geometry:
+        """``geometry`` with the window layout the GAT's window-local
+        reductions specialize on (``_attention_partial``)."""
+        s = self.sched
+        return dataclasses.replace(
+            self.geometry,
+            k=s.nnz_per_step,
+            r=s.rows_per_window,
+            n_windows=s.n_windows,
+            one_step_windows=self._one_step_windows,
+        )
+
+    @functools.cached_property
+    def attention_operands(self) -> dict:
+        """``operands`` plus the slots' local rows and the window arrays of
+        ``_window_operands``: what ``gat_forward_batch`` takes."""
+        return {**self.operands, "lrow": self._lrow, **self._windows}
+
     def commit(self, x: jax.Array) -> jax.Array:
         """Commit a dense operand to this executor's placement device, so
         the computation runs where the schedule arrays already live."""
@@ -699,7 +805,9 @@ class _ExecutorBase:
         serving engine's batch dispatch for a GAT graph. ``params`` and
         ``xs`` must already live on this executor's placement."""
         self._check_attention()
-        return _batched_gat_jit(self.geometry, self.operands, params, xs)
+        return _batched_gat_jit(
+            self.attention_geometry, self.attention_operands, params, xs
+        )
 
     @property
     def utilization(self) -> float:
@@ -765,35 +873,63 @@ class ScheduleExecutor(_ExecutorBase):
             # host copies are retained so an incremental repair can splice
             # new slot streams without re-deriving every step (DESIGN.md §11)
             self._host = (gcol, tgt, val)
-
-            # pad the flat slot stream to a whole number of chunks so the
-            # fused gather path can bound its [chunk, kdim] intermediate
-            s_total = gcol.shape[0]
-            self._slot_chunk = int(min(slot_chunk, max(1, s_total)))
-            pad = (-s_total) % self._slot_chunk
-            self._n_chunks = (s_total + pad) // self._slot_chunk
-
-            def _chunked(x, fill):
-                return _placed(
-                    np.concatenate([x, np.full(pad, fill, x.dtype)]).reshape(
-                        self._n_chunks, self._slot_chunk
-                    ),
-                    device,
-                )
-
-            self._gcol = _chunked(gcol, 0)
-            self._tgt = _chunked(tgt, 0)
-            self._val = _chunked(val, 0.0)
-            self.device_bytes = int(
-                self._gcol.nbytes + self._tgt.nbytes + self._val.nbytes
+            self._chunk_stream(gcol.shape[0])
+            self._gcol, self._tgt, self._val, self._lrow = (
+                self._chunked(x) for x in (gcol, tgt, val, sched.local_row)
             )
+            self._upload_windows()
         else:
             # step-major arrays (shared with the Pallas kernel wrapper —
             # one upload per (schedule, device) no matter who consumes it)
             self._steps = device_step_arrays(sched, device)
             self.device_bytes = int(sum(v.nbytes for v in self._steps.values()))
+            if self._unperm is not None:
+                self.device_bytes += int(self._unperm.nbytes)
+
+    def _chunk_stream(self, s_total: int) -> None:
+        """The chunk grid of a flat slot stream of ``s_total`` slots: as
+        many whole steps per chunk as ``slot_chunk`` slots hold (at least
+        one; the attention reduces whole steps onto their windows), the
+        last chunk padded with zeros — so the fused gather bounds its
+        [chunk, kdim] intermediate."""
+        k = self.sched.nnz_per_step
+        self._slot_chunk = max(k, min(self._slot_chunk_arg, s_total) // k * k)
+        self._pad = (-s_total) % self._slot_chunk
+        self._n_chunks = (s_total + self._pad) // self._slot_chunk
+
+    def _chunked(self, x: np.ndarray) -> jax.Array:
+        padded = np.concatenate([x, np.zeros(self._pad, x.dtype)])
+        return _placed(padded.reshape(self._n_chunks, self._slot_chunk), self.device)
+
+    def _upload_windows(self, old_ex: "Optional[ScheduleExecutor]" = None) -> None:
+        """Upload the attention's window arrays (``_window_operands``) —
+        shared with ``old_ex`` where its windows and chunk grid are this
+        schedule's — and total ``device_bytes``."""
+        if (
+            old_ex is not None
+            and old_ex._n_chunks == self._n_chunks
+            and old_ex._slot_chunk == self._slot_chunk
+            and np.array_equal(old_ex.sched.win_id, self.sched.win_id)
+            and np.array_equal(old_ex.sched.row_map, self.sched.row_map)
+        ):
+            self._windows = old_ex._windows
+            self._one_step_windows = old_ex._one_step_windows
+        else:
+            host, self._one_step_windows = _window_operands(
+                self.sched, self._n_chunks, self._slot_chunk
+            )
+            self._windows = {key: _placed(v, self.device) for key, v in host.items()}
+        arrays = [self._gcol, self._tgt, self._val, self._lrow, *self._windows.values()]
         if self._unperm is not None:
-            self.device_bytes += int(self._unperm.nbytes)
+            arrays.append(self._unperm)
+        self.device_bytes = int(sum(x.nbytes for x in arrays))
+
+    @property
+    def attention_scatter_slots(self) -> int:
+        """Window slots per layer whose partials still pass a scatter in
+        the attention's reductions: a split row's chunks after its first
+        (0 on the gather path of a schedule without split rows)."""
+        return int(self._windows["xslot"].shape[0])
 
     @classmethod
     def _from_repair(
@@ -842,9 +978,7 @@ class ScheduleExecutor(_ExecutorBase):
         gcol, tgt, val, moved = _spliced_host_slots(old_ex._host, new_sched, repair)
         self._host = (gcol, tgt, val)
         s_total = gcol.shape[0]
-        self._slot_chunk = int(min(self._slot_chunk_arg, max(1, s_total)))
-        pad = (-s_total) % self._slot_chunk
-        self._n_chunks = (s_total + pad) // self._slot_chunk
+        self._chunk_stream(s_total)
         # scoped patch is sound only on an identical padded grid — same
         # slot count (so the old padding region still pads) and same
         # chunking (so accumulation order, hence bitwise output, matches a
@@ -859,7 +993,7 @@ class ScheduleExecutor(_ExecutorBase):
             # content and layout identical: the old device arrays ARE the
             # new ones (jax arrays are immutable — sharing is safe)
             self._gcol, self._tgt = old_ex._gcol, old_ex._tgt
-            self._val = old_ex._val
+            self._val, self._lrow = old_ex._val, old_ex._lrow
             self.scoped_upload = True
         elif (
             same_grid
@@ -889,24 +1023,14 @@ class ScheduleExecutor(_ExecutorBase):
             self._gcol = _patch(old_ex._gcol, gcol)
             self._tgt = _patch(old_ex._tgt, tgt)
             self._val = _patch(old_ex._val, val)
+            self._lrow = _patch(old_ex._lrow, new_sched.local_row)
             self.scoped_upload = True
         else:
-
-            def _chunked(x, fill):
-                return _placed(
-                    np.concatenate([x, np.full(pad, fill, x.dtype)]).reshape(
-                        self._n_chunks, self._slot_chunk
-                    ),
-                    self.device,
-                )
-
-            self._gcol = _chunked(gcol, 0)
-            self._tgt = _chunked(tgt, 0)
-            self._val = _chunked(val, 0.0)
+            self._gcol, self._tgt, self._val, self._lrow = (
+                self._chunked(x) for x in (gcol, tgt, val, new_sched.local_row)
+            )
             self.scoped_upload = False
-        self.device_bytes = int(self._gcol.nbytes + self._tgt.nbytes + self._val.nbytes)
-        if self._unperm is not None:
-            self.device_bytes += int(self._unperm.nbytes)
+        self._upload_windows(old_ex)
         return self
 
     @classmethod
@@ -944,9 +1068,13 @@ class ScheduleExecutor(_ExecutorBase):
         self.routing = GATHER
         self._slot_chunk_arg = old_ex._slot_chunk_arg
         self._slot_chunk = old_ex._slot_chunk
+        self._pad = old_ex._pad
         self._n_chunks = old_ex._n_chunks
         self.row_unperm = old_ex.row_unperm
         self._unperm = old_ex._unperm
+        self._lrow = old_ex._lrow
+        self._windows = old_ex._windows
+        self._one_step_windows = old_ex._one_step_windows
 
         gcol, tgt, oval = old_ex._host
         val = oval.copy()

@@ -607,6 +607,10 @@ class GCNServingEngine:
             # a GAT's non-zeros x heads x layers x requests served, counted
             # when the batch is awaited (0 for GCN graphs)
             "attention_edge_heads": 0,
+            # a GAT's window slots x layers x requests served whose partials
+            # still pass a scatter in the attention's row reductions (split
+            # rows' further chunks), counted with attention_edge_heads
+            "attention_scatter_slots": 0,
         }
 
     # ---- policy state snapshot ---------------------------------------------
@@ -2321,11 +2325,17 @@ class GCNServingEngine:
 
     def _note_attention(self, gid: str, n_requests: int) -> None:
         """Count the edge-heads a GAT graph's served requests took through
-        the attention body (``attention_edge_heads``)."""
+        the attention body (``attention_edge_heads``), and the window slots
+        its row reductions still scattered (``attention_scatter_slots``)."""
         rec = self._graphs.get(gid)
         if rec is not None and rec.heads:
             nnz = int(np.asarray(rec.coo.row).shape[0])
             self._count("attention_edge_heads", rec.heads * nnz * n_requests)
+            with self._swap_lock:
+                ex = rec.executor
+            layers = len(rec.params_host) // 2
+            slots = getattr(ex, "attention_scatter_slots", 0)
+            self._count("attention_scatter_slots", slots * layers * n_requests)
 
     def _note_served(
         self, gid: str, reqs: List[_Request], t_disp: float, t_done: float

@@ -9,6 +9,7 @@ reordered schedule. Each case is checked against ``core.gat.forward``
 within 1e-5 relative; on four virtual devices, replicas, and the sharded
 route's refusal."""
 import hashlib
+import re
 import subprocess
 import sys
 import tempfile
@@ -124,35 +125,94 @@ def _split_rows(sched) -> int:
 
 
 CASES = {
-    # (nnz_per_step, rows_per_window, slot_chunk, reorder)
+    # (nnz_per_step, rows_per_window, slot_chunk, reorder[, window_nnz])
     "one_chunk": (64, 32, 1 << 18, "none"),
     "split_rows": (8, 8, 1 << 18, "none"),
     "chunks": (16, 8, 96, "none"),
     "reordered": (16, 8, 96, "degree"),
     "reordered_island": (64, 32, 1 << 18, "island"),
+    # no row past 128 non-zeros: every step is its own window
+    "one_step_windows": (128, 32, 1 << 18, "none"),
+    # windows of up to 64 non-zeros over steps of 16: several steps each
+    "multi_step_windows": (16, 8, 1 << 18, "none", 64),
+    # 100 slots round down to chunks of 6 whole steps; windows straddle them
+    "unaligned_chunk": (16, 8, 100, "none", 64),
+    "split_rows_reordered": (8, 8, 96, "degree"),
 }
+
+
+def _case_executor(case):
+    k, r, chunk, strategy, *window_nnz = CASES[case]
+    a = _graph()
+    perm, inv = reorder.permutation(a, strategy)
+    sched = schedule.build_balanced_schedule(
+        a if perm is None else csc.permute_coo(a, perm), k, r,
+        window_nnz=(window_nnz or [None])[0])
+    return a, exe.ScheduleExecutor(sched, routing=exe.GATHER, slot_chunk=chunk,
+                                   row_unperm=inv)
 
 
 @pytest.mark.parametrize("case", CASES)
 def test_attention_body_matches_the_reference(case):
-    k, r, chunk, strategy = CASES[case]
-    a = _graph()
+    a, ex = _case_executor(case)
+    sched, k, chunk = ex.sched, ex.sched.nnz_per_step, CASES[case][2]
     params = _params()
     xs = jnp.asarray(np.stack(_requests(2)))
-    perm, inv = reorder.permutation(a, strategy)
-    sched = schedule.build_balanced_schedule(
-        a if perm is None else csc.permute_coo(a, perm), k, r)
-    ex = exe.ScheduleExecutor(sched, routing=exe.GATHER, slot_chunk=chunk,
-                              row_unperm=inv)
     _, _, val = exe._gather_slots(sched)
     assert (val == 0).any()  # empty PE slots are in the stream
-    if case == "split_rows":
-        assert _split_rows(sched) > 0
+    geom = ex.attention_geometry
+    if case.startswith("split_rows"):
+        assert _split_rows(sched) > 0 and ex.attention_scatter_slots > 0
+    if case == "one_step_windows":
+        assert geom.one_step_windows and sched.n_evil_chunks == 0
+    if case in ("multi_step_windows", "unaligned_chunk"):
+        regular = sched.win_id[: sched.n_steps - sched.n_evil_chunks]
+        assert np.bincount(regular).max() > 1 and not geom.one_step_windows
     if chunk < val.shape[0]:
-        assert ex.geometry.n_chunks > 1 and val.shape[0] % chunk  # padded
+        assert geom.n_chunks > 1 and val.shape[0] % chunk  # padded
+        assert ex._slot_chunk == chunk // k * k  # whole steps per chunk
+    if case == "unaligned_chunk":
+        chunk_of_step = np.arange(sched.n_steps) // (ex._slot_chunk // k)
+        assert any(np.unique(chunk_of_step[sched.win_id == w]).size > 1
+                   for w in np.unique(sched.win_id))  # a window in two chunks
     got = ex.gat_forward_batch(params, xs)
     for i in range(2):
         assert _rel(got[i], gat.forward(params, a, xs[i])) < RTOL
+
+
+def _scatter_index_dims(hlo: str) -> list:
+    """The shape of every scatter's index operand in an HLO module's text."""
+    defined = r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([0-9,]*)\]"
+    shapes = dict(re.findall(defined, hlo, re.M))
+    return [[int(d) for d in shapes[name].split(",") if d]
+            for name in re.findall(r" scatter\([^,]+, %?([\w.\-]+)", hlo)]
+
+
+@pytest.mark.parametrize("case", ["one_step_windows", "multi_step_windows_whole_rows"])
+def test_attention_reductions_scatter_no_slot_indices(case):
+    """On a schedule that chunks no row over windows the lowered GAT
+    forward has no scatter with one index per slot: the row max and the
+    weighted sums are reduced onto windows, and a window's steps combine
+    by step index."""
+    a = _graph()
+    k, r, window_nnz = (128, 32, None) if case == "one_step_windows" else (32, 8, 128)
+    sched = schedule.build_balanced_schedule(a, k, r, window_nnz=window_nnz)
+    # no row is chunked over windows; a window's rows may span its steps
+    assert sched.n_evil_chunks == 0
+    assert (_split_rows(sched) == 0) == (case == "one_step_windows")
+    ex = exe.ScheduleExecutor(sched, routing=exe.GATHER)
+    assert ex.attention_scatter_slots == 0
+    assert ex.attention_geometry.one_step_windows == (case == "one_step_windows")
+    xs = jnp.asarray(np.stack(_requests(2)))
+    lowered = exe._batched_gat_jit.lower(
+        ex.attention_geometry, ex.attention_operands, _params(), xs)
+    hlo = lowered.as_text(dialect="hlo")
+    dims = _scatter_index_dims(hlo)
+    slots = sched.n_steps * k
+    assert all(slots not in d for d in dims), dims
+    # the step combine is all that scatters, and only where windows span steps
+    assert (dims == []) == (case == "one_step_windows")
+    assert all(sched.n_steps in d for d in dims), dims
 
 
 def test_empty_slots_would_change_the_softmax_if_counted():
@@ -162,8 +222,8 @@ def test_empty_slots_would_change_the_softmax_if_counted():
     x = jnp.asarray(_requests(1)[0])
     ex = exe.ScheduleExecutor(schedule.build_balanced_schedule(a, 16, 8),
                               routing=exe.GATHER, slot_chunk=96)
-    ops = dict(ex.operands, val=jnp.ones_like(ex.operands["val"]))
-    wrong = exe._batched_gat_jit(ex.geometry, ops, params, x[None])[0]
+    ops = dict(ex.attention_operands, val=jnp.ones_like(ex.operands["val"]))
+    wrong = exe._batched_gat_jit(ex.attention_geometry, ops, params, x[None])[0]
     assert _rel(wrong, gat.forward(params, a, x)) > 1e-2
 
 
@@ -220,6 +280,73 @@ def test_attention_counter_stays_zero_for_a_gcn(tmp_path):
                                            jax.random.PRNGKey(1)))
     eng.serve_batch("g", _requests(2))
     assert eng.stats()["attention_edge_heads"] == 0
+
+
+@pytest.mark.parametrize("case", ["whole_rows", "split_rows", "gcn"])
+def test_attention_scatter_slots_counts_split_row_chunks(tmp_path, case):
+    """``attention_scatter_slots``: the window slots per layer whose
+    partials still pass a scatter (a split row's chunks after its first),
+    times layers and requests served; 0 where no row is split and for a
+    GCN."""
+    a = _graph()
+    k, r = (128, 32) if case == "whole_rows" else (8, 8)
+    sweep = [dict(SWEEP[0], nnz_per_step=k, rows_per_window=r)]
+    eng = GCNServingEngine(store_root=tmp_path, max_batch=MAX_BATCH,
+                           autotune_kwargs=dict(FAST_KW, sweep=sweep))
+    if case == "gcn":
+        eng.add_graph("g", a, gcn.init_params(gcn.GCNConfig(N_FEATS, 8, 3),
+                                               jax.random.PRNGKey(1)))
+    else:
+        eng.add_graph("g", a, _params(), arch="gat")
+    eng.serve_batch("g", _requests(3))
+    got = eng.stats()["attention_scatter_slots"]
+    per_layer = eng._graphs["g"].executor.attention_scatter_slots
+    if case == "split_rows":
+        assert per_layer > 0 and got == per_layer * 2 * 3
+    else:
+        assert got == 0
+    if case == "whole_rows":
+        assert per_layer == 0
+    eng.reset_stats()
+    assert eng.stats()["attention_scatter_slots"] == 0
+
+
+@pytest.mark.parametrize("seed,scoped", [(2, True), (0, False)])
+def test_repaired_executor_carries_the_attention_operands(monkeypatch, seed, scoped):
+    """A repair patches the slots' local rows with the slot stream (scoped:
+    only the moved steps; else a full upload) and rebuilds the window
+    arrays: the attention operands equal a cold build's, and the repaired
+    executor serves the new graph."""
+    monkeypatch.setattr(exe, "SCOPED_UPLOAD_MIN_BYTES", 0)
+    a, params = _graph(), _params()
+    sched = schedule.build_balanced_schedule(a, 16, 8)
+    ex = exe.ScheduleExecutor(sched, routing=exe.GATHER, slot_chunk=96)
+    rng = np.random.default_rng(seed)
+    dense = np.asarray(csc.coo_to_dense(a)) != 0
+    present = np.argwhere(dense)[rng.choice(int(dense.sum()), 2, replace=False)]
+    absent = np.argwhere(~dense)[rng.choice(int((~dense).sum()), 2, replace=False)]
+    edges = np.concatenate([present, absent]).astype(np.int32)
+    delta = csc.EdgeDelta(edges[:, 0], edges[:, 1],
+                          np.asarray([0, 0, 1, 1], np.float32))
+    new_coo, rep = csc.apply_edge_delta(a, delta, with_report=True)
+    per_row_old = np.bincount(np.asarray(a.row), minlength=N_NODES)
+    per_row_new = per_row_old.copy()
+    per_row_new[rep.touched_rows] += rep.row_nnz_delta
+    new_sched, stats = schedule.repair_schedule(
+        sched, None, new_coo, rep.touched_rows, per_row_old=per_row_old,
+        per_row_new=per_row_new, nnz_per_step=16, rows_per_window=8)
+    ex2 = exe.repaired_executor(ex, new_sched, stats)
+    assert ex2.scoped_upload == scoped and ex2.attention_scatter_slots > 0
+    cold = exe.ScheduleExecutor(new_sched, routing=exe.GATHER, slot_chunk=96)
+    assert ex2.attention_geometry == cold.attention_geometry
+    assert ex2.device_bytes == cold.device_bytes
+    got, want = ex2.attention_operands, cold.attention_operands
+    assert sorted(got) == sorted(want)
+    for key in want:
+        np.testing.assert_array_equal(np.asarray(got[key]), np.asarray(want[key]))
+    x = jnp.asarray(_requests(1)[0])
+    assert _rel(ex2.gat_forward_batch(params, x[None])[0],
+                gat.forward(params, new_coo, x)) < RTOL
 
 
 def test_gcn_logits_digest_on_the_tiny_graph_is_unchanged(tmp_path):

@@ -10,6 +10,7 @@ The topology is described only inside the module fixture: the TPU library
 admits one process at a time, and a description made at import would break
 every other test worker.
 """
+import dataclasses
 import os
 import re
 
@@ -159,19 +160,40 @@ def test_served_forward_compiles_at_pubmed_width(one_chip, routing):
     assert mem.generated_code_size_in_bytes < 3 << 20
 
 
+def _attention_inputs(steps, k, windows, n_split, sharding):
+    """The attention operands and geometry of a one-chunk Pubmed stream of
+    ``steps`` steps of ``k`` slots over ``windows`` windows of 64 rows,
+    ``n_split`` of whose slots hold a split row's further chunks."""
+    ops, n_chunks = _gather_ops(steps * k, sharding)
+    one_step = steps == windows
+    ops.update(lrow=_sds((n_chunks, steps * k), jnp.int32, sharding),
+               row_slot=_sds((PUBMED["n"],), jnp.int32, sharding),
+               xslot=_sds((n_split,), jnp.int32, sharding),
+               xrow=_sds((n_split,), jnp.int32, sharding))
+    if not one_step:
+        ops["win"] = _sds((n_chunks, steps), jnp.int32, sharding)
+    geom = dataclasses.replace(_gather_geometry(PUBMED, n_chunks), k=k, r=64,
+                               n_windows=windows, one_step_windows=one_step)
+    return ops, geom
+
+
+def _compile_gat(geom, ops, sharding):
+    n, f = PUBMED["n"], PUBMED["f"]
+    params = {"w0": _sds((f, 64), jnp.float32, sharding),
+              "a0": _sds((8, 16), jnp.float32, sharding),
+              "w1": _sds((64, 24), jnp.float32, sharding),
+              "a1": _sds((8, 6), jnp.float32, sharding)}
+    xs = _sds((8, n, f), jnp.float32, sharding)
+    return exe._batched_gat_jit.lower(geom, ops, params, xs).compile()
+
+
 def test_served_gat_forward_compiles_at_pubmed_width(one_chip):
     """The GAT of gat-pubmed (8 heads of 8, then 8 of 3) over full Pubmed's
     gather stream, a batch of 8: the attention body compiles for the chip,
-    fits its memory, and is its own program beside the GCN's."""
-    ops, n_chunks = _gather_ops(GATHER_STEPS * GATHER_K, one_chip)
-    geom = _gather_geometry(PUBMED, n_chunks)
-    n, f = PUBMED["n"], PUBMED["f"]
-    params = {"w0": _sds((f, 64), jnp.float32, one_chip),
-              "a0": _sds((8, 16), jnp.float32, one_chip),
-              "w1": _sds((64, 24), jnp.float32, one_chip),
-              "a1": _sds((8, 6), jnp.float32, one_chip)}
-    xs = _sds((8, n, f), jnp.float32, one_chip)
-    compiled = exe._batched_gat_jit.lower(geom, ops, params, xs).compile()
+    fits its memory, and is its own program beside the GCN's. The chip's
+    schedule (K=256, R=64) makes every step its own window."""
+    ops, geom = _attention_inputs(GATHER_STEPS, GATHER_K, GATHER_STEPS, 0, one_chip)
+    compiled = _compile_gat(geom, ops, one_chip)
     mem = compiled.memory_analysis()
     assert _largest_constant(compiled) < 1024
     assert mem.argument_size_in_bytes >= _schedule_bytes(ops)
@@ -179,6 +201,18 @@ def test_served_gat_forward_compiles_at_pubmed_width(one_chip):
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES // 4, total
     assert "_batched_gat_body" in compiled.as_text().split("\n", 1)[0]
+
+
+def test_gat_window_combine_compiles_at_pubmed_width(one_chip):
+    """Full Pubmed's schedule at K=128 (1,028 steps over 930 windows, 50
+    further chunks of split rows): the attention's step combine and its
+    split-row scatter compile for the chip and fit as the K=256 one does;
+    the masked max over a step's slots is not materialised."""
+    ops, geom = _attention_inputs(1028, 128, 930, 50, one_chip)
+    mem = _compile_gat(geom, ops, one_chip).memory_analysis()
+    # the masked [batch, steps, k, r, heads] operand of the row max alone
+    # would be 8·1028·128·64·8·4 B = 2.2 GB
+    assert mem.temp_size_in_bytes < 2 * 10**9, mem.temp_size_in_bytes
 
 
 def test_served_forward_at_reddit_size_fits_one_chip(one_chip):
