@@ -6,17 +6,27 @@ the balancing effort is paid once per graph, and every subsequent round and
 layer replays the converged plan. This module is purely the **execution
 machinery** for that plan:
 
-* ``ScheduleExecutor`` uploads a ``Schedule``'s arrays to the device exactly
-  once at construction and serves ``spmm(b) = A @ b`` (fused-gather VPU
-  routing or step-scanned one-hot MXU routing, chosen by
-  ``select_routing``'s cost model), a whole-GCN ``forward`` and its
-  request-batched ``forward_batch``, and the GAT's edge-attention forward
-  (``gat_forward_batch``, ``core.gat``) over the same gather slot stream.
-  The compiled programs take the schedule
-  arrays as arguments, keyed on a static ``Geometry``, so they never hold a
-  copy of the schedule and executors of equal geometry share them.
-* ``ShardedScheduleExecutor`` runs the same plan across a 1-D device mesh
-  (per-device step shards under ``shard_map``, psum merge — DESIGN.md §4).
+* One executor over **step shards** (``_ExecutorBase``): a schedule's
+  gather slot stream is cut into contiguous step ranges, one per device —
+  ``ScheduleExecutor`` holds one shard of every step on one device,
+  ``ShardedScheduleExecutor`` one shard per device of a 1-D mesh, run
+  under ``shard_map`` with a psum merge (DESIGN.md §4). Both share one
+  chunk grid, one upload, one repair and one value patch; only the
+  assembly of the jit operand differs. ``make_executor`` builds either
+  from a ``TunedConfig`` and a placement.
+* An executor uploads its schedule's arrays once at construction and
+  serves ``spmm(b) = A @ b`` (fused-gather VPU routing or step-scanned
+  one-hot MXU routing, chosen by ``select_routing``'s cost model), a
+  whole-GCN ``forward`` and its request-batched ``forward_batch``, and,
+  on one device, the GAT's edge-attention forward (``gat_forward_batch``,
+  ``core.gat``) over the same gather slot stream. The compiled programs
+  take the schedule arrays as arguments, keyed on a static ``Geometry``,
+  so they never hold a copy of the schedule and executors of equal
+  geometry share them.
+* A streaming update builds a new executor from the old one
+  (``repaired_executor``, ``value_patched_executor``, DESIGN.md §11): per
+  shard, the device arrays are kept, scatter-patched or re-uploaded, and
+  the result equals a cold build bit for bit.
 
 Every caching/search concern that used to live here — fingerprint-keyed
 schedule/executor caches, the measured autotune sweep, ``TunedConfig`` —
@@ -49,7 +59,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 from collections import OrderedDict
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -60,7 +70,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core.gat import NEGATIVE_SLOPE
 from repro.core.schedule import Schedule
 from repro.lazyexports import lazy_exports
-from repro.sharding.schedule_shard import shard_schedule
+from repro.sharding.schedule_shard import shard_schedule, split_step_ranges
 
 GATHER = "gather"
 ONEHOT = "onehot"
@@ -676,22 +686,323 @@ _batched_forward_jit = jax.jit(_batched_forward_body, static_argnames="geom")
 _batched_gat_jit = jax.jit(_batched_gat_body, static_argnames="geom")
 
 
+def _bucketed(idx: np.ndarray, floor: int) -> np.ndarray:
+    """Scatter indices padded to a size in ``floor · 4^j`` by repeating the
+    last one (a duplicate ``.set`` of an identical value is harmless), so
+    repeated small patches reuse a handful of compiled ``_scatter_set``
+    programs instead of compiling one per dirty-set size."""
+    size = floor
+    while size < idx.size:
+        size *= 4
+    pad = np.full(size - idx.size, idx[-1], idx.dtype)
+    return np.concatenate([idx, pad]).astype(np.int32)
+
+
+def _sharded_step_arrays(sched: Schedule, mesh: Mesh) -> dict:
+    """The one-hot routing's step-major arrays on a mesh: ``shard_schedule``
+    stacks, each device's shard placed with ``P(axis)``; ``row_map``
+    replicated, since the epilogue runs device-local, before the psum."""
+    shards = shard_schedule(sched, mesh.devices.size)
+
+    def put(x, spec):
+        return jax.device_put(jnp.asarray(x), NamedSharding(mesh, spec))
+
+    axis = P(mesh.axis_names[0])
+    keys = ("val", "lrow", "lcol", "win", "cblk")
+    arrs = {key: put(getattr(shards, key), axis) for key in keys}
+    arrs["row_map"] = put(sched.row_map, P())
+    return arrs
+
+
+#: what a repaired or value-patched executor carries over from its
+#: predecessor unchanged: the execution fields and the placement
+_CARRIED = (
+    "ktile",
+    "routing",
+    "bf16_accumulate",
+    "device",
+    "mesh",
+    "_slot_chunk_arg",
+    "row_unperm",
+    "_unperm",
+)
+
+
 class _ExecutorBase:
-    """Shared surface of the single- and multi-device executors: operand
-    validation and the call protocol of the jitted entry points, which take
-    the executor's static ``geometry`` and its device-resident schedule
-    arrays (``operands``) as arguments."""
+    """Device-resident executor of one converged AWB schedule, over step
+    shards.
+
+    The gather slot stream is held as contiguous step ranges
+    (``split_step_ranges``), one per device: one device is one shard of
+    every step, a 1-D mesh one shard per mesh device. Each shard is cut
+    into the same chunk grid — whole steps per chunk, ``[n_chunks,
+    chunk]``, the last chunk padded with ``val == 0`` slots — and uploaded
+    to its device once. Only the assembly of the jit operand differs: one
+    device takes its shard's array itself, a mesh the ``[D, n_chunks,
+    chunk]`` array of its shards sharded ``P(axis)``. The one-hot routing
+    keeps its step-major arrays (``device_step_arrays`` on one device,
+    ``shard_schedule`` stacks on a mesh).
+
+    The jitted entry points take the static ``geometry`` and the
+    device-resident ``operands`` as arguments, so repeated ``spmm``/
+    ``forward`` calls move only the dense operand. ``device_bytes`` is the
+    resident footprint, what the serving engine's byte budget meters.
+
+    ``device`` is the placement handle of a one-device executor: a specific
+    ``jax.Device`` pins the schedule arrays (and so the computation, since
+    ``commit`` moves operands there) to one device of a mesh; ``None``
+    keeps jax's default placement, and is the value on a mesh.
+
+    ``row_unperm`` supports locality-reordered schedules (core.reorder):
+    when ``sched`` was built on a row-permuted graph, pass the inverse
+    permutation (``inv[old_row] = new_row``) and every output comes back
+    in **original** row order — one fused gather per call, bit-identical
+    to executing the unpermuted schedule.
+    """
 
     sched: Schedule
     routing: str
-    bf16_accumulate: bool = False
-    #: placement handle: the specific mesh device this executor's arrays
-    #: live on (None = jax's default device; always None for the sharded
-    #: executor, whose mesh is the placement).
-    device = None
-    #: the sharded executor's mesh (None: one device)
+    #: the 1-D mesh of a sharded executor (None: one device)
     mesh: Optional[Mesh] = None
-    _unperm = None
+
+    def __init__(
+        self,
+        sched: Schedule,
+        *,
+        ktile: int = 128,
+        routing: Optional[str] = None,
+        bf16_accumulate: bool = False,
+        slot_chunk: int = 1 << 18,
+        device=None,
+        row_unperm=None,
+    ):
+        self.sched = sched
+        self.ktile = ktile
+        self.bf16_accumulate = bf16_accumulate
+        self.device = device
+        self._slot_chunk_arg = slot_chunk
+        k = sched.nnz_per_step
+        r = sched.rows_per_window
+        cb = sched.cols_per_block
+        self.routing = routing or select_routing(k, cb, r, ktile)
+        self.row_unperm = (
+            None if row_unperm is None else np.asarray(row_unperm, np.int32)
+        )
+        if self.row_unperm is None:
+            self._unperm = None
+        elif self.mesh is None:
+            self._unperm = _placed(self.row_unperm, device)
+        else:  # replicated: the un-permute runs on the psum-merged output
+            self._unperm = jax.device_put(
+                jnp.asarray(self.row_unperm), NamedSharding(self.mesh, P())
+            )
+        self._build()
+
+    @property
+    def n_devices(self) -> int:
+        return 1 if self.mesh is None else int(self.mesh.devices.size)
+
+    @property
+    def _devices(self) -> list:
+        """The shards' devices, in step order."""
+        if self.mesh is None:
+            return [self.device]
+        return list(self.mesh.devices.reshape(-1))
+
+    def _parts(self, arr: jax.Array) -> list:
+        """Per-shard device arrays of one assembled slot stream."""
+        if self.mesh is None:
+            return [arr]
+        by_dev = {s.device: s.data for s in arr.addressable_shards}
+        return [by_dev[dev] for dev in self._devices]
+
+    def _assembled(self, parts: list) -> jax.Array:
+        """The jit operand of one slot stream from its shards' arrays."""
+        if self.mesh is None:
+            return parts[0]
+        sharding = NamedSharding(self.mesh, P(self.mesh.axis_names[0]))
+        shape = (len(parts), *parts[0].shape[1:])
+        return jax.make_array_from_single_device_arrays(shape, sharding, parts)
+
+    def _build(self, old: "Optional[_ExecutorBase]" = None, moved=None) -> None:
+        """Derive and upload the schedule's device arrays. Cold when ``old``
+        is None; else the gather slot stream reuses ``old``'s shards where
+        ``moved`` (per step: device position or content changed) allows,
+        by the repair rule of ``_upload_shards``. Host copies of the slot
+        stream are retained so a repair can splice new slot streams
+        without re-deriving every step (DESIGN.md §11)."""
+        self.step_ranges = split_step_ranges(self.sched.n_steps, self.n_devices)
+        if self.routing != GATHER:
+            # step-major arrays; on one device shared with the Pallas kernel
+            # wrapper — one upload per (schedule, device), whoever asks
+            if self.mesh is None:
+                self._steps = device_step_arrays(self.sched, self.device)
+            else:
+                self._steps = _sharded_step_arrays(self.sched, self.mesh)
+            self.scoped_upload = False
+        else:
+            if old is None:
+                self._host = _gather_slots(self.sched)
+            self._upload_shards(old, moved)
+            if self.mesh is None:
+                self._upload_windows(old)
+        self._count_bytes()
+
+    def _upload_shards(self, old, moved) -> None:
+        """Chunk and upload each shard's slot streams (``gcol``, ``tgt``,
+        ``val``, and on one device the attention's ``lrow``).
+
+        The chunk grid holds as many whole steps per chunk as
+        ``slot_chunk`` slots allow (at least one; the attention reduces
+        whole steps onto their windows) over the longest shard, so the fused
+        gather bounds its ``[chunk, kdim]`` intermediate. A repair (``old``
+        given) treats each shard by one rule: a shard whose step range and
+        grid are unchanged and that holds no moved step keeps its device
+        arrays; one that moved at most half its slots, at or above
+        ``SCOPED_UPLOAD_MIN_BYTES``, is scatter-patched copy-on-write (the
+        old executor keeps serving in-flight batches untouched); any other
+        is re-uploaded. Every result equals a cold build's bit for bit."""
+        k = self.sched.nnz_per_step
+        ranges = self.step_ranges.tolist()
+        length = max(1, max(hi - lo for lo, hi in ranges)) * k
+        chunk = max(k, min(self._slot_chunk_arg, length) // k * k)
+        self._slot_chunk, self._n_chunks = chunk, -(-length // chunk)
+        shape = (self._n_chunks, chunk)
+        if self.mesh is not None:
+            shape = (1, *shape)
+        host = dict(zip(("gcol", "tgt", "val"), self._host))
+        if self.mesh is None:
+            host["lrow"] = self.sched.local_row
+        grid = (self._n_chunks, chunk)
+        same_grid = old is not None and (old._n_chunks, old._slot_chunk) == grid
+        old_parts = {n: old._parts(old._streams[n]) for n in host} if same_grid else {}
+        parts = {name: [] for name in host}
+        kept = full = 0
+        for d, ((lo, hi), dev) in enumerate(zip(ranges, self._devices)):
+            n_slots = (hi - lo) * k
+            steps = None
+            if same_grid and [lo, hi] == old.step_ranges[d].tolist():
+                steps = np.nonzero(moved[lo:hi])[0]
+            if steps is not None and steps.size == 0:
+                kept += 1
+                for name in host:
+                    parts[name].append(old_parts[name][d])
+            elif (
+                steps is not None
+                and 2 * steps.size * k <= n_slots
+                and n_slots * 12 >= SCOPED_UPLOAD_MIN_BYTES
+            ):
+                FAULTS.check("upload", device=dev)
+                idx = (steps[:, None] * k + np.arange(k)).reshape(-1)
+                idx = _bucketed(idx, 1024)
+                for name, x in host.items():
+                    old_part = old_parts[name][d]
+                    parts[name].append(_scatter_set(old_part, idx, x[lo * k + idx]))
+            else:
+                full += 1
+                for name, x in host.items():
+                    padded = np.zeros(shape, x.dtype)
+                    padded.reshape(-1)[:n_slots] = x[lo * k : hi * k]
+                    parts[name].append(_placed(padded, dev))
+        if kept == len(ranges):  # content and layout identical: share
+            self._streams = {name: old._streams[name] for name in host}
+        else:
+            self._streams = {name: self._assembled(p) for name, p in parts.items()}
+        #: True when a repair re-uploaded fewer than all shards in full
+        self.scoped_upload = full < len(ranges)
+        #: shards whose arrays a repair or value patch had to touch
+        self.dirty_devices = len(ranges) - kept
+
+    def _upload_windows(self, old: "Optional[_ExecutorBase]" = None) -> None:
+        """Upload the attention's window arrays (``_window_operands``) —
+        shared with ``old`` where its windows and chunk grid are this
+        schedule's."""
+        if (
+            old is not None
+            and old._n_chunks == self._n_chunks
+            and old._slot_chunk == self._slot_chunk
+            and np.array_equal(old.sched.win_id, self.sched.win_id)
+            and np.array_equal(old.sched.row_map, self.sched.row_map)
+        ):
+            self._windows = old._windows
+            self._one_step_windows = old._one_step_windows
+        else:
+            host, self._one_step_windows = _window_operands(
+                self.sched, self._n_chunks, self._slot_chunk
+            )
+            self._windows = {key: _placed(v, self.device) for key, v in host.items()}
+
+    def _count_bytes(self) -> None:
+        arrays = list(self.operands.values())
+        if self.routing == GATHER and self.mesh is None:
+            arrays += [self._streams["lrow"], *self._windows.values()]
+        self.device_bytes = int(sum(x.nbytes for x in arrays))
+
+    def _successor(self, sched: Schedule) -> "_ExecutorBase":
+        """An executor of ``sched`` with this one's execution fields and
+        placement, for a repair or a value patch to fill in."""
+        new = object.__new__(type(self))
+        new.__dict__.update({key: getattr(self, key) for key in _CARRIED})
+        new.sched = sched
+        return new
+
+    def _from_repair(self, new_sched: Schedule, repair) -> "_ExecutorBase":
+        """Executor for a repaired schedule that reuses this executor's
+        device buffers wherever the repair left steps untouched.
+
+        The host slot stream is spliced (reused steps copy their old slot
+        rows, re-emitted steps derive fresh ones), then each shard follows
+        ``_upload_shards``' rule. One-hot routing and a fallback repair
+        build cold."""
+        new = self._successor(new_sched)
+        if self.routing != GATHER or repair.fell_back or repair.step_src is None:
+            new._build()
+            return new
+        *host, moved = _spliced_host_slots(self._host, new_sched, repair)
+        new._host = tuple(host)
+        new._build(self, moved)
+        return new
+
+    def _value_patched(
+        self, new_sched: Schedule, slots: np.ndarray, vals: np.ndarray
+    ) -> "_ExecutorBase":
+        """Executor for a *value-only* patched schedule: the slot layout,
+        shards and chunk grid are this executor's, only ``val`` changed at
+        ``slots``. O(|delta|): the index streams and window arrays are
+        shared outright, and only the dirty shards' ``val`` is
+        scatter-patched (copy-on-write); an empty patch shares ``val``
+        too. One-hot routing builds cold."""
+        new = self._successor(new_sched)
+        if self.routing != GATHER:
+            new._build()
+            return new
+        gcol, tgt, oval = self._host
+        val = oval.copy()
+        val[slots] = np.asarray(vals, val.dtype)
+        new._host = (gcol, tgt, val)
+        new.step_ranges = self.step_ranges
+        new._n_chunks, new._slot_chunk = self._n_chunks, self._slot_chunk
+        if self.mesh is None:
+            new._windows = self._windows
+            new._one_step_windows = self._one_step_windows
+        k = new_sched.nnz_per_step
+        parts, dirty = [], 0
+        ranges = self.step_ranges.tolist()
+        old_parts = self._parts(self._streams["val"])
+        for (lo, hi), dev, part in zip(ranges, self._devices, old_parts):
+            local = slots[(slots >= lo * k) & (slots < hi * k)] - lo * k
+            if local.size:
+                FAULTS.check("upload", device=dev)
+                idx = _bucketed(local, 64)
+                part = _scatter_set(part, idx, val[lo * k + idx])
+                dirty += 1
+            parts.append(part)
+        patched = self._assembled(parts) if dirty else self._streams["val"]
+        new._streams = {**self._streams, "val": patched}
+        new.scoped_upload = True
+        new.dirty_devices = dirty
+        new._count_bytes()
+        return new
 
     # geometry and operands are computed once, on first use: an executor is
     # immutable after construction (repairs build a new one)
@@ -725,7 +1036,7 @@ class _ExecutorBase:
         """The device-resident schedule arrays, as the jitted entry points
         take them."""
         if self.routing == GATHER:
-            ops = {"gcol": self._gcol, "tgt": self._tgt, "val": self._val}
+            ops = {key: self._streams[key] for key in ("gcol", "tgt", "val")}
         else:
             ops = dict(self._steps)
         if self._unperm is not None:
@@ -749,7 +1060,14 @@ class _ExecutorBase:
     def attention_operands(self) -> dict:
         """``operands`` plus the slots' local rows and the window arrays of
         ``_window_operands``: what ``gat_forward_batch`` takes."""
-        return {**self.operands, "lrow": self._lrow, **self._windows}
+        return {**self.operands, "lrow": self._streams["lrow"], **self._windows}
+
+    @property
+    def attention_scatter_slots(self) -> int:
+        """Window slots per layer whose partials still pass a scatter in
+        the attention's reductions: a split row's chunks after its first
+        (0 on the gather path of a schedule without split rows)."""
+        return int(self._windows["xslot"].shape[0])
 
     def commit(self, x: jax.Array) -> jax.Array:
         """Commit a dense operand to this executor's placement device, so
@@ -815,309 +1133,24 @@ class _ExecutorBase:
 
 
 class ScheduleExecutor(_ExecutorBase):
-    """Device-resident executor of one converged AWB schedule.
-
-    Construction uploads every schedule array to one device once; the
-    jitted entry points take those arrays as arguments, so repeated
-    ``spmm``/``forward`` calls move only the dense operand. ``device_bytes``
-    reports the resident footprint — what the serving engine's LRU budget
-    meters.
-
-    ``device`` is the placement handle: pass a specific ``jax.Device`` to
-    pin the schedule arrays (and therefore the computation — operands are
-    committed there by ``spmm``/``forward``) to one device of a mesh; the
-    serving tier's ``MeshPlacer`` hands each graph such a handle. ``None``
-    keeps jax's default placement.
-
-    ``row_unperm`` supports locality-reordered schedules (core.reorder):
-    when ``sched`` was built on a row-permuted graph, pass the inverse
-    permutation (``inv[old_row] = new_row``) and every ``spmm``/``forward``
-    output comes back in **original** row order — one fused gather per
-    call, bit-identical to executing the unpermuted schedule.
-    """
-
-    def __init__(
-        self,
-        sched: Schedule,
-        *,
-        ktile: int = 128,
-        routing: Optional[str] = None,
-        bf16_accumulate: bool = False,
-        slot_chunk: int = 1 << 18,
-        device=None,
-        row_unperm=None,
-    ):
-        self.sched = sched
-        self.ktile = ktile
-        self.bf16_accumulate = bf16_accumulate
-        self.device = device
-        self.row_unperm = (
-            None if row_unperm is None else np.asarray(row_unperm, np.int32)
-        )
-        self._unperm = (
-            None if self.row_unperm is None else _placed(self.row_unperm, device)
-        )
-        self._slot_chunk_arg = slot_chunk
-        k = sched.nnz_per_step
-        r = sched.rows_per_window
-        cb = sched.cols_per_block
-        self.routing = routing or select_routing(k, cb, r, ktile)
-        #: set by the repair path: True when the last (re)construction
-        #: uploaded only the dirty slot set instead of the full stream
-        self.scoped_upload = False
-
-        # ---- one-time host-side precompute + host→device upload ----------
-        # only the selected routing's representation is built/uploaded
-        if self.routing == GATHER:
-            gcol, tgt, val = _gather_slots(sched)
-            # host copies are retained so an incremental repair can splice
-            # new slot streams without re-deriving every step (DESIGN.md §11)
-            self._host = (gcol, tgt, val)
-            self._chunk_stream(gcol.shape[0])
-            self._gcol, self._tgt, self._val, self._lrow = (
-                self._chunked(x) for x in (gcol, tgt, val, sched.local_row)
-            )
-            self._upload_windows()
-        else:
-            # step-major arrays (shared with the Pallas kernel wrapper —
-            # one upload per (schedule, device) no matter who consumes it)
-            self._steps = device_step_arrays(sched, device)
-            self.device_bytes = int(sum(v.nbytes for v in self._steps.values()))
-            if self._unperm is not None:
-                self.device_bytes += int(self._unperm.nbytes)
-
-    def _chunk_stream(self, s_total: int) -> None:
-        """The chunk grid of a flat slot stream of ``s_total`` slots: as
-        many whole steps per chunk as ``slot_chunk`` slots hold (at least
-        one; the attention reduces whole steps onto their windows), the
-        last chunk padded with zeros — so the fused gather bounds its
-        [chunk, kdim] intermediate."""
-        k = self.sched.nnz_per_step
-        self._slot_chunk = max(k, min(self._slot_chunk_arg, s_total) // k * k)
-        self._pad = (-s_total) % self._slot_chunk
-        self._n_chunks = (s_total + self._pad) // self._slot_chunk
-
-    def _chunked(self, x: np.ndarray) -> jax.Array:
-        padded = np.concatenate([x, np.zeros(self._pad, x.dtype)])
-        return _placed(padded.reshape(self._n_chunks, self._slot_chunk), self.device)
-
-    def _upload_windows(self, old_ex: "Optional[ScheduleExecutor]" = None) -> None:
-        """Upload the attention's window arrays (``_window_operands``) —
-        shared with ``old_ex`` where its windows and chunk grid are this
-        schedule's — and total ``device_bytes``."""
-        if (
-            old_ex is not None
-            and old_ex._n_chunks == self._n_chunks
-            and old_ex._slot_chunk == self._slot_chunk
-            and np.array_equal(old_ex.sched.win_id, self.sched.win_id)
-            and np.array_equal(old_ex.sched.row_map, self.sched.row_map)
-        ):
-            self._windows = old_ex._windows
-            self._one_step_windows = old_ex._one_step_windows
-        else:
-            host, self._one_step_windows = _window_operands(
-                self.sched, self._n_chunks, self._slot_chunk
-            )
-            self._windows = {key: _placed(v, self.device) for key, v in host.items()}
-        arrays = [self._gcol, self._tgt, self._val, self._lrow, *self._windows.values()]
-        if self._unperm is not None:
-            arrays.append(self._unperm)
-        self.device_bytes = int(sum(x.nbytes for x in arrays))
-
-    @property
-    def attention_scatter_slots(self) -> int:
-        """Window slots per layer whose partials still pass a scatter in
-        the attention's reductions: a split row's chunks after its first
-        (0 on the gather path of a schedule without split rows)."""
-        return int(self._windows["xslot"].shape[0])
-
-    @classmethod
-    def _from_repair(
-        cls, old_ex: "ScheduleExecutor", new_sched: Schedule, repair
-    ) -> "ScheduleExecutor":
-        """Executor for a repaired schedule that reuses the old executor's
-        device buffers wherever the repair left steps untouched.
-
-        GATHER: the host slot stream is spliced (reused steps copy their old
-        slot rows, re-emitted steps derive fresh ones), and when the chunk
-        grid is unchanged only the *moved* slots are scattered into the old
-        device arrays (`.at[idx].set` — copy-on-write, so the old executor
-        keeps serving in-flight batches untouched). ONEHOT or any fallback
-        repair rebuilds from scratch — a fresh full upload.
-
-        The result is a **new** executor object;
-        never mutates ``old_ex``. Device contents are bit-identical to a
-        cold ``ScheduleExecutor(new_sched, ...)`` with the same kwargs.
-        """
-        if (
-            old_ex.routing != GATHER
-            or repair.fell_back
-            or repair.step_src is None
-            or getattr(old_ex, "_host", None) is None
-        ):
-            return cls(
-                new_sched,
-                ktile=old_ex.ktile,
-                routing=old_ex.routing,
-                bf16_accumulate=old_ex.bf16_accumulate,
-                slot_chunk=old_ex._slot_chunk_arg,
-                device=old_ex.device,
-                row_unperm=old_ex.row_unperm,
-            )
-        self = cls.__new__(cls)
-        self.sched = new_sched
-        self.ktile = old_ex.ktile
-        self.bf16_accumulate = old_ex.bf16_accumulate
-        self.device = old_ex.device
-        self.routing = GATHER
-        self._slot_chunk_arg = old_ex._slot_chunk_arg
-        self.row_unperm = old_ex.row_unperm
-        self._unperm = old_ex._unperm
-
-        k = new_sched.nnz_per_step
-        gcol, tgt, val, moved = _spliced_host_slots(old_ex._host, new_sched, repair)
-        self._host = (gcol, tgt, val)
-        s_total = gcol.shape[0]
-        self._chunk_stream(s_total)
-        # scoped patch is sound only on an identical padded grid — same
-        # slot count (so the old padding region still pads) and same
-        # chunking (so accumulation order, hence bitwise output, matches a
-        # cold build)
-        same_grid = (
-            s_total == old_ex._host[0].shape[0]
-            and self._slot_chunk == old_ex._slot_chunk
-            and self._n_chunks == old_ex._n_chunks
-        )
-        n_moved = int(np.count_nonzero(moved)) * k
-        if same_grid and n_moved == 0:
-            # content and layout identical: the old device arrays ARE the
-            # new ones (jax arrays are immutable — sharing is safe)
-            self._gcol, self._tgt = old_ex._gcol, old_ex._tgt
-            self._val, self._lrow = old_ex._val, old_ex._lrow
-            self.scoped_upload = True
-        elif (
-            same_grid
-            and 2 * n_moved <= s_total
-            and s_total * 12 >= SCOPED_UPLOAD_MIN_BYTES
-        ):
-            FAULTS.check("upload", device=self.device)
-            steps = np.nonzero(moved)[0]
-            idx = (steps[:, None] * k + np.arange(k, dtype=np.int64)).reshape(-1)
-            # pad the scatter index to a coarse bucket (repeating the
-            # last slot — duplicate .set with an identical value is
-            # harmless) so repeated small updates reuse a handful of
-            # compiled scatters instead of recompiling per dirty-set size
-            bucket = 1024
-            while bucket < idx.size:
-                bucket *= 4
-            if bucket > idx.size:
-                idx = np.concatenate(
-                    [idx, np.full(bucket - idx.size, idx[-1], idx.dtype)]
-                )
-            jidx = jnp.asarray(idx.astype(np.int32))
-
-            def _patch(dev, host):
-                flat = dev.reshape(-1).at[jidx].set(jnp.asarray(host[idx]))
-                return flat.reshape(self._n_chunks, self._slot_chunk)
-
-            self._gcol = _patch(old_ex._gcol, gcol)
-            self._tgt = _patch(old_ex._tgt, tgt)
-            self._val = _patch(old_ex._val, val)
-            self._lrow = _patch(old_ex._lrow, new_sched.local_row)
-            self.scoped_upload = True
-        else:
-            self._gcol, self._tgt, self._val, self._lrow = (
-                self._chunked(x) for x in (gcol, tgt, val, new_sched.local_row)
-            )
-            self.scoped_upload = False
-        self._upload_windows(old_ex)
-        return self
-
-    @classmethod
-    def _value_patched(
-        cls,
-        old_ex: "ScheduleExecutor",
-        new_sched: Schedule,
-        slots: np.ndarray,
-        vals: np.ndarray,
-    ) -> "ScheduleExecutor":
-        """Executor for a *value-only* patched schedule: structure (and
-        therefore the slot layout, chunk grid, gcol/tgt streams) is
-        byte-identical to ``old_ex``; only ``val`` changed, at ``slots``.
-
-        O(|delta|): shares the old device ``_gcol``/``_tgt`` arrays
-        outright and scatters just the changed values into ``_val``
-        (copy-on-write — the old executor keeps serving untouched). The
-        scatter index is padded to a small fixed bucket so every update of
-        a given size class reuses one compiled scatter."""
-        if old_ex.routing != GATHER or getattr(old_ex, "_host", None) is None:
-            return cls(
-                new_sched,
-                ktile=old_ex.ktile,
-                routing=old_ex.routing,
-                bf16_accumulate=old_ex.bf16_accumulate,
-                slot_chunk=old_ex._slot_chunk_arg,
-                device=old_ex.device,
-                row_unperm=old_ex.row_unperm,
-            )
-        self = cls.__new__(cls)
-        self.sched = new_sched
-        self.ktile = old_ex.ktile
-        self.bf16_accumulate = old_ex.bf16_accumulate
-        self.device = old_ex.device
-        self.routing = GATHER
-        self._slot_chunk_arg = old_ex._slot_chunk_arg
-        self._slot_chunk = old_ex._slot_chunk
-        self._pad = old_ex._pad
-        self._n_chunks = old_ex._n_chunks
-        self.row_unperm = old_ex.row_unperm
-        self._unperm = old_ex._unperm
-        self._lrow = old_ex._lrow
-        self._windows = old_ex._windows
-        self._one_step_windows = old_ex._one_step_windows
-
-        gcol, tgt, oval = old_ex._host
-        val = oval.copy()
-        val[slots] = np.asarray(vals, val.dtype)
-        self._host = (gcol, tgt, val)
-        self._gcol, self._tgt = old_ex._gcol, old_ex._tgt
-        if slots.size == 0:
-            self._val = old_ex._val
-        else:
-            FAULTS.check("upload", device=self.device)
-            idx = np.asarray(slots, np.int64)
-            bucket = 64
-            while bucket < idx.size:
-                bucket *= 4
-            if bucket > idx.size:
-                idx = np.concatenate(
-                    [idx, np.full(bucket - idx.size, idx[-1], idx.dtype)]
-                )
-            self._val = _scatter_set(old_ex._val, idx.astype(np.int32), val[idx])
-        self.scoped_upload = True
-        self.device_bytes = old_ex.device_bytes
-        return self
+    """The executor of one schedule on one device: a single shard of every
+    step (``_ExecutorBase``)."""
 
 
 class ShardedScheduleExecutor(_ExecutorBase):
-    """Multi-device executor of one converged AWB schedule.
+    """The executor of one schedule across a 1-D device mesh: one
+    contiguous step shard per device (steps are equal work, so equal
+    counts are balanced devices — the paper's equal-work distribution
+    across the PE array, lifted one level to the device mesh).
+    ``spmm``/``forward`` run the routing body per shard under
+    ``shard_map`` and merge the per-device partial outputs with a
+    ``psum`` — the distributed adder tree that also reunites evil-row
+    chunks and boundary-straddling windows living on different devices.
+    Numerics match the single-device executor up to f32 re-association of
+    the cross-device sum.
 
-    The schedule is split by ``sharding.schedule_shard`` into contiguous
-    per-device step shards (steps are equal work, so equal counts are
-    balanced devices — the paper's equal-work distribution across the PE
-    array, lifted one level to the device mesh). Construction uploads each
-    shard to its own device exactly once (``device_put`` with a
-    ``P('dev', ...)`` sharding on the stacked step axis); ``spmm``/
-    ``forward`` then run the routing body under ``shard_map`` and merge the
-    per-device partial outputs with a ``psum`` — the distributed adder
-    tree that also reunites evil-row chunks and boundary-straddling
-    windows living on different devices.
-
-    Both routing paths shard identically: the step axis is the shard axis,
-    and each device executes exactly the single-device body over its own
-    steps. Numerics therefore match the single-device executor up to f32
-    re-association of the cross-device sum.
-    """
+    Pass ``mesh`` (1-D), or ``n_devices`` for the first ``n_devices`` of
+    this host's devices (default: all)."""
 
     def __init__(
         self,
@@ -1152,245 +1185,49 @@ class ShardedScheduleExecutor(_ExecutorBase):
                     f"n_devices={n_devices} contradicts the given mesh of "
                     f"{mesh.devices.size} device(s); pass one or the other"
                 )
-            n_devices = int(mesh.devices.size)
         self.mesh = mesh
-        self.axis = mesh.axis_names[0]
-        self.n_devices = n_devices
-        self.sched = sched
-        self.ktile = ktile
-        self.bf16_accumulate = bf16_accumulate
-        self._slot_chunk_arg = slot_chunk
-        self.row_unperm = (
-            None if row_unperm is None else np.asarray(row_unperm, np.int32)
+        super().__init__(
+            sched,
+            ktile=ktile,
+            routing=routing,
+            bf16_accumulate=bf16_accumulate,
+            slot_chunk=slot_chunk,
+            row_unperm=row_unperm,
         )
-        # replicated — the un-permute runs on the psum-merged output
-        self._unperm = (
-            None
-            if self.row_unperm is None
-            else jax.device_put(
-                jnp.asarray(self.row_unperm), NamedSharding(mesh, P())
-            )
-        )
-        k = sched.nnz_per_step
-        r = sched.rows_per_window
-        cb = sched.cols_per_block
-        self.routing = routing or select_routing(k, cb, r, ktile)
-        #: set by the repair path: True when the last (re)construction
-        #: re-uploaded only the device shards whose steps changed
-        self.scoped_upload = False
 
-        shards = shard_schedule(sched, n_devices)
-        self.step_ranges = shards.ranges
 
-        def put(x, *tail_spec):
-            return jax.device_put(
-                jnp.asarray(x), NamedSharding(mesh, P(self.axis, *tail_spec))
-            )
+class ExecutionConfig(NamedTuple):
+    """The execution fields of a ``TunedConfig`` that ``make_executor``
+    reads, for a caller that holds them as loose values."""
 
-        # ---- one-time host-side split + per-device upload ----------------
-        if self.routing == GATHER:
-            gcol, tgt, val = _gather_slots(sched)
-            # retained for incremental repair splicing (DESIGN.md §11)
-            self._host = (gcol, tgt, val)
-            # per-device flat slot streams, padded to the common shard
-            # length, then chunked so the [chunk, kdim] intermediate stays
-            # bounded (same contract as the single-device executor)
-            s_max = shards.steps_per_shard
-            length = s_max * k
-            self._slot_chunk = int(min(slot_chunk, max(1, length)))
-            pad = (-length) % self._slot_chunk
-            self._n_chunks = (length + pad) // self._slot_chunk
+    ktile: int = 128
+    routing: Optional[str] = None
+    bf16_accumulate: bool = False
 
-            def stack(x, fill):
-                out = np.full((n_devices, length + pad), fill, x.dtype)
-                for d, (lo, hi) in enumerate(shards.ranges):
-                    out[d, : (hi - lo) * k] = x[lo * k : hi * k]
-                return put(out.reshape(n_devices, self._n_chunks, self._slot_chunk))
 
-            self._gcol = stack(gcol, 0)
-            self._tgt = stack(tgt, 0)
-            self._val = stack(val, 0.0)
-            self.device_bytes = int(
-                self._gcol.nbytes + self._tgt.nbytes + self._val.nbytes
-            )
-            if self._unperm is not None:
-                self.device_bytes += int(self._unperm.nbytes)
-        else:
-            self._steps = {
-                "val": put(shards.val),
-                "lrow": put(shards.lrow),
-                "lcol": put(shards.lcol),
-                "win": put(shards.win),
-                "cblk": put(shards.cblk),
-                # replicated: the epilogue runs device-local, pre-psum
-                "row_map": jax.device_put(
-                    jnp.asarray(sched.row_map), NamedSharding(mesh, P())
-                ),
-            }
-            self.device_bytes = int(sum(v.nbytes for v in self._steps.values()))
-            if self._unperm is not None:
-                self.device_bytes += int(self._unperm.nbytes)
-
-    @classmethod
-    def _from_repair(
-        cls, old_ex: "ShardedScheduleExecutor", new_sched: Schedule, repair
-    ) -> "ShardedScheduleExecutor":
-        """Sharded executor for a repaired schedule, re-uploading only the
-        device shards whose step range contains a moved/re-emitted step.
-
-        The step count must be unchanged (the linspace split is then
-        identical, so each clean device's stacked shard is byte-identical);
-        otherwise — or for ONEHOT routing or a fallback repair — this
-        rebuilds from scratch. Clean devices keep their existing on-device
-        shard buffers via ``make_array_from_single_device_arrays``; the new
-        executor is a distinct object, and the old
-        one keeps serving in-flight batches."""
-        if (
-            old_ex.routing != GATHER
-            or repair.fell_back
-            or repair.step_src is None
-            or getattr(old_ex, "_host", None) is None
-            or new_sched.n_steps != old_ex.sched.n_steps
-        ):
-            return cls(
-                new_sched,
-                mesh=old_ex.mesh,
-                ktile=old_ex.ktile,
-                routing=old_ex.routing,
-                bf16_accumulate=old_ex.bf16_accumulate,
-                slot_chunk=old_ex._slot_chunk_arg,
-                row_unperm=old_ex.row_unperm,
-            )
-        self = cls.__new__(cls)
-        self.mesh = old_ex.mesh
-        self.axis = old_ex.axis
-        self.n_devices = old_ex.n_devices
-        self.sched = new_sched
-        self.ktile = old_ex.ktile
-        self.bf16_accumulate = old_ex.bf16_accumulate
-        self.routing = GATHER
-        self._slot_chunk_arg = old_ex._slot_chunk_arg
-        self.row_unperm = old_ex.row_unperm
-        self._unperm = old_ex._unperm
-        # n_steps unchanged ⇒ the deterministic linspace split is identical
-        self.step_ranges = old_ex.step_ranges
-        self._slot_chunk = old_ex._slot_chunk
-        self._n_chunks = old_ex._n_chunks
-
-        k = new_sched.nnz_per_step
-        gcol, tgt, val, moved = _spliced_host_slots(old_ex._host, new_sched, repair)
-        self._host = (gcol, tgt, val)
-        n_devices = self.n_devices
-        row_len = self._n_chunks * self._slot_chunk
-        dirty = [bool(np.any(moved[lo:hi])) for lo, hi in self.step_ranges]
-        devices = list(self.mesh.devices.reshape(-1))
-        sharding = NamedSharding(self.mesh, P(self.axis))
-        gshape = (n_devices, self._n_chunks, self._slot_chunk)
-
-        def _restack(old_arr, flat, fill):
-            by_dev = {s.device: s.data for s in old_arr.addressable_shards}
-            parts = []
-            for d, dev in enumerate(devices):
-                lo, hi = self.step_ranges[d]
-                if not dirty[d]:
-                    parts.append(by_dev[dev])
-                    continue
-                FAULTS.check("upload", device=dev)
-                row = np.full((1, row_len), fill, flat.dtype)
-                row[0, : (hi - lo) * k] = flat[lo * k : hi * k]
-                parts.append(
-                    jax.device_put(
-                        jnp.asarray(row.reshape(1, self._n_chunks, self._slot_chunk)),
-                        dev,
-                    )
-                )
-            return jax.make_array_from_single_device_arrays(gshape, sharding, parts)
-
-        self._gcol = _restack(old_ex._gcol, gcol, 0)
-        self._tgt = _restack(old_ex._tgt, tgt, 0)
-        self._val = _restack(old_ex._val, val, 0.0)
-        self.scoped_upload = not all(dirty)
-        self.dirty_devices = int(sum(dirty))
-        self.device_bytes = int(self._gcol.nbytes + self._tgt.nbytes + self._val.nbytes)
-        if self._unperm is not None:
-            self.device_bytes += int(self._unperm.nbytes)
-        return self
-
-    @classmethod
-    def _value_patched(
-        cls,
-        old_ex: "ShardedScheduleExecutor",
-        new_sched: Schedule,
-        slots: np.ndarray,
-        vals: np.ndarray,
-    ) -> "ShardedScheduleExecutor":
-        """Sharded executor for a value-only patched schedule: slot layout
-        and step split are identical to ``old_ex``, only ``val`` changed at
-        ``slots``. Shares the global ``_gcol``/``_tgt`` arrays and re-uploads
-        just the ``_val`` shards of devices whose step range contains a
-        changed slot; clean devices keep their existing shard buffers."""
-        if old_ex.routing != GATHER or getattr(old_ex, "_host", None) is None:
-            return cls(
-                new_sched,
-                mesh=old_ex.mesh,
-                ktile=old_ex.ktile,
-                routing=old_ex.routing,
-                bf16_accumulate=old_ex.bf16_accumulate,
-                slot_chunk=old_ex._slot_chunk_arg,
-                row_unperm=old_ex.row_unperm,
-            )
-        self = cls.__new__(cls)
-        self.mesh = old_ex.mesh
-        self.axis = old_ex.axis
-        self.n_devices = old_ex.n_devices
-        self.sched = new_sched
-        self.ktile = old_ex.ktile
-        self.bf16_accumulate = old_ex.bf16_accumulate
-        self.routing = GATHER
-        self._slot_chunk_arg = old_ex._slot_chunk_arg
-        self.row_unperm = old_ex.row_unperm
-        self._unperm = old_ex._unperm
-        self.step_ranges = old_ex.step_ranges
-        self._slot_chunk = old_ex._slot_chunk
-        self._n_chunks = old_ex._n_chunks
-
-        gcol, tgt, oval = old_ex._host
-        val = oval.copy()
-        val[slots] = np.asarray(vals, val.dtype)
-        self._host = (gcol, tgt, val)
-        self._gcol, self._tgt = old_ex._gcol, old_ex._tgt
-
-        k = new_sched.nnz_per_step
-        touched_steps = np.unique(np.asarray(slots, np.int64) // k)
-        row_len = self._n_chunks * self._slot_chunk
-        dirty = [
-            bool(np.any((touched_steps >= lo) & (touched_steps < hi)))
-            for lo, hi in self.step_ranges
-        ]
-        devices = list(self.mesh.devices.reshape(-1))
-        sharding = NamedSharding(self.mesh, P(self.axis))
-        gshape = (self.n_devices, self._n_chunks, self._slot_chunk)
-        by_dev = {s.device: s.data for s in old_ex._val.addressable_shards}
-        parts = []
-        for d, dev in enumerate(devices):
-            lo, hi = self.step_ranges[d]
-            if not dirty[d]:
-                parts.append(by_dev[dev])
-                continue
-            FAULTS.check("upload", device=dev)
-            row = np.zeros((1, row_len), val.dtype)
-            row[0, : (hi - lo) * k] = val[lo * k : hi * k]
-            parts.append(
-                jax.device_put(
-                    jnp.asarray(row.reshape(1, self._n_chunks, self._slot_chunk)),
-                    dev,
-                )
-            )
-        self._val = jax.make_array_from_single_device_arrays(gshape, sharding, parts)
-        self.scoped_upload = True
-        self.dirty_devices = int(sum(dirty))
-        self.device_bytes = old_ex.device_bytes
-        return self
+def make_executor(
+    sched: Schedule,
+    cfg,
+    *,
+    device=None,
+    mesh: Optional[Mesh] = None,
+    n_devices: Optional[int] = None,
+    row_unperm=None,
+) -> _ExecutorBase:
+    """The executor of ``sched`` under ``cfg``'s execution fields
+    (``ktile``, ``routing``, ``bf16_accumulate``: a ``TunedConfig`` or an
+    ``ExecutionConfig``), placed on one device (``device``, a placement
+    handle; None is jax's default device) or sharded by steps across a
+    mesh (``mesh``, or ``n_devices`` of this host's devices)."""
+    kwargs = dict(
+        ktile=cfg.ktile,
+        routing=cfg.routing,
+        bf16_accumulate=cfg.bf16_accumulate,
+        row_unperm=row_unperm,
+    )
+    if mesh is None and n_devices is None:
+        return ScheduleExecutor(sched, device=device, **kwargs)
+    return ShardedScheduleExecutor(sched, n_devices=n_devices, mesh=mesh, **kwargs)
 
 
 def repaired_executor(old_ex, new_sched: Schedule, repair):
@@ -1398,35 +1235,22 @@ def repaired_executor(old_ex, new_sched: Schedule, repair):
     buffers wherever the repair (``schedule.repair_schedule``) left steps
     untouched — the scoped re-upload path of DESIGN.md §11.
 
-    Dispatches on the old executor's class; always returns a **new**
-    executor object and never mutates ``old_ex``, so
-    the serving tier can atomically swap while in-flight batches finish on
-    the old one. Guaranteed bit-identical device state to a cold build of
-    the same class on ``new_sched`` with the same construction kwargs."""
-    if isinstance(old_ex, ShardedScheduleExecutor):
-        return ShardedScheduleExecutor._from_repair(old_ex, new_sched, repair)
-    if isinstance(old_ex, ScheduleExecutor):
-        return ScheduleExecutor._from_repair(old_ex, new_sched, repair)
-    raise TypeError(f"unsupported executor type: {type(old_ex).__name__}")
+    Always returns a **new** executor object and never mutates ``old_ex``,
+    so the serving tier can atomically swap while in-flight batches finish
+    on the old one. Device state is bit-identical to a cold build on
+    ``new_sched`` with the same construction arguments."""
+    return old_ex._from_repair(new_sched, repair)
 
 
 def value_patched_executor(old_ex, new_sched: Schedule, slots, vals):
     """Executor for a schedule produced by ``schedule.value_patch_schedule``
     — structure unchanged, only ``val[slots]`` differ from ``old_ex.sched``.
 
-    The O(|delta|) fast lane of DESIGN.md §11: gcol/tgt device arrays are
-    shared with ``old_ex`` and only the changed values are scattered (or
-    the dirty ``val`` shards re-uploaded, for the sharded class). Same
-    contract as ``repaired_executor``: a new object with bit-identical
-    device state to a cold build on ``new_sched``.
-    """
+    The O(|delta|) fast lane of DESIGN.md §11: the index streams are
+    shared with ``old_ex`` and only the dirty shards' ``val`` is
+    scatter-patched. Same contract as ``repaired_executor``."""
     slots = np.asarray(slots, np.int64)
-    vals = np.asarray(vals)
-    if isinstance(old_ex, ShardedScheduleExecutor):
-        return ShardedScheduleExecutor._value_patched(old_ex, new_sched, slots, vals)
-    if isinstance(old_ex, ScheduleExecutor):
-        return ScheduleExecutor._value_patched(old_ex, new_sched, slots, vals)
-    raise TypeError(f"unsupported executor type: {type(old_ex).__name__}")
+    return old_ex._value_patched(new_sched, slots, np.asarray(vals))
 
 
 # ---------------------------------------------------------------------------

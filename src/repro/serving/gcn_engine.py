@@ -95,8 +95,7 @@ from repro.core import gat
 from repro.core.executor import (
     FAULTS,
     GATHER,
-    ScheduleExecutor,
-    ShardedScheduleExecutor,
+    make_executor,
     release_device_steps,
     repaired_executor,
     value_patched_executor,
@@ -929,26 +928,15 @@ class GCNServingEngine:
         device_index: Optional[int],
         row_unperm: Optional[np.ndarray] = None,
     ):
-        """Cold executor for one serving clone (the re-tune fallback's
-        builder — full upload)."""
-        if device_index is None:  # sharded: spans the mesh
-            return ShardedScheduleExecutor(
-                sched,
-                mesh=self._mesh,
-                ktile=cfg.ktile,
-                routing=cfg.routing,
-                bf16_accumulate=cfg.bf16_accumulate,
-                row_unperm=row_unperm,
-            )
-        _, handle = self._unit_handle(device_index)
-        return ScheduleExecutor(
-            sched,
-            ktile=cfg.ktile,
-            routing=cfg.routing,
-            bf16_accumulate=cfg.bf16_accumulate,
-            device=handle,
-            row_unperm=row_unperm,
-        )
+        """Cold executor for one serving clone — on mesh device
+        ``device_index``, or across the whole mesh when it is None (a
+        sharded graph): admission's and the re-tune fallback's builder,
+        a full upload."""
+        if device_index is None:
+            placement = dict(mesh=self._mesh)
+        else:
+            placement = dict(device=self._unit_handle(device_index)[1])
+        return make_executor(sched, cfg, row_unperm=row_unperm, **placement)
 
     def _rebuilt_units(self, rec: _Resident, p: Placement, build):
         """New executor + jitted forward for every resident clone of one
@@ -1390,25 +1378,17 @@ class GCNServingEngine:
         dev = self.devices[device_index]
         return dev, (None if dev == jax.devices()[0] else dev)
 
-    def _build_unit(self, rec: _Resident, device_index: int) -> _Unit:
-        """One serving clone of ``rec`` on a specific mesh device — built
-        from the already-converged config and the host schedule, so it
-        costs one upload and zero sweeps, zero rebuilds (what makes a
-        replica cheap)."""
-        cfg = rec.config
-        dev, handle = self._unit_handle(device_index)
-        ex = ScheduleExecutor(
-            rec.sched,
-            ktile=cfg.ktile,
-            routing=cfg.routing,
-            bf16_accumulate=cfg.bf16_accumulate,
-            device=handle,
-            row_unperm=rec.inv,
-        )
-        if handle is None:
+    def _build_unit(self, rec: _Resident, device_index: Optional[int]) -> _Unit:
+        """One serving clone of ``rec`` on a specific mesh device (or, for
+        ``device_index`` None, across the whole mesh) — built from the
+        already-converged config and the host schedule, so it costs one
+        upload and zero sweeps, zero rebuilds (what makes a replica
+        cheap)."""
+        ex = self._fresh_executor(rec.sched, rec.config, device_index, rec.inv)
+        if ex.device is None:
             params = jax.tree.map(jnp.asarray, rec.params_host)
         else:
-            params = jax.device_put(rec.params_host, dev)
+            params = jax.device_put(rec.params_host, ex.device)
         nbytes = ex.device_bytes + sum(int(x.nbytes) for x in jax.tree.leaves(params))
         return _Unit(device_index, ex, self._forward_of(rec, ex), params, nbytes)
 
@@ -1419,32 +1399,15 @@ class GCNServingEngine:
             evicted = rec.fwd is None
             first = rec.bytes == 0
         if evicted:
-            cfg = rec.config
             p = self.placer.placement_of(rec.graph_id)
             # the upload runs outside the swap lock (it is O(bytes) slow);
             # the four unit fields then publish atomically under it
-            if p.kind == SHARDED:
-                ex = ShardedScheduleExecutor(
-                    rec.sched,
-                    mesh=self._mesh,
-                    ktile=cfg.ktile,
-                    routing=cfg.routing,
-                    bf16_accumulate=cfg.bf16_accumulate,
-                    row_unperm=rec.inv,
-                )
-                params = jax.tree.map(jnp.asarray, rec.params_host)
-                fwd = self._forward_of(rec, ex)
-                w_bytes = sum(int(x.nbytes) for x in jax.tree.leaves(params))
-                nbytes = ex.device_bytes + w_bytes
-            else:
-                unit = self._build_unit(rec, p.device_index)
-                ex, fwd = unit.executor, unit.fwd
-                params, nbytes = unit.params, unit.bytes
+            unit = self._build_unit(rec, None if p.kind == SHARDED else p.device_index)
             with self._swap_lock:
-                rec.executor, rec.fwd = ex, fwd
-                rec.params, rec.bytes = params, nbytes
-            self.placer.account(rec.graph_id, nbytes)
-            self.device_bytes_in_use += nbytes
+                rec.executor, rec.fwd = unit.executor, unit.fwd
+                rec.params, rec.bytes = unit.params, unit.bytes
+            self.placer.account(rec.graph_id, unit.bytes)
+            self.device_bytes_in_use += unit.bytes
             if not first:
                 self._count("readmissions")
         self._graphs.move_to_end(rec.graph_id)

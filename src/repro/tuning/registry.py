@@ -26,9 +26,9 @@ from repro.core import executor as _exe
 from repro.core import reorder as _reorder
 from repro.core import schedule as _schedule
 from repro.core.executor import (
-    ScheduleExecutor,
-    ShardedScheduleExecutor,
+    ExecutionConfig,
     _ExecutorBase,
+    make_executor,
     select_routing,
 )
 from repro.core.schedule import Schedule
@@ -365,25 +365,14 @@ def get_executor(
             fingerprint=fp,
         )
         _, inv = get_reorder(a, reorder, fingerprint=fp)
-        if mkey is None:
-            ex = ScheduleExecutor(
-                sched,
-                ktile=ktile,
-                routing=routing,
-                bf16_accumulate=bf16_accumulate,
-                device=device,
-                row_unperm=inv,
-            )
-        else:
-            ex = ShardedScheduleExecutor(
-                sched,
-                n_devices=n_devices,
-                mesh=mesh,
-                ktile=ktile,
-                routing=routing,
-                bf16_accumulate=bf16_accumulate,
-                row_unperm=inv,
-            )
+        ex = make_executor(
+            sched,
+            ExecutionConfig(ktile, routing, bf16_accumulate),
+            device=device,
+            mesh=mesh,
+            n_devices=n_devices,
+            row_unperm=inv,
+        )
         _EXECUTOR_CACHE[key] = ex
     return ex
 
@@ -412,23 +401,13 @@ def executor_for_schedule(
     if ex is not None and ex.sched is sched:
         _EXEC_BY_SCHEDULE.move_to_end(key)
         return ex
-    if mkey is None:
-        ex = ScheduleExecutor(
-            sched,
-            ktile=ktile,
-            routing=routing,
-            bf16_accumulate=bf16_accumulate,
-            device=device,
-        )
-    else:
-        ex = ShardedScheduleExecutor(
-            sched,
-            n_devices=n_devices,
-            mesh=mesh,
-            ktile=ktile,
-            routing=routing,
-            bf16_accumulate=bf16_accumulate,
-        )
+    ex = make_executor(
+        sched,
+        ExecutionConfig(ktile, routing, bf16_accumulate),
+        device=device,
+        mesh=mesh,
+        n_devices=n_devices,
+    )
     _EXEC_BY_SCHEDULE[key] = ex
     if len(_EXEC_BY_SCHEDULE) > _EXEC_BY_SCHEDULE_CAP:
         _EXEC_BY_SCHEDULE.popitem(last=False)

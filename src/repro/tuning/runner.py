@@ -280,25 +280,15 @@ def _bf16_report(a: fmt.COO, best: TunedConfig, b) -> TunedConfig:
     footprint in the registry for every tuned graph."""
     import jax.numpy as jnp
 
-    from repro.core.executor import ScheduleExecutor, ShardedScheduleExecutor
+    from repro.core.executor import make_executor
 
     # the winner stays in the registry (it is what gets served); its
     # opposite-precision twin is built directly and garbage-collected
     out_base = registry.get_executor(a, **best.as_executor_kwargs()).spmm(b)
     sched = registry.get_schedule(a, **best.as_schedule_kwargs())
     _, inv = registry.get_reorder(a, best.reorder)
-    twin_kw = dict(
-        ktile=best.ktile,
-        routing=best.routing,
-        bf16_accumulate=not best.bf16_accumulate,
-        row_unperm=inv,
-    )
-    if best.n_devices is None:
-        twin = ScheduleExecutor(sched, **twin_kw)
-    else:
-        twin = ShardedScheduleExecutor(
-            sched, n_devices=best.n_devices, **twin_kw
-        )
+    twin_cfg = dataclasses.replace(best, bf16_accumulate=not best.bf16_accumulate)
+    twin = make_executor(sched, twin_cfg, n_devices=best.n_devices, row_unperm=inv)
     out_twin = twin.spmm(b)
     err = float(
         jnp.max(

@@ -237,11 +237,30 @@ def test_repair_schedule_bit_identical_structural():
 # executor splicing
 # ---------------------------------------------------------------------------
 
-def test_value_patched_executor_matches_fresh():
+#: the two placements of one executor: one device, and a mesh of one
+PLACEMENTS = {
+    "one_device": lambda sched: exe.ScheduleExecutor(sched, routing=exe.GATHER),
+    "mesh": lambda sched: exe.ShardedScheduleExecutor(
+        sched, n_devices=1, routing=exe.GATHER),
+}
+
+
+def _assert_same_device_state(got, want):
+    assert sorted(got.operands) == sorted(want.operands)
+    for key, arr in want.operands.items():
+        np.testing.assert_array_equal(np.asarray(got.operands[key]),
+                                      np.asarray(arr))
+    assert got.geometry == want.geometry
+    assert got.device_bytes == want.device_bytes
+
+
+@pytest.mark.parametrize("placement", sorted(PLACEMENTS))
+def test_value_patched_executor_matches_fresh(placement):
+    build = PLACEMENTS[placement]
     a, _, _ = _workload(6)
     rng = np.random.default_rng(6)
     sched = schedule.build_balanced_schedule(a, **SCHED_KW)
-    ex = exe.ScheduleExecutor(sched, routing=exe.GATHER)
+    ex = build(sched)
     index = schedule.slot_entry_keys(sched)
     delta = _value_delta(a, 9, rng)
     new_sched, slots = schedule.value_patch_schedule(
@@ -250,25 +269,26 @@ def test_value_patched_executor_matches_fresh():
                                      new_sched.val[slots])
     assert ex2.scoped_upload
     assert ex2.device_bytes == ex.device_bytes
-    fresh = exe.ScheduleExecutor(new_sched, routing=exe.GATHER)
-    np.testing.assert_array_equal(np.asarray(ex2._val),
-                                  np.asarray(fresh._val))
+    fresh = build(new_sched)
+    _assert_same_device_state(ex2, fresh)
     b = np.random.default_rng(60).random((N_NODES, 16)).astype(np.float32)
     np.testing.assert_array_equal(np.asarray(ex2.spmm(jnp.asarray(b))),
                                   np.asarray(fresh.spmm(jnp.asarray(b))))
     # empty patch: the device stream is shared outright, no upload
     ex3 = exe.value_patched_executor(ex, sched, np.zeros(0, np.int64),
                                      np.zeros(0, np.float32))
-    assert ex3._val is ex._val
+    assert ex3.operands["val"] is ex.operands["val"]
 
 
-def test_repaired_executor_scoped_matches_fresh(monkeypatch):
+@pytest.mark.parametrize("placement", sorted(PLACEMENTS))
+def test_repaired_executor_scoped_matches_fresh(monkeypatch, placement):
     monkeypatch.setattr(exe, "SCOPED_UPLOAD_MIN_BYTES", 0)
+    build = PLACEMENTS[placement]
     a, _, _ = _workload(7)
     rng = np.random.default_rng(7)
     per_row_old = np.bincount(np.asarray(a.row), minlength=N_NODES)
     sched = schedule.build_balanced_schedule(a, **SCHED_KW)
-    ex = exe.ScheduleExecutor(sched, routing=exe.GATHER)
+    ex = build(sched)
     delta = _structural_delta(a, N_NODES, 20, rng)
     new_coo, rep = csc.apply_edge_delta(a, delta, with_report=True)
     per_row_new = per_row_old.copy()
@@ -277,10 +297,31 @@ def test_repaired_executor_scoped_matches_fresh(monkeypatch):
         sched, None, new_coo, rep.touched_rows,
         per_row_old=per_row_old, per_row_new=per_row_new, **SCHED_KW)
     ex2 = exe.repaired_executor(ex, new_sched, stats)
-    fresh = exe.ScheduleExecutor(new_sched, routing=exe.GATHER)
+    # the step count changed (28 -> 29): the shard's range moved, so it
+    # re-uploads in full
+    assert new_sched.n_steps != sched.n_steps and not ex2.scoped_upload
+    fresh = build(new_sched)
+    _assert_same_device_state(ex2, fresh)
     b = np.random.default_rng(70).random((N_NODES, 16)).astype(np.float32)
     np.testing.assert_array_equal(np.asarray(ex2.spmm(jnp.asarray(b))),
                                   np.asarray(fresh.spmm(jnp.asarray(b))))
+    # one edge moved within the densest row: the step count holds, only
+    # that row's steps move, and they are scatter-patched in place
+    dense = _dense(new_coo) != 0
+    row = int(np.argmax(dense.sum(1)))
+    cols = [np.nonzero(dense[row])[0][0], np.nonzero(~dense[row])[0][0]]
+    delta = csc.EdgeDelta(np.array([row, row]), np.array(cols),
+                          np.array([0.0, 1.0], np.float32))
+    coo3, rep = csc.apply_edge_delta(new_coo, delta, with_report=True)
+    per_row_old = np.bincount(np.asarray(new_coo.row), minlength=N_NODES)
+    per_row_new = per_row_old.copy()
+    per_row_new[rep.touched_rows] += rep.row_nnz_delta
+    sched3, stats = schedule.repair_schedule(
+        new_sched, None, coo3, rep.touched_rows,
+        per_row_old=per_row_old, per_row_new=per_row_new, **SCHED_KW)
+    ex3 = exe.repaired_executor(ex2, sched3, stats)
+    assert ex3.scoped_upload
+    _assert_same_device_state(ex3, build(sched3))
 
 
 # ---------------------------------------------------------------------------
