@@ -535,49 +535,56 @@ def _over_chunks(body, init, *, n_chunks: int):
 
 def _window_reduce(step_part, ops, *, g: Geometry, width: int, identity, scatter):
     """Reduce per-step window partials onto matrix rows: ``step_part(i)``
-    gives chunk ``i``'s steps as ``[steps, r, width]`` partials of their
-    windows' local rows. A window's steps (contiguous, possibly across two
-    chunks) are combined by ``scatter`` over step indices, skipped where
-    every step is its own window (``g.one_step_windows``); then each row
-    gathers its first window slot through ``row_slot`` and ``scatter``
-    folds in a split row's further chunk slots (``xslot`` → ``xrow``).
-    ``identity`` is what a slot no step wrote reads."""
+    gives chunk ``i``'s steps as ``[width, steps, r]`` partials of their
+    windows' local rows, the rows minor. A window's steps (contiguous,
+    possibly across two chunks) are combined by ``scatter`` over step
+    indices, skipped where every step is its own window
+    (``g.one_step_windows``); then each row gathers its first window slot
+    through ``row_slot`` and ``scatter`` folds in a split row's further
+    chunk slots (``xslot`` → ``xrow``). Returns ``[width, m]``; ``identity``
+    is what a slot no step wrote reads."""
     if g.one_step_windows:
-        flat = step_part(0).reshape(-1, width)
+        flat = step_part(0).reshape(width, -1)
     else:
 
         def combine(i, windows):
-            return scatter(windows, ops["win"][i], step_part(i))
+            return scatter(windows, (slice(None), ops["win"][i]), step_part(i))
 
-        init = jnp.full((g.n_windows, g.r, width), identity, g.acc)
-        flat = _over_chunks(combine, init, n_chunks=g.n_chunks).reshape(-1, width)
-    rows = jnp.take(flat, ops["row_slot"], axis=0, mode="fill", fill_value=identity)
+        init = jnp.full((width, g.n_windows, g.r), identity, g.acc)
+        flat = _over_chunks(combine, init, n_chunks=g.n_chunks).reshape(width, -1)
+    rows = jnp.take(flat, ops["row_slot"], axis=1, mode="fill", fill_value=identity)
     if ops["xslot"].shape[0]:
-        rows = scatter(rows, ops["xrow"], flat[ops["xslot"]])
+        rows = scatter(rows, (slice(None), ops["xrow"]), flat[:, ops["xslot"]])
     return rows
 
 
 def _attention_partial(ops, s_row, s_col, wh, *, g: Geometry, layer: int):
     """Edge attention over one slot stream ``[n_chunks, chunk]``, the GAT's
-    A-side of a layer: per slot and head the score ``LeakyReLU(s_row[tgt] +
+    A-side of a layer for a batch of B requests of H heads each: per slot
+    and channel (request and head) the score ``LeakyReLU(s_row[tgt] +
     s_col[gcol])``, a softmax over each output row, and the sum of ``Wh``
-    rows weighted by it. ``s_row`` ``[m, H]`` (H heads) is in the stream's
-    row order, ``s_col`` ``[n, H]`` and ``wh`` ``[n, H·F]`` in node order;
-    returns ``[m, H, F]`` in the stream's row order.
+    rows weighted by it. ``s_row`` ``[m, B·H]`` is in the stream's row
+    order, ``s_col`` ``[n, B·H]`` and ``wh`` ``[n, B, H·F]`` in node order;
+    returns ``[B, m, H, F]`` in the stream's row order.
 
-    Both row reductions are window-local (``_window_reduce``): a step's
-    slots all write one window of ``g.r`` rows, so each step reduces its
-    slots densely onto those rows — the row max as a masked max over the
-    step's slots, the exp-weighted ``[Wh ‖ 1]`` sum (whose last column per
-    head is the softmax's denominator) as a one-hot ``[r, k]`` contraction
-    on the MXU, ``k = g.k`` slots per step — and only window slots are
-    mapped to rows. The row max is
-    complete, split rows included, before any ``exp``. Slots with ``val ==
-    0`` — chunk padding, and empty PE slots — are masked out of both."""
+    The per-slot scores, mask and ``exp`` are slot-minor, ``[B·H, steps,
+    k]`` (``k = g.k`` slots per step): the slots lie on the lanes, where a
+    request's H heads would pad them up to 128-fold. Their gathers read
+    rows of all B·H channels at once. Both row reductions are window-local
+    (``_window_reduce``): a step's slots all write one window of ``g.r``
+    rows, so each step reduces its slots densely onto those rows — the row
+    max as a masked max over the step's slots, the exp-weighted ``[Wh ‖
+    1]`` sum (whose last column per head is the softmax's denominator) as a
+    one-hot ``[r, k]`` contraction on the MXU — and only window slots are
+    mapped to rows. The row max is complete, split rows included, before
+    any ``exp``. Slots with ``val == 0`` — chunk padding, and empty PE
+    slots — are masked out of both."""
     gcol, tgt, val, lrow = ops["gcol"], ops["tgt"], ops["val"], ops["lrow"]
     acc = g.acc
-    h = s_row.shape[1]
-    f = wh.shape[1] // h
+    c = s_row.shape[1]
+    b = wh.shape[1]
+    h = c // b
+    f = wh.shape[2] // h
     steps = gcol.shape[1] // g.k
     # HIGHEST keeps the one-hot selection exact and the sums in float32 on
     # a TPU, as in ``_onehot_partial``
@@ -585,22 +592,21 @@ def _attention_partial(ops, s_row, s_col, wh, *, g: Geometry, layer: int):
 
     def scores(i):
         with jax.named_scope(f"l{layer}.score"):
-            e = s_row[tgt[i]] + s_col[gcol[i]]  # [chunk, heads]
-            e = jnp.where(e > 0, e, NEGATIVE_SLOPE * e).reshape(steps, g.k, h)
-            return e, (val[i] != 0).reshape(steps, g.k, 1)
+            e = (s_row[tgt[i]] + s_col[gcol[i]]).T  # [channels, chunk]
+            e = jnp.where(e > 0, e, NEGATIVE_SLOPE * e).reshape(c, steps, g.k)
+            return e, (val[i] != 0).reshape(steps, g.k)
 
-    def onehot(i):  # [steps, k, r]: each slot's row in its step's window
-        return lrow[i].reshape(steps, g.k)[:, :, None] == jnp.arange(g.r)
+    def onehot(i):  # [steps, r, k]: each slot's row in its step's window
+        return lrow[i].reshape(steps, 1, g.k) == jnp.arange(g.r)[:, None]
 
     def step_max(i):
         e, live = scores(i)
         with jax.named_scope(f"l{layer}.softmax"):
             e = jnp.where(live, e, -jnp.inf)
-            # [steps, k, heads, r], reduced over k: the r rows lie on the
-            # lanes (heads there would pad them 16-fold), and the masked
-            # operand is fused into the reduction, never materialised
-            top = jnp.where(onehot(i)[:, :, None], e[..., None], -jnp.inf).max(1)
-            return top.transpose(0, 2, 1)
+            # [channels, steps, r, k], reduced over the k slots on the
+            # lanes; the masked operand is fused into the reduction, never
+            # materialised
+            return jnp.where(onehot(i), e[:, :, None, :], -jnp.inf).max(3)
 
     def scatter_max(a, idx, v):
         return a.at[idx].max(v)
@@ -609,73 +615,75 @@ def _attention_partial(ops, s_row, s_col, wh, *, g: Geometry, layer: int):
         return a.at[idx].add(v)
 
     top = _window_reduce(
-        step_max, ops, g=g, width=h, identity=-jnp.inf, scatter=scatter_max
-    )
+        step_max, ops, g=g, width=c, identity=-jnp.inf, scatter=scatter_max
+    ).T  # [m, channels]
 
     def step_sum(i):
         e, live = scores(i)
         with jax.named_scope(f"l{layer}.softmax"):
-            shift = top[tgt[i]].reshape(steps, g.k, h)
-            p = jnp.where(live, jnp.exp(e - shift), 0.0)  # [steps, k, heads]
+            shift = top[tgt[i]].T.reshape(c, steps, g.k)
+            p = jnp.where(live, jnp.exp(e - shift), 0.0)  # [channels, steps, k]
         with jax.named_scope(f"l{layer}.aggregate"):
-            wh_i = jnp.take(wh, gcol[i], axis=0).reshape(steps, g.k, h, f)
-            rows = jnp.concatenate([p[..., None] * wh_i, p[..., None]], axis=3)
-            rows = rows.reshape(steps, g.k, h * (f + 1))
-            return dot("skr,skc->src", onehot(i).astype(acc), rows)
+            p = p.reshape(b, h, steps, g.k).transpose(2, 3, 0, 1)[..., None]
+            wh_i = jnp.take(wh, gcol[i], axis=0).reshape(steps, g.k, b, h, f)
+            rows = jnp.concatenate([p * wh_i, p], axis=4)
+            rows = rows.reshape(steps, g.k, c * (f + 1))
+            return dot("srk,skc->csr", onehot(i).astype(acc), rows)
 
     with jax.named_scope(f"l{layer}.aggregate"):
         sums = _window_reduce(
-            step_sum, ops, g=g, width=h * (f + 1), identity=0.0, scatter=scatter_add
+            step_sum, ops, g=g, width=c * (f + 1), identity=0.0, scatter=scatter_add
         )
-        sums = sums.reshape(g.m, h, f + 1)
+        sums = sums.reshape(b, h, f + 1, g.m)
         den = sums[:, :, f:]
-        return sums[:, :, :f] / jnp.where(den > 0, den, 1.0)
-
-
-def _gat_forward_body(
-    ops: dict, params: dict, x: jax.Array, *, geom: Geometry
-) -> jax.Array:
-    """Whole-GAT logits (``core.gat``): per layer ``l<i>.xw`` X·W, then
-    ``l<i>.score``, ``l<i>.softmax`` and ``l<i>.aggregate`` over the gather
-    slot stream (``_attention_partial``), then ``l<i>.elu`` on the
-    concatenated heads or ``l<i>.mean`` of the last layer's heads. Scores,
-    softmax and sums run in ``geom.acc``."""
-    acc = geom.acc
-    n_layers = len(params) // 2
-    h = x
-    for i in range(n_layers):
-        att = params[f"a{i}"]
-        k, f = att.shape[0], att.shape[1] // 2
-        with jax.named_scope(f"l{i}.xw"):
-            wh = (h @ params[f"w{i}"]).astype(acc)
-        with jax.named_scope(f"l{i}.score"):
-            whk = wh.reshape(-1, k, f)
-            s_row = (whk * att[:, :f].astype(acc)).sum(-1)
-            s_col = (whk * att[:, f:].astype(acc)).sum(-1)
-            if geom.unperm:
-                # a reordered stream's targets are permuted rows: row
-                # ``unperm[v]`` holds node v, so its own score moves there
-                s_row = jnp.zeros_like(s_row).at[ops["unperm"]].set(s_row)
-        out = _attention_partial(ops, s_row, s_col, wh, g=geom, layer=i)
-        if geom.unperm:
-            with jax.named_scope("unperm"):
-                out = jnp.take(out, ops["unperm"], axis=0)
-        out = out.astype(x.dtype)
-        if i < n_layers - 1:
-            with jax.named_scope(f"l{i}.elu"):
-                h = jax.nn.elu(out.reshape(out.shape[0], k * f))
-        else:
-            with jax.named_scope(f"l{i}.mean"):
-                h = out.mean(axis=1)
-    return h
+        out = sums[:, :, :f] / jnp.where(den > 0, den, 1.0)
+        return out.transpose(0, 3, 1, 2)
 
 
 def _batched_gat_body(
     geom: Geometry, ops: dict, params: dict, xs: jax.Array
 ) -> jax.Array:
-    """``_gat_forward_body`` vmapped over a leading request axis of ``xs``."""
-    fwd = functools.partial(_gat_forward_body, geom=geom)
-    return jax.vmap(fwd, in_axes=(None, None, 0))(ops, params, xs)
+    """Whole-GAT logits (``core.gat``) for a batch of requests ``xs``
+    ``[B, n, din]``: per layer ``l<i>.xw`` X·W, then ``l<i>.score``,
+    ``l<i>.softmax`` and ``l<i>.aggregate`` over the gather slot stream
+    (``_attention_partial``, each request's heads as channels of one
+    stream pass), then ``l<i>.elu`` on the concatenated heads or
+    ``l<i>.mean`` of the last layer's heads. Scores, softmax and sums run
+    in ``geom.acc``."""
+    acc = geom.acc
+    n_layers = len(params) // 2
+    b, n = xs.shape[:2]
+    h = xs
+    for i in range(n_layers):
+        att = params[f"a{i}"]
+        k, f = att.shape[0], att.shape[1] // 2
+        with jax.named_scope(f"l{i}.xw"):
+            wh = (h @ params[f"w{i}"]).astype(acc)  # [B, n, K·F]
+        with jax.named_scope(f"l{i}.score"):
+            whk = wh.reshape(b, n, k, f)
+            # node-major, the batch's requests and heads as B·K channels
+            s_row = (whk * att[:, :f].astype(acc)).sum(-1)
+            s_row = s_row.transpose(1, 0, 2).reshape(n, b * k)
+            s_col = (whk * att[:, f:].astype(acc)).sum(-1)
+            s_col = s_col.transpose(1, 0, 2).reshape(n, b * k)
+            if geom.unperm:
+                # a reordered stream's targets are permuted rows: row
+                # ``unperm[v]`` holds node v, so its own score moves there
+                s_row = jnp.zeros_like(s_row).at[ops["unperm"]].set(s_row)
+        out = _attention_partial(
+            ops, s_row, s_col, wh.transpose(1, 0, 2), g=geom, layer=i
+        )
+        if geom.unperm:
+            with jax.named_scope("unperm"):
+                out = jnp.take(out, ops["unperm"], axis=1)
+        out = out.astype(xs.dtype)
+        if i < n_layers - 1:
+            with jax.named_scope(f"l{i}.elu"):
+                h = jax.nn.elu(out.reshape(b, n, k * f))
+        else:
+            with jax.named_scope(f"l{i}.mean"):
+                h = out.mean(axis=2)
+    return h
 
 
 # module-level jits keyed on the static geometry: executors of one geometry

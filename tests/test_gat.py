@@ -50,8 +50,8 @@ def _graph(seed=1):
     return synth.power_law_adjacency(N_NODES, 0.03, 0.9, seed=seed)
 
 
-def _params(seed=1, feats=N_FEATS):
-    return gat.init_params(gat.GATConfig(feats), jax.random.PRNGKey(seed))
+def _params(seed=1, feats=N_FEATS, heads=(8, 8)):
+    return gat.init_params(gat.GATConfig(feats, heads), jax.random.PRNGKey(seed))
 
 
 def _requests(k, seed=2):
@@ -152,11 +152,18 @@ def _case_executor(case):
                                    row_unperm=inv)
 
 
+#: heads per layer: the paper's 8 and 8; one head, whose channels are the
+#: batch's requests alone; and head counts that divide no tile
+HEADS = [(8, 8), (1, 1), (3, 2)]
+
+
+@pytest.mark.parametrize("heads", HEADS, ids=lambda h: "heads%dx%d" % h)
 @pytest.mark.parametrize("case", CASES)
-def test_attention_body_matches_the_reference(case):
+def test_attention_body_matches_the_reference(case, heads):
     a, ex = _case_executor(case)
     sched, k, chunk = ex.sched, ex.sched.nnz_per_step, CASES[case][2]
-    params = _params()
+    params = _params(heads=heads)
+    assert gat.layer_heads(params) == heads
     xs = jnp.asarray(np.stack(_requests(2)))
     _, _, val = exe._gather_slots(sched)
     assert (val == 0).any()  # empty PE slots are in the stream

@@ -203,6 +203,50 @@ def test_served_gat_forward_compiles_at_pubmed_width(one_chip):
     assert "_batched_gat_body" in compiled.as_text().split("\n", 1)[0]
 
 
+#: a compiled instruction's result shapes: element type, dims, layout
+#: (minor to major) and the tile of its two most minor dims
+_RESULT = re.compile(r"(\w+)\[([0-9,]*)\]\{([0-9,]*):T\(([0-9,]+)\)")
+
+
+def _entry_results(hlo: str):
+    """``(name, dims, padding)`` of every array result of the entry
+    computation's instructions but bitcasts, where padding is the tiled
+    size over the data size."""
+    for line in hlo[hlo.index("\nENTRY"):].splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if not m or m.group(3) == "bitcast":
+            continue
+        for _, dims, layout, tile in _RESULT.findall(m.group(2)):
+            dims = [int(d) for d in dims.split(",") if d]
+            tiled = list(dims)
+            minor = [int(d) for d in layout.split(",") if d]
+            for t, d in zip(reversed([int(t) for t in tile.split(",")]), minor):
+                tiled[d] = -(-tiled[d] // t) * t
+            yield m.group(1), dims, np.prod(tiled) / max(np.prod(dims), 1)
+
+
+@pytest.mark.parametrize("steps,windows,n_split", [(GATHER_STEPS, GATHER_STEPS, 0),
+                                                   (GATHER_STEPS, 400, 512)],
+                         ids=["one_step_windows", "multi_step_windows_split_rows"])
+def test_gat_per_slot_arrays_are_not_lane_padded(one_chip, steps, windows, n_split):
+    """No array over the slot stream is padded more than 2-fold by its
+    (8, 128) tile: the per-slot scores, mask, row-max shift and ``exp`` of
+    both layers keep a request's heads off the 128 lanes, where 8 heads
+    filled one sixteenth of them (twelve such arrays of 517 MB, each
+    holding 32 MB). The second geometry compiles the window combine and
+    the split-row scatter too. Left out, by their shape: the gathered
+    ``Wh`` rows, ``[slots, batch, heads x features]`` of 64 and 24
+    columns, another layer's work."""
+    ops, geom = _attention_inputs(steps, GATHER_K, windows, n_split, one_chip)
+    slots = steps * GATHER_K
+    wh_rows = ([8, 64], [8, 24])  # batch 8, 8 heads of 8 and of 3
+    padded = [(name, dims, float(round(pad, 2)))
+              for name, dims, pad in _entry_results(_compile_gat(geom, ops, one_chip).as_text())
+              if (slots in dims or {steps, GATHER_K} <= set(dims))
+              and dims[-2:] not in wh_rows and pad > 2]
+    assert padded == []
+
+
 def test_gat_window_combine_compiles_at_pubmed_width(one_chip):
     """Full Pubmed's schedule at K=128 (1,028 steps over 930 windows, 50
     further chunks of split rows): the attention's step combine and its
